@@ -25,23 +25,6 @@ import sys
 from typing import Any
 
 
-def _ensure_jax_backend() -> None:
-    """Operator machines may carry a JAX_PLATFORMS pointing at a plugin
-    that isn't loadable here; probe in a subprocess (jax caches backend
-    init failures in-process) and fall back to CPU so the CLI always
-    works."""
-    import subprocess
-    if not os.environ.get("JAX_PLATFORMS"):
-        return
-    probe = subprocess.run(
-        [sys.executable, "-c", "import jax; jax.devices()"],
-        capture_output=True)
-    if probe.returncode != 0:
-        print(f"warning: JAX backend '{os.environ['JAX_PLATFORMS']}' "
-              "unavailable; falling back to cpu", file=sys.stderr)
-        os.environ["JAX_PLATFORMS"] = "cpu"
-
-
 def _build_cluster(wal: str):
     from .engine.durability import open_durable_stores, recover_stores
     from .engine.onebox import Onebox
@@ -301,7 +284,14 @@ def main(argv=None) -> int:
     # WAL-fsck + relaunch, asymmetric partitions, membership flaps —
     # gated on fault-free byte-identity, clean fsck, zero parity
     # divergence, closing verify_all (both regions with --regions 2)
-    fc = fz.add_parser("cluster")
+    # `fuzz cluster`, `load cluster` and `load region` each start SEVERAL
+    # serving-tier hosts on this machine, and a chip belongs to one process
+    several_hosts = (
+        "Starts several service hosts with the serving tier on. A chip "
+        "belongs to one process, so the launcher refuses this fleet "
+        "unless JAX_PLATFORMS=cpu is set in the environment: export it "
+        "on any machine where it is not.")
+    fc = fz.add_parser("cluster", description=several_hosts)
     fc.add_argument("--seed", type=int, default=20260806)
     fc.add_argument("--hosts", type=int, default=3)
     fc.add_argument("--shards", type=int, default=8)
@@ -378,7 +368,7 @@ def main(argv=None) -> int:
     # the multi-host kill-mid-traffic migration scenario (wire cluster,
     # serving tier ON in every host; gates victim p99, zero divergence,
     # snapshot-hydrated steals >= the floor; records events/s/cluster)
-    cl = load_grp.add_parser("cluster")
+    cl = load_grp.add_parser("cluster", description=several_hosts)
     cl.add_argument("--duration", type=float, default=12.0)
     cl.add_argument("--hosts", type=int, default=3)
     cl.add_argument("--rps", type=float, default=16.0,
@@ -399,7 +389,7 @@ def main(argv=None) -> int:
     # continuous replication + snapshot shipping; gates promoted-region
     # p99, bounded pre-kill lag, warm steals >= the floor, zero
     # divergence, both-region verify; records events/s/fleet)
-    rg = load_grp.add_parser("region")
+    rg = load_grp.add_parser("region", description=several_hosts)
     rg.add_argument("--duration", type=float, default=10.0,
                     help="per traffic phase (active + promoted)")
     rg.add_argument("--hosts", type=int, default=2,
@@ -505,7 +495,6 @@ def main(argv=None) -> int:
             before, after = migrate_wal_file(args.wal)
             _emit({"migrated": args.wal, "from": before, "to": after})
         return 0
-    _ensure_jax_backend()
     box, _report = _build_cluster(args.wal)
     from .engine.admin import AdminHandler
     admin = AdminHandler(box)
@@ -908,7 +897,6 @@ def _fuzz_tool(args) -> int:
     gen/shrink.py, gen/interleave.py): exit 0 iff the run's gates held
     (zero oracle<->device divergence, all 13 decision types covered,
     clean interleaving when requested)."""
-    _ensure_jax_backend()
     from .gen import fuzz as fuzz_mod
 
     if args.cmd == "run":
@@ -1011,7 +999,6 @@ def _load_tool(args) -> int:
     """`load run` / `load overload` (cadence_tpu/loadgen/scenarios.py):
     exit 0 iff the scenario's gate held (SLOs, shed ratio, zero
     checksum divergence)."""
-    _ensure_jax_backend()
     from .loadgen import report as lg_report
     from .loadgen import scenarios
 

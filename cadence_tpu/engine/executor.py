@@ -307,17 +307,9 @@ def replay_corpus_mesh(events, mesh=None, layout=None,
     key = ("serve-dense", layout, Wc, E, n)
 
     def build():
-        from functools import partial
+        from ..ops.replay import replay_to_payload_branch
 
-        from ..ops.payload import payload_rows
-        from ..ops.replay import replay_events
-
-        @partial(jax.jit, static_argnames=("lay",))
-        def fn(ev, lay):
-            s = replay_events(ev, lay)
-            return payload_rows(s, lay), s.error, s.current_branch
-
-        return lambda ev: fn(ev, layout)
+        return lambda ev: replay_to_payload_branch(ev, layout)
 
     fn = variants.get(key, build, registry, scope=m.SCOPE_TPU_EXECUTOR)
 

@@ -239,6 +239,7 @@ class HistoryEngine:
             self.last_serving_ticket = None
         except Exception:
             self.last_serving_ticket = None
+            self.metrics.inc(m.SCOPE_TPU_SERVING, m.M_SERVING_HANDOFF_FAILED)
             self.log.warning("serving handoff failed",
                              workflow_id=info.workflow_id)
 

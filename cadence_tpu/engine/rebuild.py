@@ -213,18 +213,7 @@ class DeviceRebuilder:
         # chunks fan across the serving mesh (workflow axis sharded over
         # 'shard', per-device slice copies; a mesh of 1 is single-chip)
         from ..parallel.mesh import place_corpus
-        try:
-            mesh = self.mesh
-        except RuntimeError:
-            # serving_mesh() enumerates devices, so a MISSING BACKEND
-            # surfaces here, before the executor even runs — degrade to
-            # the oracle exactly like the executor-run handler below
-            # (the CLI-on-a-deviceless-host contract, ADVICE r3)
-            self.stats.oracle_fallback += len(jobs)
-            scope.inc(m.M_ORACLE_FALLBACKS, len(jobs))
-            return self._merge_prepass(
-                pre, positions,
-                [self._oracle_rebuild(b, e) for b, e in jobs])
+        mesh = self.mesh
         n_dev = int(mesh.devices.size)
         chunk_jobs = max(1, self.chunk_jobs)
         spans = [(lo, min(lo + chunk_jobs, len(jobs)))
@@ -266,24 +255,9 @@ class DeviceRebuilder:
             with prof.leg(m.M_PROFILE_READBACK):
                 return np.asarray(rows_dev), jax.device_get(state)
 
-        try:
-            with scope.timed():
-                results, _report = executor.run(len(spans), pack, launch,
-                                                consume)
-        except RuntimeError:
-            # only a MISSING BACKEND degrades to the oracle (e.g. the CLI
-            # on a machine whose JAX_PLATFORMS points at an unavailable
-            # plugin); genuine kernel/compile/OOM failures must surface,
-            # not silently fall back — probe the backend to tell them apart
-            try:
-                jax.local_devices()
-            except RuntimeError:
-                self.stats.oracle_fallback += len(jobs)
-                scope.inc(m.M_ORACLE_FALLBACKS, len(jobs))
-                return self._merge_prepass(
-                    pre, positions,
-                    [self._oracle_rebuild(b, e) for b, e in jobs])
-            raise
+        with scope.timed():
+            results, _report = executor.run(len(spans), pack, launch,
+                                            consume)
 
         from ..ops.state import CAPACITY_ERRORS
 
@@ -451,8 +425,7 @@ class DeviceRebuilder:
         leaf shapes) read back individually — the rare case."""
         import jax
 
-        from ..ops.state import init_state, layout_of
-        from .resident import ResidentStateCache, _bucket
+        from .resident import _bucket, _stack_padded
 
         pre: Dict[int, MutableState] = {}
 
@@ -470,12 +443,8 @@ class DeviceRebuilder:
             if len(states) == 1:
                 arrs = jax.device_get(states[0])
             else:
-                Wp = _bucket(len(states), 8)
-                if Wp > len(states):
-                    states = states + [init_state(Wp - len(states),
-                                                  layout_of(states[0]))]
                 arrs = jax.device_get(
-                    ResidentStateCache._stack_rows(states))
+                    _stack_padded(states, _bucket(len(states), 8)))
             for j, (pos, key, batches, entry, rentry) in enumerate(group):
                 hydrate_one(arrs, j if len(group) > 1 else 0,
                             pos, key, batches, entry, rentry)

@@ -431,19 +431,6 @@ class ResidentStateCache:
         (one dynamic-slice launch per leaf; jit-cached per shape)."""
         return _slice_row(state, index)
 
-    @staticmethod
-    def _stack_rows(rows: Sequence[object]):
-        """Batch W=1 state rows back into one [k, ...] ReplayState.
-
-        One JITTED concatenate over the whole pytree (a list of states
-        IS a pytree argument): the serving tier stacks per flush, and
-        the eager per-leaf version paid ~66 dispatch round-trips
-        (promote_dtypes + a fresh tiny concat trace per batch-size
-        combo) — 30ms of host overhead per launch that quantized every
-        coalesced transaction's latency. Jitting collapses it to one
-        cached call per row-count."""
-        return _stack_states(list(rows))
-
     # -- the append transaction ---------------------------------------------
 
     def replay_append(self, items: Sequence[Tuple[tuple, ResidentEntry,
@@ -512,7 +499,6 @@ class ResidentStateCache:
                       address_of: Callable = content_address) -> None:
         from ..ops.encode import assemble_corpus
         from ..ops.replay import replay_from_state_to_payload
-        from ..ops.state import init_state, layout_of
         from .executor import BulkReplayExecutor
 
         chunk = max(1, self.chunk_workflows)
@@ -522,7 +508,6 @@ class ResidentStateCache:
                                       registry=self.metrics,
                                       scope=m.SCOPE_TPU_RESIDENT)
         scope = self._scope()
-        layout_g = layout_of(items[idxs[0]][1].state)
 
         def pack(ci):
             lo, hi = spans[ci]
@@ -547,14 +532,8 @@ class ResidentStateCache:
 
         def launch(ci, corpus):
             lo, hi = spans[ci]
-            states = [items[i][1].state for i in idxs[lo:hi]]
-            if corpus.shape[0] > len(states):
-                pad_rows = init_state(corpus.shape[0] - len(states),
-                                      layout_g)
-                if device is not None:
-                    pad_rows = jax.device_put(pad_rows, device)
-                states.append(pad_rows)
-            s0 = self._stack_rows(states) if len(states) > 1 else states[0]
+            s0 = _stack_padded([items[i][1].state for i in idxs[lo:hi]],
+                               corpus.shape[0], device)
             report.chunk_shapes.append(
                 (corpus.shape[0], corpus.shape[1]))
             events = int((corpus[:, :, 0] > 0).sum())  # LANE_EVENT_ID
@@ -643,8 +622,8 @@ class ResidentStateCache:
         scope = self._scope()
         scope.inc(m.M_RESIDENT_WIDENED, len(flat_idxs))
         report.escalated_rows += len(flat_idxs)
-        pre_states = self._stack_rows([items[i][1].state
-                                       for i in flat_idxs])
+        pre_states = _stack_states([items[i][1].state
+                                    for i in flat_idxs])
         trimmed = gather_subcorpus(sub, np.arange(sub.shape[0]))
         outcome, states_out = self.ladder.escalate_resident(
             trimmed, pre_states, base_rung=rung)
@@ -692,8 +671,10 @@ _STACK_FN = None
 
 
 def _stack_states(states):
-    """Jitted whole-pytree stack of W=1 state rows (one trace per row
-    count + leaf shapes, then a single cached dispatch per call)."""
+    """Jitted whole-pytree stack of state rows (a list of states IS a
+    pytree argument): one trace per row count + leaf shapes, then a
+    single cached dispatch per call — an eager per-leaf concatenate
+    paid ~66 dispatch round-trips per flush."""
     global _STACK_FN
     if _STACK_FN is None:
         def stack(ss):
@@ -702,6 +683,25 @@ def _stack_states(states):
 
         _STACK_FN = jax.jit(stack)
     return _STACK_FN(states)
+
+
+def _stack_padded(rows, width: int, device=None):
+    """Stack k W=1 state rows into one [width, ...] launch state, the
+    tail filled with initial-state rows (their corpus rows carry no
+    events). The filler must be W=1 rows, not one [width - k] block:
+    the jitted stack then takes `width` operands whatever k is — one
+    program per flush width, not one per ROW COUNT, and the TPU's
+    compiler pays for each super-linearly in its operand count (a cold
+    host's boot warm-up; PERF.md, PR 22)."""
+    from ..ops.state import init_state, layout_of
+
+    rows = list(rows)
+    if len(rows) < width:
+        filler = init_state(1, layout_of(rows[0]))
+        if device is not None:
+            filler = jax.device_put(filler, device)
+        rows += [filler] * (width - len(rows))
+    return _stack_states(rows)
 
 
 _SLICE_FN = None

@@ -387,8 +387,7 @@ class ServingScheduler:
             self._pending.clear()
             self._cv.notify_all()
         for item in pending:
-            for t in item.tickets:
-                t._resolve(ServingResult(ok=False, error="stopped"))
+            self._resolve(item, ServingResult(ok=False, error="stopped"))
         thread = self._thread
         if thread is not None and thread.is_alive() \
                 and thread is not threading.current_thread():
@@ -490,6 +489,9 @@ class ServingScheduler:
         item.resolved = True
         result.coalesced = item.coalesced > 0
         result.queue_wait_s = time.perf_counter() - item.enqueued
+        self._scope().inc(m.M_SERVING_TICKETS_OK if result.ok
+                          else m.M_SERVING_TICKETS_FAILED,
+                          len(item.tickets))
         for t in item.tickets:
             t._resolve(result)
 
@@ -900,7 +902,7 @@ class ServingScheduler:
         from ..ops.encode import NUM_LANES
         from ..ops.replay import replay_from_state_to_payload
         from ..ops.state import init_state
-        from .resident import _slice_row, _stack_states
+        from .resident import _slice_row, _stack_padded
 
         top = _bucket(width if width is not None else self.max_batch, 8)
         widths = [w for w in (8, 16, 32, 64, 128) if w <= top] or [top]
@@ -915,22 +917,16 @@ class ServingScheduler:
                     replay_from_state_to_payload(dev, s0, self.layout)[1])
                 jax.block_until_ready(self._cold_fn(Wp, int(E))(dev)[1])
                 warmed += 1
-        # the per-flush host plumbing jits too: stacking k W=1 resident
-        # rows (+ one pad block) into the launch state traces once per
-        # row-count combo, and the post-launch row slice traces once per
-        # state width — both must happen HERE, not inside the first
-        # drain windows (each mid-window trace stalls the drain long
-        # enough for folds to outgrow the warmed event buckets)
-        rows = [init_state(1, self.layout) for _ in range(top)]
-        for k in range(1, top + 1):
-            ss = list(rows[:k])
-            pad = _bucket(k, 8) - k
-            if pad:
-                ss.append(init_state(pad, self.layout))
-            if len(ss) > 1:
-                jax.block_until_ready(
-                    jax.tree_util.tree_leaves(_stack_states(ss))[0])
+        # the per-flush host plumbing jits too: stacking the W=1
+        # resident rows into the launch state and slicing a row back out
+        # each trace once per flush width — both must happen HERE, not
+        # inside the first drain windows (each mid-window trace stalls
+        # the drain long enough for folds to outgrow the warmed event
+        # buckets)
+        row = init_state(1, self.layout)
         for Wp in widths:
+            jax.block_until_ready(jax.tree_util.tree_leaves(
+                _stack_padded([row], Wp))[0])
             jax.block_until_ready(jax.tree_util.tree_leaves(
                 _slice_row(init_state(Wp, self.layout), 0))[0])
         return warmed
@@ -971,6 +967,10 @@ class ServingScheduler:
                                            m.M_SERVING_REJECTED),
             "parity_divergence": reg.counter(m.SCOPE_TPU_SERVING,
                                              m.M_SERVING_DIVERGENCE),
+            "tickets_ok": reg.counter(m.SCOPE_TPU_SERVING,
+                                      m.M_SERVING_TICKETS_OK),
+            "tickets_failed": reg.counter(m.SCOPE_TPU_SERVING,
+                                          m.M_SERVING_TICKETS_FAILED),
             "batch_size_p50": round(size.percentile(0.5), 2),
             "batch_size_p99": round(size.percentile(0.99), 2),
             "queue_wait_p50_ms": round(wait.percentile(0.5) * 1e3, 3),

@@ -24,7 +24,7 @@ AST (engine/visibility_query.py) into vectorized mask kernels
 (ops/scan.py) whose variants are cached in a KernelVariantCache — warm
 queries of a seen shape recompile NOTHING, and only matching row ids
 come back off the device (a packed bitmap, a scalar count, or a top-K
-page via device argsort over the start-time column).
+page selected on the device by start time).
 
 The HOST STORE STAYS THE WRITE-SIDE AUTHORITY. Every mutation lands in
 `VisibilityStore` first and enqueues a column delta here (sequence-
@@ -794,7 +794,7 @@ class DeviceVisibilityView:
 
     def page(self, store, domain_id: str, query: str, page_size: int,
              next_page_token=None):
-        from ..ops.scan import UnsupportedPredicate, pow2_bucket
+        from ..ops.scan import TOPK_MAX_K, UnsupportedPredicate, pow2_bucket
         from .visibility_query import parse_query
 
         node, hints = parse_query(query)
@@ -815,8 +815,9 @@ class DeviceVisibilityView:
                 return store._query_page_locked(
                     domain_id, self._pred(node), hints, page_size, token)
             k = pow2_bucket(page_size + 1, floor=64)
+            use_topk = k < self.capacity and k <= TOPK_MAX_K
             entries = complete = None
-            if k < self.capacity:
+            if use_topk:
                 entries, complete = self._topk_page(plan, k, token)
                 if (entries is not None and not complete
                         and len(entries) < page_size):
@@ -824,8 +825,9 @@ class DeviceVisibilityView:
                     entries = None
             if entries is None:
                 # tie straddled the K boundary (or K covers the whole
-                # table): the bitmap path has every matching id
-                if k < self.capacity:
+                # table, or the page is too wide to select row by row):
+                # the bitmap path has every matching id
+                if use_topk:
                     scope.inc(m.M_VIS_TOPK_ESCALATIONS)
                 scope.inc(m.M_VIS_BITMAP)
                 rows, _ = self._matched_rows(plan)
@@ -870,8 +872,9 @@ class DeviceVisibilityView:
         return out
 
     def _topk_page(self, plan, k: int, token):
-        """Device-argsort fast path: the first k matching ids in
-        (start DESC, row ASC) order. Returns (entries, complete) or
+        """Device top-K fast path (ops/scan.build_topk's k-round
+        selection): the first k matching ids in (start DESC, row ASC)
+        order. Returns (entries, complete) or
         (None, False) when a start-time tie straddles the k boundary —
         entries past k could sort between returned ones in the host's
         (workflow_id, run_id) tie order, so the caller escalates."""

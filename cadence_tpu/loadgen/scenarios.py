@@ -758,12 +758,15 @@ def region_failover_scenario(duration_s: float = 10.0, num_hosts: int = 2,
     import threading
     import types
 
-    import cadence_tpu
-
     from ..engine.failovermanager import FailoverManager
     from ..engine.multicluster import _refresh_domain_tasks
     from ..engine.replication import REPLICATION_QUEUE
-    from ..rpc.cluster import _wait_listening, free_port, launch_group
+    from ..rpc.cluster import (
+        _wait_listening,
+        child_env,
+        free_port,
+        launch_group,
+    )
     from ..utils import metrics as cm
     from .mixes import (
         OP_QUERY,
@@ -944,15 +947,10 @@ def region_failover_scenario(duration_s: float = 10.0, num_hosts: int = 2,
         verify_primary = None
         if verify:
             rport = free_port()
-            renv = dict(os.environ)
-            renv.setdefault("JAX_PLATFORMS", "cpu")
-            repo = os.path.dirname(os.path.dirname(
-                os.path.abspath(cadence_tpu.__file__)))
-            renv["PYTHONPATH"] = repo + os.pathsep + renv.get(
-                "PYTHONPATH", "")
             recover_proc = subprocess.Popen(
                 [sys.executable, "-m", "cadence_tpu.rpc.storeserver",
-                 "--port", str(rport), "--wal", pcluster.wal], env=renv)
+                 "--port", str(rport), "--wal", pcluster.wal],
+                env=child_env("store", "store"))
             _wait_listening(rport, recover_proc)
             verify_primary = _verify_cluster_state(
                 types.SimpleNamespace(store_port=rport))

@@ -1,15 +1,24 @@
-"""Build + load the native packer (ctypes, g++, cached by source hash).
+"""Build + load the native libraries (ctypes, g++, cached by source hash).
 
 No pip/pybind11 in this environment — the C ABI via ctypes is the binding
-layer. The shared object is rebuilt only when packer.cc changes; loading
-falls back to None (callers use the pure-Python packer) when no toolchain
-is available, so the framework stays importable everywhere.
+layer. A shared object is rebuilt only when its sources change. The
+build directory is git-ignored, so a fresh checkout compiles each
+library once, on first use, and many processes may reach that first use
+together (test workers, the service hosts of one wire cluster): each
+compiles to a temporary name of its own and renames it into place, so
+no process ever loads a half-written file.
+
+Loading returns None only where there is no toolchain at all (no `g++`
+on PATH) — callers then use the pure-Python packer. With a toolchain, a
+compile or load that fails is raised: a host must not lose its native
+encoder silently.
 """
 from __future__ import annotations
 
 import ctypes
 import hashlib
 import os
+import shutil
 import subprocess
 import threading
 from typing import Optional
@@ -22,7 +31,6 @@ _BUILD_DIR = os.path.join(_DIR, "_build")
 
 _lock = threading.Lock()
 _cached: dict = {}
-_load_failed: set = set()
 
 
 def _so_path(src: str, stem: str, deps: tuple = ()) -> str:
@@ -38,19 +46,32 @@ def _so_path(src: str, stem: str, deps: tuple = ()) -> str:
 
 def _build_src(src: str, stem: str, verbose: bool = False,
                deps: tuple = ()) -> str:
-    """Compile one source if needed; returns the .so path."""
+    """Compile one source if needed; returns the .so path. Safe under
+    concurrent first use across processes and threads: the compiler
+    writes a name only this caller uses, and the atomic rename
+    publishes a complete file (every racer's output is identical, so
+    whichever rename lands last changes nothing)."""
     so = _so_path(src, stem, deps)
     if os.path.exists(so):
         return so
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{so}.{os.getpid()}.{threading.get_ident()}.tmp"
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        "-o", so + ".tmp", src,
+        "-o", tmp, src,
     ]
     if verbose:
         print("+", " ".join(cmd))
-    subprocess.run(cmd, check=True, capture_output=not verbose)
-    os.replace(so + ".tmp", so)
+    try:
+        proc = subprocess.run(cmd, capture_output=not verbose, text=True)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"g++ failed (rc={proc.returncode}) building {stem} from "
+                f"{src}:\n{proc.stderr or ''}")
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     return so
 
 
@@ -63,13 +84,10 @@ def _load_lib(src: str, stem: str, configure,
     with _lock:
         if stem in _cached:
             return _cached[stem]
-        if stem in _load_failed:
-            return None
-        try:
-            lib = ctypes.CDLL(_build_src(src, stem, deps=deps))
-        except (OSError, subprocess.CalledProcessError, FileNotFoundError):
-            _load_failed.add(stem)
-            return None
+        so = _so_path(src, stem, deps)
+        if not os.path.exists(so) and shutil.which("g++") is None:
+            return None  # genuinely no toolchain: pure-Python callers
+        lib = ctypes.CDLL(_build_src(src, stem, deps=deps))
         configure(lib)
         _cached[stem] = lib
         return lib

@@ -41,9 +41,8 @@ from . import build as _build
 #: 0/false/off pins the byte-identical pure-Python encoder
 NATIVE_WIREC_ENV = "CADENCE_TPU_NATIVE_WIREC"
 
-#: host→device staging knob: default on — reusable staging buffers hand
-#: off through dlpack where the backend accepts it (on the CPU backend
-#: this halves the measured H2D cost vs device_put of the same buffer);
+#: host→device staging knob: default on — on the CPU backend reusable
+#: staging buffers hand off through dlpack (see h2d_path);
 #: 0/false/off pins plain jax.device_put
 ZERO_COPY_ENV = "CADENCE_TPU_ZERO_COPY"
 
@@ -70,38 +69,37 @@ def wirec_native_enabled(registry=None) -> bool:
     return avail
 
 
-#: None = undecided; set once on the first staging attempt so a backend
-#: that rejects dlpack imports costs ONE failed try, not one per chunk
-_DLPACK_OK: Optional[bool] = None
-
-
-def stage_h2d(arr):
-    """ONE host→device staging hop for a reusable pinned host buffer.
-
-    dlpack import when the backend accepts it (the fast path — the
-    buffer's memory is handed to the runtime without a Python-side
-    copy), jax.device_put otherwise. A numpy buffer always imports as a
-    kDLCPU tensor, so on a non-CPU default backend (TPU/GPU) the import
-    "succeeds" but lands on the wrong device and every downstream jit
-    would reject it — the first call checks placement against the
-    default device and pins device_put for the process when it doesn't
-    match. Safe against ring-slot reuse either way: the executor's ring
-    discipline frees a slot only after the chunk that last used it has
-    fully replayed, so the device is never still reading a buffer being
-    overwritten."""
-    global _DLPACK_OK
+def h2d_path() -> str:
+    """Which staging path this process takes — "dlpack" or "device_put"
+    — chosen from what the code can observe: the default backend's
+    platform. A numpy buffer exports as a kDLCPU tensor, so a dlpack
+    import can only ever land on the CPU device: on the CPU backend
+    that IS the target and the import hands the buffer over without a
+    copy; on an accelerator it would land on the wrong device (or, with
+    only the accelerator's platform loaded, fail), so there the one path
+    is jax.device_put, which copies host→HBM. CADENCE_TPU_ZERO_COPY=0
+    pins device_put everywhere."""
     import jax
 
     env = os.environ.get(ZERO_COPY_ENV, "").strip().lower()
-    if env not in ("0", "false", "off", "no") and _DLPACK_OK is not False:
-        try:
-            out = jax.dlpack.from_dlpack(arr)
-            if _DLPACK_OK is None:
-                _DLPACK_OK = next(iter(out.devices())) == jax.devices()[0]
-            if _DLPACK_OK:
-                return out
-        except Exception:
-            _DLPACK_OK = False
+    if env in ("0", "false", "off", "no"):
+        return "device_put"
+    return "dlpack" if jax.default_backend() == "cpu" else "device_put"
+
+
+def stage_h2d(arr):
+    """ONE host→device staging hop for a reusable host buffer, by the
+    path h2d_path() names. DLPack carries neither a read-only flag nor
+    non-compact strides, so such an array takes device_put on any
+    backend. Safe against ring-slot reuse either way: the executor's
+    ring discipline frees a slot only after the chunk that last used it
+    has fully replayed, so the device is never still reading a buffer
+    being overwritten."""
+    import jax
+
+    if (h2d_path() == "dlpack" and arr.flags.c_contiguous
+            and arr.flags.writeable):
+        return jax.dlpack.from_dlpack(arr)
     return jax.device_put(arr)
 
 

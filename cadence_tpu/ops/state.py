@@ -20,8 +20,10 @@ ApplyEvents (which aborts that workflow's replay transaction).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from ..core.checksum import DEFAULT_LAYOUT, PAD, PayloadLayout
@@ -182,9 +184,17 @@ def widen_layout(layout: PayloadLayout, factor: int) -> PayloadLayout:
     )
 
 
+@partial(jax.jit, static_argnames=("num_workflows", "layout"))
 def init_state(num_workflows: int, layout: PayloadLayout = DEFAULT_LAYOUT) -> ReplayState:
     """Fresh state for W workflows, matching the oracle's ExecutionInfo
-    defaults (oracle/mutable_state.py ExecutionInfo / NewMutableStateBuilder)."""
+    defaults (oracle/mutable_state.py ExecutionInfo / NewMutableStateBuilder).
+
+    Jitted as ONE program per (W, layout): called eagerly — the serving
+    tier's pad rows, warm-up and cold admits do — every `jnp.zeros` /
+    `jnp.full` below would otherwise be an XLA executable of its own per
+    distinct (shape, dtype, value), and on the TPU each of those tiny
+    compiles costs seconds (measured on a v5e: 330 of them in one host's
+    boot warm-up). Inside another jit this inlines, as before."""
     W = num_workflows
 
     def full(shape, value, dtype=I64):

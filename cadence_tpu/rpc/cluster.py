@@ -254,7 +254,11 @@ class Cluster:
 
 
 def _wait_listening(port: int, proc: subprocess.Popen,
-                    timeout: float = 30.0) -> None:
+                    timeout: float = 120.0) -> None:
+    """Block until the process answers a ping. The bound covers a host
+    that opens an accelerator before it listens (a device-tier host
+    states its platform at boot, and reaching a chip takes a quarter of
+    a minute); a process that dies first is reported at once."""
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         if proc.poll() is not None:
@@ -409,6 +413,93 @@ def _role_env(env_extra, env_per_role, role: str, generic: str):
     return env
 
 
+#: the switch that makes a process of each role initialise a JAX
+#: backend at boot (engine/serving.ENABLE_ENV on a service host,
+#: engine/visibility_device.VIS_ENV in the store server). Named here
+#: because this module must stay importable without JAX.
+_DEVICE_TIER_ENV = {"host": "CADENCE_TPU_SERVING",
+                    "store": "CADENCE_TPU_VISIBILITY"}
+
+
+def child_env(role: str, generic: str, env_extra=None,
+              env_per_role=None) -> Dict[str, str]:
+    """The full environment of one spawned process: the launcher's own,
+    the repo on PYTHONPATH, then the role overlays.
+
+    One process for each chip. The STORE SERVER is pinned to XLA's CPU
+    backend by role: it does no device work of its own, and in a wire
+    cluster the chip belongs to the service host that runs the serving
+    tier. A service host inherits the platform untouched — JAX picks
+    the accelerator when one is attached and fails at start-up when the
+    named platform cannot load. Nothing here defaults a host to the
+    CPU; test runs get their CPU from the JAX_PLATFORMS=cpu that
+    tests/conftest.py puts into the launcher's environment.
+
+    The compile cache follows utils/compile_cache's rule by inheritance:
+    JAX_COMPILATION_CACHE_DIR passes through when set, and when it is
+    not, parent and child compute the same in-checkout directory."""
+    env = dict(os.environ)
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
+    if generic == "store":
+        env["JAX_PLATFORMS"] = "cpu"
+    env.update(_role_env(env_extra, env_per_role, role, generic))
+    return env
+
+
+def _takes_accelerator(env: Dict[str, str], generic: str) -> bool:
+    """True when a process with this environment will initialise a
+    backend other than the CPU's at boot: its role's device tier is
+    switched on and its platform is not pinned to cpu."""
+    tier = env.get(_DEVICE_TIER_ENV[generic], "").strip().lower()
+    if tier in ("", "0", "false", "off", "no"):
+        return False
+    return env.get("JAX_PLATFORMS", "").strip().lower() != "cpu"
+
+
+def check_one_process_per_chip(envs: Dict[str, Tuple[str, Dict[str, str]]]
+                               ) -> None:
+    """Refuse a cluster in which two processes would initialise the same
+    accelerator. `envs` maps process name → (generic role, environment).
+
+    A chip belongs to one process at a time: the second process to open
+    it dies at start-up inside libtpu ("Unable to initialize backend
+    'tpu' ... libtpu multi-process lockfile", as a v5e host answered),
+    and all the launcher would see is a host that exited before it
+    listened."""
+    takers = sorted(name for name, (generic, env) in envs.items()
+                    if _takes_accelerator(env, generic))
+    if len(takers) < 2:
+        return
+    raise ValueError(
+        "one process per chip: " + ", ".join(takers) + " would each "
+        "initialise the accelerator (a device tier is on and "
+        "JAX_PLATFORMS is not cpu), and a chip belongs to one process. "
+        "Turn the device tier on in one process only, or pin the others "
+        "to the CPU backend with JAX_PLATFORMS=cpu through env_per_role; "
+        "the store server's visibility tier and a host's serving tier "
+        "cannot share a chip either.")
+
+
+def _host_names(cluster_name: str, num_hosts: int, grouped: bool
+                ) -> List[str]:
+    """Host names carry the cluster prefix inside a cluster group."""
+    return [f"{cluster_name}-host-{i}" if grouped else f"host-{i}"
+            for i in range(num_hosts)]
+
+
+def _fleet_envs(host_names, store_name: str, env_extra, env_per_role
+                ) -> Dict[str, Tuple[str, Dict[str, str]]]:
+    """Every process of one cluster → (generic role, its environment):
+    what check_one_process_per_chip reads and Popen is handed."""
+    envs = {name: ("host", child_env(name, "host", env_extra, env_per_role))
+            for name in host_names}
+    envs[store_name] = ("store", child_env("store", "store", env_extra,
+                                           env_per_role))
+    return envs
+
+
 def launch_group(cluster_names=("primary", "standby"), num_hosts: int = 2,
                  num_shards: int = 8, hb_interval: float = 0.15,
                  ttl: float = 3.0, env_extra=None,
@@ -425,6 +516,13 @@ def launch_group(cluster_names=("primary", "standby"), num_hosts: int = 2,
     per cluster name) — the region-failover scenario relaunches a
     kill -9'd region's store from its WAL for post-mortem verification."""
     store_ports = {name: free_port() for name in cluster_names}
+    # the regions of one group share this machine's chips: check the
+    # whole group before any region starts (launch() checks each alone)
+    envs = {}
+    for name in cluster_names:
+        envs.update(_fleet_envs(_host_names(name, num_hosts, True),
+                                f"{name}-store", env_extra, env_per_role))
+    check_one_process_per_chip(envs)
     clusters: Dict[str, Cluster] = {}
     try:
         for name in cluster_names:
@@ -459,20 +557,17 @@ def launch(num_hosts: int = 2, num_shards: int = 8, wal: str = "",
     "store", "host" (every service host), or an exact host name
     ("host-0"; with peer_specs, "<cluster>-host-0") — the loadgen hands
     each host its own CADENCE_TPU_QUOTAS knobs through this seam."""
-    base_env = dict(os.environ)
-    base_env.setdefault("JAX_PLATFORMS", "cpu")  # control-plane processes
-    repo = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    base_env["PYTHONPATH"] = repo + os.pathsep + base_env.get(
-        "PYTHONPATH", "")
-
     store_port = store_port or free_port()
     store_cmd = [sys.executable, "-m", "cadence_tpu.rpc.storeserver",
                  "--port", str(store_port)]
     if wal:
         store_cmd += ["--wal", wal]
-    store_env = dict(base_env)
-    store_env.update(_role_env(env_extra, env_per_role, "store", "store"))
+    host_names = _host_names(cluster_name, num_hosts, bool(peer_specs))
+    # every process's environment is settled, and the fleet checked
+    # against the chip, before the first process starts
+    envs = _fleet_envs(host_names, "store", env_extra, env_per_role)
+    check_one_process_per_chip(envs)
+    store_env = envs["store"][1]
     store_proc = subprocess.Popen(store_cmd, env=store_env)
     _wait_listening(store_port, store_proc)
 
@@ -494,12 +589,13 @@ def launch(num_hosts: int = 2, num_shards: int = 8, wal: str = "",
                "--http-port", str(http_port)]
         for spec in peer_specs:
             cmd += ["--peer", spec]
-        host_env = dict(base_env)
-        host_env.update(_role_env(env_extra, env_per_role, name, "host"))
-        return port, http_port, subprocess.Popen(cmd, env=host_env)
+        if name not in envs:  # add_host: the grown fleet, same rule
+            grown = _fleet_envs([name], "store", env_extra, env_per_role)
+            check_one_process_per_chip({**envs, **grown})
+            envs.update(grown)
+        return port, http_port, subprocess.Popen(cmd, env=envs[name][1])
 
-    for i in range(num_hosts):
-        name = f"{cluster_name}-host-{i}" if peer_specs else f"host-{i}"
+    for name in host_names:
         hosts[name], http_ports[name], procs[name] = spawn_host(name)
     for name, port in hosts.items():
         _wait_listening(port, procs[name])

@@ -306,7 +306,10 @@ class ServiceHost(socketserver.ThreadingTCPServer):
                        cm.M_SERVING_COALESCED, cm.M_SERVING_DIVERGENCE,
                        cm.M_SERVING_EXACT, cm.M_SERVING_SUFFIX,
                        cm.M_SERVING_COLD, cm.M_SERVING_BYPASSED,
-                       cm.M_SERVING_REQUEUED, cm.M_SERVING_REJECTED):
+                       cm.M_SERVING_REQUEUED, cm.M_SERVING_REJECTED,
+                       cm.M_SERVING_TICKETS_OK,
+                       cm.M_SERVING_TICKETS_FAILED,
+                       cm.M_SERVING_HANDOFF_FAILED):
             self.metrics.inc(cm.SCOPE_TPU_SERVING, metric, 0)
         self.metrics.gauge(cm.SCOPE_TPU_SERVING, cm.M_SERVING_QUEUE_DEPTH,
                            0.0)
@@ -328,7 +331,20 @@ class ServiceHost(socketserver.ThreadingTCPServer):
         self.serving = None
         self.tpu = None
         self.migration = None
+        #: the backend this host opened, as JAX reports it — stated at
+        #: boot (the host-boot flight-recorder event) and on /health, so
+        #: "which device is this host's tier on" is read, never
+        #: inferred. None while the host has opened no backend: a host
+        #: without a device tier touches JAX only if a reset or rebuild
+        #: routes to it. A backend that cannot load ends the process
+        #: here, before it listens.
+        self.device: Optional[Dict[str, object]] = None
         if serving_mod.enabled():
+            import jax
+            devices = jax.devices()
+            self.device = {"platform": devices[0].platform,
+                           "kind": devices[0].device_kind,
+                           "count": len(devices)}
             from ..engine.tpu_engine import TPUReplayEngine
             tpu = TPUReplayEngine(self.stores, self.config.payload_layout())
             tpu.metrics = self.metrics
@@ -358,13 +374,18 @@ class ServiceHost(socketserver.ThreadingTCPServer):
         # anyway; `serving_warmed` is surfaced in the admin_cluster doc
         # so deploys/scenarios can hold traffic until the fleet is hot
         self.serving_warmed = self.serving is None
+        #: why the boot warm-up failed, if it did ("" = it did not): a
+        #: kernel the backend refuses to compile must show in the
+        #: admin_cluster doc, not vanish in a daemon thread
+        self.serving_warm_error = ""
         if self.serving is not None and serving_mod.warm_on_boot():
             def _warm_serving():
                 try:
                     self.serving.warm(
                         e_shapes=serving_mod.warm_event_shapes())
-                except Exception:
-                    pass
+                except Exception as exc:
+                    self.serving_warm_error = \
+                        f"{type(exc).__name__}: {exc}"
                 self.serving_warmed = True
             threading.Thread(target=_warm_serving, daemon=True,
                              name="cadence-serving-warm").start()
@@ -674,6 +695,8 @@ class ServiceHost(socketserver.ThreadingTCPServer):
             "serving": (self.serving.stats()
                         if self.serving is not None else None),
             "serving_warmed": bool(self.serving_warmed),
+            "serving_warm_error": self.serving_warm_error,
+            "device": self.device,
             "resident": (self.tpu.resident.stats()
                          if self.tpu is not None else None),
             "migration": (self.migration.stats()
@@ -724,6 +747,7 @@ class ServiceHost(socketserver.ThreadingTCPServer):
     def _health(self) -> Dict[str, object]:
         return {"status": "ok", "name": self.name,
                 "cluster": self.cluster_name,
+                "device": self.device,
                 "owned_shards": sorted(self.controller.owned_shards()),
                 "ring": sorted(self.ring.members())}
 
@@ -794,7 +818,7 @@ class ServiceHost(socketserver.ThreadingTCPServer):
         flightrecorder.install_dump_handlers()
         flightrecorder.emit("host-boot", host=self.name,
                             cluster=self.cluster_name, port=self.port,
-                            shards=self.num_shards)
+                            shards=self.num_shards, device=self.device)
         self.refresh_membership()
         self._beat_thread.start()
         self._pump_thread.start()

@@ -160,6 +160,21 @@ def serve(port: int, wal: str = "", host: str = "127.0.0.1",
             stores = open_durable_stores(wal)
     else:
         stores = Stores()
+    from ..engine import visibility_device
+    if visibility_device.enabled():
+        # the visibility view scans on THIS process's backend — XLA's
+        # CPU under rpc.cluster.launch, which pins the store server by
+        # role. Open it before listening and say which it is, as a
+        # serving host does on /health: a view that scans on the CPU
+        # must not pass for one on the chip, and a backend that cannot
+        # load ends the process here
+        import sys
+
+        import jax
+        devices = jax.devices()
+        print("cadence-tpu-store: the visibility device view runs on "
+              f"backend {devices[0].platform} ({devices[0].device_kind}, "
+              f"{len(devices)} device(s))", file=sys.stderr, flush=True)
     fault_spec = fault_spec or os.environ.get(STORE_FAULTS_ENV, "")
     if fault_spec:
         from ..engine.faults import inject_faults

@@ -274,6 +274,15 @@ M_SERVING_BYPASSED = "bypassed"
 M_SERVING_REQUEUED = "requeued"
 M_SERVING_REJECTED = "busy-rejections"
 M_SERVING_QUEUE_DEPTH = "queue-depth"
+#: how every handed transaction ENDED: one ticket per submit (folded
+#: ones included), resolved ok — device state maintained and equal to
+#: the oracle's row — or not. The oracle commits first, so an RPC can
+#: succeed while its device flush failed; these two are what says so.
+M_SERVING_TICKETS_OK = "tickets-ok"
+M_SERVING_TICKETS_FAILED = "tickets-failed"
+#: committed transactions the engine could NOT hand to the tier for a
+#: reason other than a full queue (that one is `busy-rejections`)
+M_SERVING_HANDOFF_FAILED = "handoff-failures"
 #: persisted mutable-state snapshot tier (engine/snapshot.py,
 #: SCOPE_TPU_SNAPSHOT): `writes` counts checksum-gated snapshot records
 #: appended to the WAL, `checksum-skips` counts writes refused because

@@ -11,6 +11,9 @@ set -euo pipefail
 REPO="$(cd "$(dirname "$0")/.." && pwd)"
 RUN_DIR="${CADENCE_TPU_RUN_DIR:-/tmp/cadence_tpu_cluster}"
 PIDS="$RUN_DIR/pids"
+# A development topology: several service hosts on one machine, so it
+# runs on XLA's CPU backend unless told otherwise (a chip belongs to one
+# process — see README "Running"). Not a path any chip run takes.
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 export PYTHONPATH="$REPO${PYTHONPATH:+:$PYTHONPATH}"
 

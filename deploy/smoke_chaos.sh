@@ -7,6 +7,8 @@
 # `chaos`; wired like deploy/smoke_observability.sh).
 #
 # Usage: deploy/smoke_chaos.sh [extra pytest args]
+# CPU gate: it checks parity, counts and SLOs on XLA's CPU backend and no
+# device rate; the run on the accelerator is `python chip_smoke.py`.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 exec env JAX_PLATFORMS=cpu python -m pytest tests/test_chaos_soak.py \

@@ -9,6 +9,8 @@
 # --seed-workload 4`).
 #
 # Usage: deploy/smoke_crash.sh [extra pytest args]
+# CPU gate: it checks parity, counts and SLOs on XLA's CPU backend and no
+# device rate; the run on the accelerator is `python chip_smoke.py`.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 exec env JAX_PLATFORMS=cpu python -m pytest tests/test_crashsim.py \

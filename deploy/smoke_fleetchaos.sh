@@ -13,6 +13,8 @@
 # kill-then-signal regression to its 1-minimal 2-op campaign.
 #
 # Usage: deploy/smoke_fleetchaos.sh [extra `fuzz cluster` args]
+# CPU gate: it checks parity, counts and SLOs on XLA's CPU backend and no
+# device rate; the run on the accelerator is `python chip_smoke.py`.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 exec env JAX_PLATFORMS=cpu python -m cadence_tpu fuzz cluster \

@@ -12,6 +12,8 @@
 # trajectory next to the BENCH/LOADGEN files.
 #
 # Usage: deploy/smoke_fuzz.sh [extra `fuzz run` args]
+# CPU gate: it checks parity, counts and SLOs on XLA's CPU backend and no
+# device rate; the run on the accelerator is `python chip_smoke.py`.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 exec env JAX_PLATFORMS=cpu python -m cadence_tpu fuzz run \

@@ -15,6 +15,8 @@
 # scenario duration/SLO are env-tunable (LOADGEN_DURATION_S, LOADGEN_*).
 #
 # Usage: deploy/smoke_load.sh [extra pytest args]
+# CPU gate: it checks parity, counts and SLOs on XLA's CPU backend and no
+# device rate; the run on the accelerator is `python chip_smoke.py`.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 exec env JAX_PLATFORMS=cpu \

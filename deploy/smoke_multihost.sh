@@ -22,6 +22,8 @@
 # persistent JAX cache.
 #
 # Usage: deploy/smoke_multihost.sh [extra pytest args]
+# CPU gate: it checks parity, counts and SLOs on XLA's CPU backend and no
+# device rate; the run on the accelerator is `python chip_smoke.py`.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 exec env JAX_PLATFORMS=cpu \

@@ -24,6 +24,8 @@
 # compiles once into the persistent JAX cache.
 #
 # Usage: deploy/smoke_multiregion.sh [extra pytest args]
+# CPU gate: it checks parity, counts and SLOs on XLA's CPU backend and no
+# device rate; the run on the accelerator is `python chip_smoke.py`.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 exec env JAX_PLATFORMS=cpu \

@@ -60,6 +60,8 @@
 # The assertions live in tests/test_perf_gate.py, marked `perf`.
 #
 # Usage: deploy/smoke_perf.sh [baseline.json] [extra pytest args]
+# CPU gate: it checks parity, counts and SLOs on XLA's CPU backend and no
+# device rate; the run on the accelerator is `python chip_smoke.py`.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

@@ -96,19 +96,23 @@ class TestGenerator:
         with pytest.raises(ValueError):
             generate_and_replay_sharded(11, 0, 65, E, mesh)
 
+    @pytest.mark.parametrize("n", [1, 8])
+    def test_sharded_crc_equals_single_device(self, n):
+        """The north-star form (bench.py, chip_smoke.py): the shard_map
+        kernel reduced to CRCs on device. The CRC scan's initial carry
+        has to vary across the mesh axis like the rows it hashes, or
+        shard_map's typing rejects the scan — on a mesh of 1 too."""
+        import jax
 
-def test_persistent_compile_cache_config_applied(tmp_path):
-    """enable() must set the post-import jax config — the env var alone
-    is frozen unread on hosts whose site bootstrap imports jax first
-    (VERDICT r4 #7: every process paid the ~50s compile)."""
-    import jax
+        from cadence_tpu.core.checksum import crc32_of_rows
+        from cadence_tpu.ops.genkernel import (
+            generate_and_replay_sharded_crc,
+        )
+        from cadence_tpu.parallel.mesh import make_mesh
 
-    from cadence_tpu.utils import compile_cache
-
-    before = jax.config.jax_compilation_cache_dir
-    try:
-        used = compile_cache.enable(str(tmp_path / "cache"))
-        assert jax.config.jax_compilation_cache_dir == used
-        assert (tmp_path / "cache").is_dir()
-    finally:
-        jax.config.update("jax_compilation_cache_dir", before)
+        mesh = make_mesh(jax.devices()[:n])
+        crc_s, err_s = map(np.asarray, generate_and_replay_sharded_crc(
+            11, 0, 64, E, mesh))
+        rows_1, err_1 = map(np.asarray, generate_and_replay(11, 0, 64, E))
+        assert (err_s == err_1).all()
+        assert (crc_s.astype(np.uint32) == crc32_of_rows(rows_1)).all()

@@ -76,6 +76,19 @@ class TestWireCluster:
         assert ms.execution_info.state == WorkflowState.Completed
         assert ms.execution_info.close_status == CloseStatus.Completed
 
+    def test_hosts_without_a_device_tier_open_no_backend(self, cluster):
+        """/health states the backend a host opened: none, for a host
+        with no device tier on (it touches JAX only if a reset or a
+        rebuild routes to it)."""
+        import json
+        import urllib.request
+
+        for name, port in cluster.http_ports.items():
+            with urllib.request.urlopen(
+                    f"http://127.0.0.1:{port}/health", timeout=10) as resp:
+                doc = json.loads(resp.read())
+            assert doc["name"] == name and doc["device"] is None
+
     def test_cross_process_range_fence(self, cluster):
         """A usurper (this test process) acquires a shard through the store
         server; the old owner's CACHED engine then writes through its stale

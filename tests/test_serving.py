@@ -106,6 +106,37 @@ class TestSchedulerSeam:
         assert h.counter(m.M_SERVING_SUFFIX) == 1
         assert h.counter(m.M_SERVING_DIVERGENCE) == 0
 
+    def test_warmed_host_stacks_any_row_count_without_a_new_program(self):
+        """A suffix flush stacks its k resident rows to the flush WIDTH
+        (resident._stack_padded), so after warm() the stack program
+        exists whatever k the drain window holds — one program per
+        width, not per row count (a per-count program is a mid-window
+        compile on the CPU and minutes of boot warm-up on a TPU)."""
+        from cadence_tpu.engine import resident
+
+        h = _Harness(workflows=5)
+        for k in h.keys:
+            h.counts[k] = len(h.by_key[k]) - 2
+            h.submit(k)
+        h.flush()  # seed five residents
+        def stack_programs():
+            fn = resident._STACK_FN  # built on first use
+            return fn._cache_size() if fn is not None else 0
+
+        before = stack_programs()
+        h.sched.warm(e_shapes=(16,), width=8)
+        programs = stack_programs()
+        assert programs - before <= 1  # one width warmed
+        for rows in (1, 3, 5):
+            tickets = []
+            for k in h.keys[:rows]:
+                h.counts[k] = min(h.counts[k] + 1, len(h.by_key[k]))
+                tickets.append(h.submit(k))
+            h.flush()
+            assert all(t.result(timeout=1).ok for t in tickets)
+        assert h.counter(m.M_SERVING_SUFFIX) >= 5
+        assert stack_programs() == programs
+
     def test_same_key_transactions_coalesce_into_one_pass(self):
         h = _Harness(workflows=1)
         k = h.keys[0]
