@@ -8,7 +8,6 @@ CLI consumes (tools/cli admin commands).
 from __future__ import annotations
 
 import json
-import time
 import urllib.request
 from collections import Counter
 from typing import Any, Dict, List, Optional
@@ -338,19 +337,11 @@ class AdminHandler:
         return sampler.doc(last_n)
 
     def hostprof(self, duration_s: float = 0.5) -> Dict[str, Any]:
-        """Host-runtime attribution (`admin hostprof` in-process arm).
-        When the box's profiler thread runs, report what it has; else
-        burst-sample this process for `duration_s` first."""
+        """Host-runtime attribution (`admin hostprof` in-process arm):
+        sample this process for `duration_s`, then roll up."""
         self._authorize("hostprof")
-        profiler = self.box.hostprof
-        if profiler._thread is None or not profiler._thread.is_alive():
-            deadline = time.monotonic() + max(0.0, duration_s)
-            while True:
-                profiler.sample_once()
-                if time.monotonic() >= deadline:
-                    break
-                time.sleep(profiler.period_s)
-        return profiler.rollup()
+        # one sample at least, as before, whatever the duration
+        return self.box.hostprof.rollup_after(max(duration_s, 1e-9))
 
     def flightrec(self, last_n: int = 100,
                   dump: Optional[str] = None) -> Dict[str, Any]:

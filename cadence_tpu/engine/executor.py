@@ -39,7 +39,7 @@ ring discipline generalizes per device: a ring slot frees only when the
 chunk that last used it has fully replayed on EVERY shard of the mesh,
 so no device's in-flight slice copy can be overwritten. Per-device
 observability lands under `tpu.executor/*` (chunks-dispatched and
-device-busy carry a -dev{d} series per mesh position) next to the
+launches-in-flight carry a -dev{d} series per mesh position) next to the
 aggregate pack-queue-wait. A mesh of 1 is byte-identical to the
 single-chip executor — the serving path and the multichip diagnostic
 are the same code at every N.
@@ -59,6 +59,10 @@ from ..utils.profiler import ReplayProfiler
 #: pool run ahead of the device by more than one chunk
 DEPTH_ENV = "CADENCE_TPU_PIPELINE_DEPTH"
 DEFAULT_DEPTH = 3
+
+#: the timeline name of the consumer's wait on chunk 0, in which nothing
+#: is in flight yet (its histogram stays pack-queue-wait)
+FIRST_CHUNK_WAIT_SPAN = "feed.first-chunk-wait"
 
 
 def pipeline_depth(depth: Optional[int] = None) -> int:
@@ -138,14 +142,15 @@ class BulkReplayExecutor:
         in_flight = [0]
 
         def busy(delta: int) -> None:
-            # in-flight chunk count as the device-busy gauge; in SPMD
-            # every mesh position carries a slice of each in-flight
-            # chunk, so the per-device series share the value — the
-            # point is the LABELS exist for dashboards keyed by device
+            # chunks launched and not yet read back: a host-side count,
+            # not a device share. In SPMD every mesh position carries a
+            # slice of each in-flight chunk, so the per-device series
+            # share the value — the point is the LABELS exist for
+            # dashboards keyed by device
             in_flight[0] += delta
-            exec_scope.gauge(m.M_EXEC_DEVICE_BUSY, float(in_flight[0]))
+            exec_scope.gauge(m.M_EXEC_IN_FLIGHT, float(in_flight[0]))
             for d in range(self._n_dev):
-                exec_scope.gauge(m.device_metric(m.M_EXEC_DEVICE_BUSY, d),
+                exec_scope.gauge(m.device_metric(m.M_EXEC_IN_FLIGHT, d),
                                  float(in_flight[0]))
 
         outs: List[Any] = [None] * num_chunks
@@ -169,11 +174,9 @@ class BulkReplayExecutor:
                 jax.block_until_ready(prior)
                 del prior
                 launched.pop(ci - self.depth, None)
-            t0 = time.perf_counter()
-            packed = pack_fn(ci)
-            dt = time.perf_counter() - t0
-            prof.observe(m.M_PROFILE_PACK, dt)
-            return packed, dt
+            with prof.leg(m.M_PROFILE_PACK) as leg:
+                packed = pack_fn(ci)
+            return packed, leg.duration_s
 
         t_start = time.perf_counter()
         with ThreadPoolExecutor(
@@ -182,11 +185,12 @@ class BulkReplayExecutor:
             futs = [pool.submit(pack_task, ci) for ci in range(num_chunks)]
             try:
                 for ci in range(num_chunks):
-                    t0 = time.perf_counter()
-                    packed, pack_dt = futs[ci].result()
-                    wait = time.perf_counter() - t0
+                    with prof.leg(m.M_PROFILE_PACK_WAIT,
+                                  span=FIRST_CHUNK_WAIT_SPAN
+                                  if ci == 0 else None) as leg:
+                        packed, pack_dt = futs[ci].result()
+                    wait = leg.duration_s
                     report.pack_queue_wait_s += wait
-                    prof.observe(m.M_PROFILE_PACK_WAIT, wait)
                     self.registry.observe(m.SCOPE_TPU_EXECUTOR,
                                           m.M_PROFILE_PACK_WAIT, wait)
                     report.pack_s += pack_dt
@@ -277,7 +281,6 @@ def replay_corpus_mesh(events, mesh=None, layout=None,
     from ..ops.encode import LANE_EVENT_ID, LANE_EVENT_TYPE
     from ..parallel.mesh import place_corpus, serving_mesh
     from ..utils import compile_cache
-    from ..utils.profiler import ReplayProfiler
 
     if layout is None:
         layout = DEFAULT_LAYOUT

@@ -281,6 +281,7 @@ class Frontend:
                                                  signal_name, run_id,
                                                  request_id=request_id)
 
+    @tracing.traced(m.SCOPE_FRONTEND_SIGNAL_WITH_START)
     def signal_with_start_workflow_execution(
             self, domain: str, workflow_id: str, signal_name: str,
             workflow_type: str, task_list: str,

@@ -229,12 +229,13 @@ class HistoryEngine:
         info = ms.execution_info
         key = (info.domain_id, info.workflow_id, info.run_id)
         try:
-            row = payload_row(ms, serving.layout)
-            # sticky state is active-side only; replay clears it
-            row[STICKY_ROW_INDEX] = 0
-            self.last_serving_ticket = serving.submit(
-                key, row, int(ms.version_histories.current_index),
-                zlib.crc32(events_blob), batch=batch)
+            with tracing.span("history.hand-to-serving"):
+                row = payload_row(ms, serving.layout)
+                # sticky state is active-side only; replay clears it
+                row[STICKY_ROW_INDEX] = 0
+                self.last_serving_ticket = serving.submit(
+                    key, row, int(ms.version_histories.current_index),
+                    zlib.crc32(events_blob), batch=batch)
         except ServiceBusyError:
             self.last_serving_ticket = None
         except Exception:
@@ -459,11 +460,12 @@ class HistoryEngine:
         # orphan history under a never-registered run ID — harmless; the
         # execution row is the commit point, so a retried start (fresh run
         # ID) starts clean
-        self.shard.append_history(domain_id, workflow_id, run_id, events,
-                                  blob=start_blob)
-        self.shard.insert_tasks(domain_id, workflow_id, run_id,
-                                ms.transfer_tasks, ms.timer_tasks)
-        self.shard.create_workflow(ms)  # commit point
+        with tracing.span("history.commit"):
+            self.shard.append_history(domain_id, workflow_id, run_id, events,
+                                      blob=start_blob)
+            self.shard.insert_tasks(domain_id, workflow_id, run_id,
+                                    ms.transfer_tasks, ms.timer_tasks)
+            self.shard.create_workflow(ms)  # commit point
         ms.transfer_tasks, ms.timer_tasks = [], []
         self._publish_replication(domain_id, workflow_id, run_id, events, ms)
         self.notifier.notify((domain_id, workflow_id, run_id),
@@ -1720,9 +1722,10 @@ class _Txn:
         # CAS, so a concurrent writer of the same workflow fails before
         # it can clobber this transaction's committed tail.
         try:
-            version = self.engine.shard.commit_workflow(
-                self.ms, expected_next_event_id, self.events,
-                new_transfer, new_timer, events_blob=events_blob)
+            with tracing.span("history.commit"):
+                version = self.engine.shard.commit_workflow(
+                    self.ms, expected_next_event_id, self.events,
+                    new_transfer, new_timer, events_blob=events_blob)
         except Exception:
             # the entry that fed this transaction may be stale (a foreign
             # writer won) — drop it so the caller's retry reads fresh

@@ -517,7 +517,10 @@ class MatchingEngine:
                                            identity=identity)
         if task is None and wait_seconds > 0:
             parked = self.park_for_decision_task(domain_id, task_list)
-            parked.done.wait(wait_seconds)
+            # the park is its own span, so that a long poll's self time is
+            # work and not waiting
+            with tracing.span("matching.poll-wait"):
+                parked.done.wait(wait_seconds)
             if parked.task is None:
                 parked.cancel()
             task = parked.task
@@ -530,7 +533,8 @@ class MatchingEngine:
                                            identity=identity)
         if task is None and wait_seconds > 0:
             parked = self.park_for_activity_task(domain_id, task_list)
-            parked.done.wait(wait_seconds)
+            with tracing.span("matching.poll-wait"):
+                parked.done.wait(wait_seconds)
             if parked.task is None:
                 parked.cancel()
             task = parked.task

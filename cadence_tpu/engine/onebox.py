@@ -269,7 +269,7 @@ class Onebox:
         return ObservabilityHTTPServer(self.metrics, health_fn=health,
                                        tracer=self.tracer, address=address,
                                        timeseries_fn=timeseries_doc,
-                                       hostprof_fn=self.hostprof.rollup,
+                                       hostprof_fn=self.hostprof.rollup_after,
                                        flightrec_fn=flightrec_doc)
 
     # -- recovery ----------------------------------------------------------

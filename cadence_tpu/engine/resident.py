@@ -67,6 +67,7 @@ import numpy as np
 from ..core.checksum import DEFAULT_LAYOUT, PayloadLayout
 from ..ops.encode import NUM_LANES, history_length
 from ..utils import metrics as m
+from ..utils import tracing
 from .cache import ContentAddress, address_relation, content_address
 
 #: HBM byte budget for resident states (LRU evicts past it); the default
@@ -550,10 +551,13 @@ class ResidentStateCache:
 
         def consume(ci, packed):
             corpus, (s_fin, rows_dev, err_dev, ovf_dev) = packed
-            jax.block_until_ready(rows_dev)
-            return (corpus, s_fin, np.asarray(rows_dev),
-                    np.asarray(err_dev), np.asarray(ovf_dev),
-                    np.asarray(s_fin.current_branch))
+            # the blocking wait and the readback: what a flush of the
+            # serving tier spends on the device, not on the host
+            with tracing.span("resident.device-wait"):
+                jax.block_until_ready(rows_dev)
+                return (corpus, s_fin, np.asarray(rows_dev),
+                        np.asarray(err_dev), np.asarray(ovf_dev),
+                        np.asarray(s_fin.current_branch))
 
         chunk_outs, _report = executor.run(len(spans), pack, launch, consume)
 

@@ -71,6 +71,7 @@ from ..core.checksum import crc32_of_row
 from ..utils import compile_cache
 from ..utils import flightrecorder
 from ..utils import metrics as m
+from ..utils import tracing
 from ..utils.profiler import ReplayProfiler
 from ..utils.quotas import ServiceBusyError
 from .cache import ContentAddress, batch_crc, content_address
@@ -418,8 +419,11 @@ class ServingScheduler:
         is still filling (up to max_wait_us / max_batch), then pop one
         flush batch FIFO. Returns None on stop."""
         with self._cv:
-            while not self._stop_flag and not self._pending:
-                self._cv.wait(timeout=0.1)
+            if not self._stop_flag and not self._pending:
+                # idle by design: a device gap under this span is no stall
+                with tracing.span("serving.idle-wait"):
+                    while not self._stop_flag and not self._pending:
+                        self._cv.wait(timeout=0.1)
             if self._stop_flag:
                 return None
         # adaptive window: poll in quarter-wait slices; close as soon as
@@ -453,7 +457,8 @@ class ServingScheduler:
                     return
                 continue
             try:
-                with self._prof.leg(m.M_PROFILE_SERVING):
+                with self._prof.leg(m.M_PROFILE_SERVING,
+                                    span="serving.flush"):
                     self._flush(batch)
             except Exception as exc:  # never kill the drain on one batch
                 for item in batch:
@@ -509,29 +514,8 @@ class ServingScheduler:
         suffix: List[Tuple[tuple, object, tuple]] = []
         suffix_items: List[_Pending] = []
         cold: List[Tuple[_Pending, list]] = []
-        for item in batch:
-            # zero-read chain path: the engine handed the committed
-            # batches and the resident entry's tail is exactly this
-            # chain's prev — the handed batches ARE the suffix, so the
-            # flush touches neither the history store nor the serializer
-            if item.batches is not None and item.prev_crc is not None:
-                entry = self.resident.entry_for(item.key)
-                if entry is not None and \
-                        entry.address.last_batch_crc == item.prev_crc:
-                    new_addr = ContentAddress(
-                        entry.address.batch_count + len(item.batches),
-                        item.tail_crc)
-                    rows = self.pack_cache.encode_append(
-                        item.key, entry.address, item.batches, new_addr)
-                    if rows is not None:
-                        suffix.append((item.key, entry, (rows, new_addr)))
-                        suffix_items.append(item)
-                        continue
-            if self._injected_reads:
-                self._route_full_read(item, suffix, suffix_items, cold)
-            else:
-                self._route_ranged(item, suffix, suffix_items, cold)
-
+        with tracing.span("serving.route"):
+            self._route(batch, suffix, suffix_items, cold)
         if suffix:
             self._flush_suffix(suffix, suffix_items)
         if cold:
@@ -545,6 +529,35 @@ class ServingScheduler:
             coalesced=sum(i.coalesced for i in batch),
             suffix=len(suffix_items), cold=len(cold),
             flush_s=round(dt, 6), queue_depth=len(self._pending))
+
+    def _route(self, batch: List[_Pending], suffix, suffix_items,
+               cold) -> None:
+        """Partition a flush batch into suffix appends and cold admits
+        (exact hits are served on the spot); each encode of suffix rows
+        is a `serving.pack` span."""
+        for item in batch:
+            # zero-read chain path: the engine handed the committed
+            # batches and the resident entry's tail is exactly this
+            # chain's prev — the handed batches ARE the suffix, so the
+            # flush touches neither the history store nor the serializer
+            if item.batches is not None and item.prev_crc is not None:
+                entry = self.resident.entry_for(item.key)
+                if entry is not None and \
+                        entry.address.last_batch_crc == item.prev_crc:
+                    new_addr = ContentAddress(
+                        entry.address.batch_count + len(item.batches),
+                        item.tail_crc)
+                    with tracing.span("serving.pack"):
+                        rows = self.pack_cache.encode_append(
+                            item.key, entry.address, item.batches, new_addr)
+                    if rows is not None:
+                        suffix.append((item.key, entry, (rows, new_addr)))
+                        suffix_items.append(item)
+                        continue
+            if self._injected_reads:
+                self._route_full_read(item, suffix, suffix_items, cold)
+            else:
+                self._route_ranged(item, suffix, suffix_items, cold)
 
     def _route_full_read(self, item: _Pending, suffix, suffix_items,
                          cold) -> None:
@@ -583,8 +596,9 @@ class ServingScheduler:
             self._serve_exact(item, hit[1])
         else:
             entry = hit[1]
-            rows = self.pack_cache.encode_suffix(
-                item.key, batches, entry.address.batch_count)
+            with tracing.span("serving.pack"):
+                rows = self.pack_cache.encode_suffix(
+                    item.key, batches, entry.address.batch_count)
             suffix.append((item.key, entry,
                            (rows, content_address(batches))))
             suffix_items.append(item)
@@ -701,8 +715,9 @@ class ServingScheduler:
             self._serve_exact(item, entry)
             return
         new_addr = ContentAddress(total, tail_crc_now)
-        rows = self.pack_cache.encode_append(key, addr, part[1:],
-                                             new_addr)
+        with tracing.span("serving.pack"):
+            rows = self.pack_cache.encode_append(key, addr, part[1:],
+                                                 new_addr)
         if rows is None:
             # pack entry evicted out from under the resident state: one
             # full pack re-anchors it, then the suffix path proceeds
@@ -768,20 +783,23 @@ class ServingScheduler:
         scope.inc(m.M_SERVING_SUFFIX, len(items))
         scope.inc(m.M_SERVING_LAUNCHES, len(report.chunk_shapes))
         snapshot_due = []
-        for (key, _entry, token), item, res in zip(suffix, items, results):
-            if not res.ok:
-                # entry already invalidated by replay_append; the oracle
-                # stays authoritative and the next transaction cold-admits
+        with tracing.span("serving.parity"):
+            for (key, _entry, token), item, res in zip(suffix, items,
+                                                       results):
+                if not res.ok:
+                    # entry already invalidated by replay_append; the
+                    # oracle stays authoritative and the next transaction
+                    # cold-admits
+                    self._resolve(item, ServingResult(
+                        ok=False, path="suffix", escalated=res.escalated,
+                        error=f"device-error:{res.error}"))
+                    continue
+                parity_ok, crc = self._parity(item, res.payload, res.branch)
                 self._resolve(item, ServingResult(
-                    ok=False, path="suffix", escalated=res.escalated,
-                    error=f"device-error:{res.error}"))
-                continue
-            parity_ok, crc = self._parity(item, res.payload, res.branch)
-            self._resolve(item, ServingResult(
-                ok=parity_ok, parity_ok=parity_ok, checksum=crc,
-                path="suffix", escalated=res.escalated))
-            if parity_ok:
-                snapshot_due.append((key, int(token[0].shape[0])))
+                    ok=parity_ok, parity_ok=parity_ok, checksum=crc,
+                    path="suffix", escalated=res.escalated))
+                if parity_ok:
+                    snapshot_due.append((key, int(token[0].shape[0])))
         self._maybe_snapshot(snapshot_due)
 
     def _cold_fn(self, Wp: int, E: int):
@@ -824,8 +842,9 @@ class ServingScheduler:
             groups.setdefault(self.resident.shard_of(item.key),
                               []).append((item, batches))
         for shard, grp in sorted(groups.items()):
-            rows_list = [self.pack_cache.encode(item.key, batches)
-                         for item, batches in grp]
+            with tracing.span("serving.pack"):
+                rows_list = [self.pack_cache.encode(item.key, batches)
+                             for item, batches in grp]
             E = _bucket(max((r.shape[0] for r in rows_list), default=1), 16)
             Wp = _bucket(len(grp), 8)
             corpus = assemble_corpus(rows_list, E)
@@ -835,14 +854,16 @@ class ServingScheduler:
                 pad[:, :, 1] = -1  # LANE_EVENT_TYPE: no-op padding rows
                 corpus = np.concatenate([corpus, pad])
             device = self.resident.device_of(grp[0][0].key)
-            corpus_dev = jax.device_put(corpus, device)
-            fn = self._cold_fn(Wp, E)
-            state, rows_dev, err_dev, branch_dev = fn(corpus_dev)
-            jax.block_until_ready(rows_dev)
+            with tracing.span("serving.launch"):
+                corpus_dev = jax.device_put(corpus, device)
+                fn = self._cold_fn(Wp, E)
+                state, rows_dev, err_dev, branch_dev = fn(corpus_dev)
+            with tracing.span("serving.device-wait"):
+                jax.block_until_ready(rows_dev)
+                rows = np.asarray(rows_dev)
+                errors = np.asarray(err_dev)
+                branch = np.asarray(branch_dev)
             scope.inc(m.M_SERVING_LAUNCHES)
-            rows = np.asarray(rows_dev)
-            errors = np.asarray(err_dev)
-            branch = np.asarray(branch_dev)
 
             flagged = [j for j in range(len(grp))
                        if errors[j] in CAPACITY_ERRORS]
@@ -855,33 +876,34 @@ class ServingScheduler:
                         ladder_rows[j] = (outcome.rows[k],
                                           int(outcome.branch[k]))
 
-            for j, (item, batches) in enumerate(grp):
-                if errors[j] != 0 and j not in ladder_rows:
-                    self._resolve(item, ServingResult(
-                        ok=False, path="cold",
-                        error=f"device-error:{int(errors[j])}"))
-                    continue
-                if j in ladder_rows:
-                    row_j, br_j = ladder_rows[j]
-                    parity_ok, crc = self._parity(item, row_j, br_j)
+            with tracing.span("serving.parity"):
+                for j, (item, batches) in enumerate(grp):
+                    if errors[j] != 0 and j not in ladder_rows:
+                        self._resolve(item, ServingResult(
+                            ok=False, path="cold",
+                            error=f"device-error:{int(errors[j])}"))
+                        continue
+                    if j in ladder_rows:
+                        row_j, br_j = ladder_rows[j]
+                        parity_ok, crc = self._parity(item, row_j, br_j)
+                        self._resolve(item, ServingResult(
+                            ok=parity_ok, parity_ok=parity_ok, checksum=crc,
+                            path="cold", escalated=True))
+                        continue
+                    self.resident.admit(item.key, content_address(batches),
+                                        self.resident.extract_row(state, j),
+                                        rows[j], int(branch[j]))
+                    scope.inc(m.M_SERVING_COLD)
+                    parity_ok, crc = self._parity(item, rows[j],
+                                                  int(branch[j]))
                     self._resolve(item, ServingResult(
                         ok=parity_ok, parity_ok=parity_ok, checksum=crc,
-                        path="cold", escalated=True))
-                    continue
-                self.resident.admit(item.key, content_address(batches),
-                                    self.resident.extract_row(state, j),
-                                    rows[j], int(branch[j]))
-                scope.inc(m.M_SERVING_COLD)
-                parity_ok, crc = self._parity(item, rows[j],
-                                              int(branch[j]))
-                self._resolve(item, ServingResult(
-                    ok=parity_ok, parity_ok=parity_ok, checksum=crc,
-                    path="cold"))
-                if parity_ok:
-                    # a freshly admitted cold state is the cheapest
-                    # moment to persist: no snapshot exists yet, so
-                    # the policy's first-record rule applies
-                    snapshot_due.append((item.key, 0))
+                        path="cold"))
+                    if parity_ok:
+                        # a freshly admitted cold state is the cheapest
+                        # moment to persist: no snapshot exists yet, so
+                        # the policy's first-record rule applies
+                        snapshot_due.append((item.key, 0))
         self._maybe_snapshot(snapshot_due)
 
     def warm(self, e_shapes: Sequence[int] = (16, 32, 64, 128),
@@ -941,6 +963,7 @@ class ServingScheduler:
         launches = reg.counter(m.SCOPE_TPU_SERVING, m.M_SERVING_LAUNCHES)
         wait = reg.histogram(m.SCOPE_TPU_SERVING, m.M_SERVING_QUEUE_WAIT)
         size = reg.histogram(m.SCOPE_TPU_SERVING, m.M_SERVING_BATCH_SIZE)
+        flush = reg.histogram(self._prof.scope, m.M_PROFILE_SERVING)
         return {
             "enabled": enabled(),
             "max_batch": self.max_batch,
@@ -975,4 +998,9 @@ class ServingScheduler:
             "batch_size_p99": round(size.percentile(0.99), 2),
             "queue_wait_p50_ms": round(wait.percentile(0.5) * 1e3, 3),
             "queue_wait_p99_ms": round(wait.percentile(0.99) * 1e3, 3),
+            # totals that only grow (a reader takes after - before): the
+            # seconds every flushed item stood in the queue before its
+            # flush began, and the seconds spent inside flushes
+            "queue_wait_s_total": wait.total,
+            "flush_s_total": flush.total,
         }

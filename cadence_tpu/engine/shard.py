@@ -12,6 +12,7 @@ import threading
 from typing import List, Optional
 
 from ..oracle.mutable_state import GeneratedTask, MutableState
+from ..utils import tracing
 from .persistence import ShardInfo, ShardOwnershipLostError, Stores
 
 # rangeSizeBits analog: each range owns this many task IDs
@@ -160,7 +161,11 @@ class ShardContext:
         concurrent loser fail BEFORE its append can truncate the winner's
         committed history tail (append_batch node-overwrite semantics)."""
         info = ms.execution_info
-        with self._lock:
+        # the wait for the shard's lock is a span of its own: it ends when
+        # the lock is held, so what follows is work, not waiting
+        with tracing.span("history.lock-wait"):
+            self._lock.acquire()
+        try:
             self._ensure_open()
             self._stores.execution.check_next_event_id(
                 info.domain_id, info.workflow_id, info.run_id,
@@ -170,6 +175,8 @@ class ShardContext:
             self.insert_tasks(info.domain_id, info.workflow_id, info.run_id,
                               transfer, timer)
             return self.update_workflow(ms, expected_next_event_id)
+        finally:
+            self._lock.release()
 
     # -- shard task queues -------------------------------------------------
 

@@ -28,6 +28,7 @@ import numpy as np
 from ..core.checksum import DEFAULT_LAYOUT, PayloadLayout
 from ..engine.executor import BulkReplayExecutor
 from ..utils import metrics as m
+from ..utils import tracing
 from ..utils.profiler import ReplayProfiler
 from . import packing
 
@@ -100,6 +101,7 @@ def _chunk_blobs(blobs: Sequence[bytes], lo: int,
     return chunk
 
 
+@tracing.spanned("feed.call")
 def _feed(blobs: Sequence[bytes], max_events: int, chunk_workflows: int,
           layout: PayloadLayout, num_threads: Optional[int],
           num_lanes: int, dtype, pack_fn, replay_fn,
@@ -114,14 +116,15 @@ def _feed(blobs: Sequence[bytes], max_events: int, chunk_workflows: int,
     copies — the ingest pipeline feeds N devices from one host."""
     import jax
 
-    mesh = _resolve_mesh(mesh)
-    chunk_workflows = _mesh_chunk(chunk_workflows, mesh)
-    total = len(blobs)
-    executor = BulkReplayExecutor(depth=depth, mesh=mesh)
-    report = FeedReport(workflows=total, depth=executor.depth)
-    prof = ReplayProfiler()
-    buffers = [np.empty((chunk_workflows, max_events, num_lanes),
-                        dtype=dtype) for _ in range(executor.depth)]
+    with tracing.span("feed.setup"):
+        mesh = _resolve_mesh(mesh)
+        chunk_workflows = _mesh_chunk(chunk_workflows, mesh)
+        total = len(blobs)
+        executor = BulkReplayExecutor(depth=depth, mesh=mesh)
+        report = FeedReport(workflows=total, depth=executor.depth)
+        prof = ReplayProfiler()
+        buffers = [np.empty((chunk_workflows, max_events, num_lanes),
+                            dtype=dtype) for _ in range(executor.depth)]
     n_chunks = -(-total // chunk_workflows) if total else 0
     chunk_events = [0] * n_chunks
 
@@ -151,8 +154,9 @@ def _feed(blobs: Sequence[bytes], max_events: int, chunk_workflows: int,
 
     start = time.perf_counter()
     results, prep = executor.run(n_chunks, pack, launch, consume)
-    first = np.concatenate([r for r, _ in results])[:total]
-    errors = np.concatenate([e for _, e in results])[:total]
+    with tracing.span("feed.gather"):
+        first = np.concatenate([r for r, _ in results])[:total]
+        errors = np.concatenate([e for _, e in results])[:total]
     report.chunks = prep.chunks
     report.pack_s = prep.pack_s
     report.pack_queue_wait_s = prep.pack_queue_wait_s
@@ -193,6 +197,7 @@ def feed_serialized32(blobs: Sequence[bytes], max_events: int,
                  replay_to_crc32, depth=depth, mesh=mesh)
 
 
+@tracing.spanned("feed.call")
 def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
                           chunk_workflows: int = 4096,
                           layout: PayloadLayout = DEFAULT_LAYOUT,
@@ -230,31 +235,35 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
     from ..utils.concurrency import pack_threads
     from . import wirec as nwirec
 
-    mesh = _resolve_mesh(mesh)
-    chunk_workflows = _mesh_chunk(chunk_workflows, mesh)
-    total = len(blobs)
-    registry = registry if registry is not None else m.DEFAULT_REGISTRY
-    executor = BulkReplayExecutor(depth=depth, mesh=mesh,
-                                  registry=registry)
-    use_native = nwirec.wirec_native_enabled(registry)
-    report = FeedReport(workflows=total, depth=executor.depth,
-                        native_wirec=use_native)
-    prof = ReplayProfiler()
-    n_chunks = -(-total // chunk_workflows) if total else 0
-    # intra-chunk wirec threads: the one CADENCE_TPU_PACK_THREADS knob,
-    # split across the pack pool's concurrent workers
-    wirec_threads = (num_threads if num_threads is not None
-                     else max(1, pack_threads() // executor.depth))
-    if use_native:
-        # reusable staging: lanes scratch + wirec output triple per ring
-        # slot, fully overwritten by every emit (no zeroing, no per-chunk
-        # allocation) — the pinned host buffers the H2D stages from
-        buffers = [nwirec.WirecBuffers(chunk_workflows, max_events)
-                   for _ in range(executor.depth)]
-    else:
-        buffers = [np.empty((chunk_workflows, max_events,
-                             packing.NUM_LANES), dtype=np.int64)
-                   for _ in range(executor.depth)]
+    # what a call does before its first pack task exists: the executor
+    # and the ring's staging buffers
+    with tracing.span("feed.setup"):
+        mesh = _resolve_mesh(mesh)
+        chunk_workflows = _mesh_chunk(chunk_workflows, mesh)
+        total = len(blobs)
+        registry = registry if registry is not None else m.DEFAULT_REGISTRY
+        executor = BulkReplayExecutor(depth=depth, mesh=mesh,
+                                      registry=registry)
+        use_native = nwirec.wirec_native_enabled(registry)
+        report = FeedReport(workflows=total, depth=executor.depth,
+                            native_wirec=use_native)
+        prof = ReplayProfiler()
+        n_chunks = -(-total // chunk_workflows) if total else 0
+        # intra-chunk wirec threads: the one CADENCE_TPU_PACK_THREADS knob,
+        # split across the pack pool's concurrent workers
+        wirec_threads = (num_threads if num_threads is not None
+                         else max(1, pack_threads() // executor.depth))
+        if use_native:
+            # reusable staging: lanes scratch + wirec output triple per
+            # ring slot, fully overwritten by every emit (no zeroing, no
+            # per-chunk allocation) — the pinned host buffers the H2D
+            # stages from
+            buffers = [nwirec.WirecBuffers(chunk_workflows, max_events)
+                       for _ in range(executor.depth)]
+        else:
+            buffers = [np.empty((chunk_workflows, max_events,
+                                 packing.NUM_LANES), dtype=np.int64)
+                       for _ in range(executor.depth)]
 
     # chunk 0 measures the profile; later pack tasks pin the latest plan
     # (a refit replaces it under the lock)
@@ -275,7 +284,8 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
                 shared["profile"] = corpus.profile
             first_profile.set_result(corpus.profile)
             return corpus, 0.0
-        first_profile.result()
+        with tracing.span("pack.first-profile-wait"):
+            first_profile.result()
         with state_lock:
             pinned = shared["profile"]
         try:
@@ -305,7 +315,8 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
                 shared["profile"] = corpus.profile
             first_profile.set_result(corpus.profile)
         else:
-            first_profile.result()
+            with tracing.span("pack.first-profile-wait"):
+                first_profile.result()
             with state_lock:
                 pinned = shared["profile"]
             try:
@@ -367,8 +378,9 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
 
     start = time.perf_counter()
     results, prep = executor.run(n_chunks, pack, launch, consume)
-    first = np.concatenate([r for r, _ in results])[:total]
-    errors = np.concatenate([e for _, e in results])[:total]
+    with tracing.span("feed.gather"):
+        first = np.concatenate([r for r, _ in results])[:total]
+        errors = np.concatenate([e for _, e in results])[:total]
     report.chunks = prep.chunks
     report.pack_queue_wait_s = prep.pack_queue_wait_s
     report.pack_s = shared["pack_s"]

@@ -46,6 +46,7 @@ _TABLES = _make_tables()
 
 
 @jax.jit
+@jax.named_scope("crc32")
 def crc32_rows(rows: jnp.ndarray) -> jnp.ndarray:
     """Per-row IEEE CRC32 of a [W, width] int64 matrix's little-endian
     bytes; bit-identical to core.checksum.crc32_of_rows."""

@@ -8,6 +8,7 @@ the tail, matching the oracle's [sorted reals..., PAD...] layout.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..core.checksum import DEFAULT_LAYOUT, PAD, PayloadLayout
@@ -33,6 +34,7 @@ def payload_rows(s: ReplayState, layout: PayloadLayout = DEFAULT_LAYOUT) -> jnp.
     return rows
 
 
+@jax.named_scope("payload")
 def payload_rows_narrow(s: ReplayState, out_layout: PayloadLayout
                         ) -> "tuple[jnp.ndarray, jnp.ndarray]":
     """Project a (possibly widened-K) state's canonical payload down to

@@ -149,7 +149,9 @@ def replay_wirec(slab: jnp.ndarray, bases: jnp.ndarray,
     def body(carry, xs):
         s, prev = carry
         sl, e_idx = xs
-        ev, prev = decode_step(sl, prev, bases, n_events, e_idx, profile)
+        with jax.named_scope("wirec-decode"):
+            ev, prev = decode_step(sl, prev, bases, n_events, e_idx,
+                                   profile)
         return (step(s, ev), prev), None
 
     (s, _), _ = jax.lax.scan(
@@ -243,7 +245,9 @@ def replay_wirec_from_state(slab: jnp.ndarray, bases: jnp.ndarray,
     def body(carry, xs):
         s, prev = carry
         sl, e_idx = xs
-        ev, prev = decode_step(sl, prev, bases, n_events, e_idx, profile)
+        with jax.named_scope("wirec-decode"):
+            ev, prev = decode_step(sl, prev, bases, n_events, e_idx,
+                                   profile)
         return (step(s, ev), prev), None
 
     (s, _), _ = jax.lax.scan(

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..core.checksum import PAD
@@ -151,6 +152,7 @@ def state_transition_valid(cur_state, cur_close, new_state, new_close):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("transition")   # metadata only: names the phase's ops
 def step(s: ReplayState, ev: jnp.ndarray,
          enable_reset: bool = True) -> ReplayState:
     """Apply one event (lanes [W, L]) to all workflows. Returns new state.

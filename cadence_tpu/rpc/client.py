@@ -161,6 +161,15 @@ class _Pool:
     # -- the resilient call path ------------------------------------------
 
     def call(self, request):
+        """One round trip, retries included. A store call is a span on the
+        calling side, `store.<sub>.<method>`."""
+        if isinstance(request, tuple) and len(request) >= 3 \
+                and request[0] == "store":
+            with tracing.span(f"store.{request[1]}.{request[2]}"):
+                return self._call(request)
+        return self._call(request)
+
+    def _call(self, request):
         breaker = self._breaker
         idempotent = _is_idempotent(request)
         attempt = 0

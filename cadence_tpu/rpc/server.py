@@ -28,7 +28,6 @@ import os
 import socketserver
 import threading
 import time
-from contextlib import nullcontext
 from typing import Dict, Optional, Tuple
 
 from ..engine.controller import ShardController, ShardNotOwnedError
@@ -250,7 +249,7 @@ class ServiceHost(socketserver.ThreadingTCPServer):
         from ..parallel.mesh import mesh_devices_requested
         n_mesh = mesh_devices_requested() or 1
         self.metrics.inc(cm.SCOPE_TPU_EXECUTOR, cm.M_EXEC_CHUNKS, 0)
-        self.metrics.gauge(cm.SCOPE_TPU_EXECUTOR, cm.M_EXEC_DEVICE_BUSY,
+        self.metrics.gauge(cm.SCOPE_TPU_EXECUTOR, cm.M_EXEC_IN_FLIGHT,
                            0.0)
         for d in range(n_mesh):
             self.metrics.inc(
@@ -261,7 +260,7 @@ class ServiceHost(socketserver.ThreadingTCPServer):
                 cm.device_metric(cm.M_EXEC_ROWS, d), 0)
             self.metrics.gauge(
                 cm.SCOPE_TPU_EXECUTOR,
-                cm.device_metric(cm.M_EXEC_DEVICE_BUSY, d), 0.0)
+                cm.device_metric(cm.M_EXEC_IN_FLIGHT, d), 0.0)
         # per-host quota knobs (common/quotas seat): the env var is the
         # subprocess-cluster path (rpc/cluster.launch env_per_role hands
         # each host its own spec — a cluster-wide RPS budget is split
@@ -407,8 +406,9 @@ class ServiceHost(socketserver.ThreadingTCPServer):
         # registry (one host per process in production; in-process test
         # hosts share the ring, which is exactly the interleaved timeline
         # a post-mortem wants); sampler + profiler objects always exist
-        # (the admin ops and scrape endpoints need them) but their
-        # threads only start in start(), each gated on its env knob
+        # (the admin ops and scrape endpoints need them); the sampler's
+        # thread starts in start(), gated on its env knob, the profiler
+        # samples only while a request asks it to
         flightrecorder.DEFAULT_RECORDER.metrics = self.metrics
         self.metrics.inc(cm.SCOPE_FLIGHTREC, "events", 0)
         self.metrics.inc(cm.SCOPE_FLIGHTREC, "dumps", 0)
@@ -720,22 +720,38 @@ class ServiceHost(socketserver.ThreadingTCPServer):
         return doc
 
     def hostprof_doc(self, duration_s: float = 0.0) -> Dict[str, object]:
-        """The GET /hostprof body. With the profiler thread running the
-        rollup is free; a host running with CADENCE_TPU_HOSTPROF=0 can
-        still be burst-profiled by passing duration_s (the wire op's
-        knob)."""
-        prof = self.hostprof
-        if duration_s > 0 and (prof._thread is None
-                               or not prof._thread.is_alive()):
-            deadline = time.monotonic() + duration_s
-            while True:
-                prof.sample_once()
-                if time.monotonic() >= deadline:
-                    break
-                time.sleep(prof.period_s)
-        doc = prof.rollup()
+        """The GET /hostprof body: sample for `duration_s` (the wire op's
+        and the query string's knob), then roll up everything sampled so
+        far. No sampler thread runs between requests."""
+        doc = self.hostprof.rollup_after(duration_s)
         doc["host"] = self.name
         return doc
+
+    def device_trace(self, verb: str,
+                     directory: str = "") -> Dict[str, object]:
+        """Start (with a directory) or stop a `jax.profiler` trace of this
+        process. Python calls are not traced (`python_tracer_level` 0):
+        the host's Python is what is being measured, and the program's own
+        spans (utils/tracing.py) name what it was doing."""
+        import jax
+
+        if verb == "start":
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            # the xplane counts wall-clock nanoseconds from the session's
+            # start, which is the start of this call: a span's `start_ns`
+            # less this offset is its event's start on the timeline
+            session_start_ns = time.time_ns()
+            jax.profiler.start_trace(directory, profiler_options=options)
+            self._device_trace_t0 = time.perf_counter()
+            return {"host": self.name, "tracing": True, "dir": directory,
+                    "session_start_ns": session_start_ns}
+        if verb == "stop":
+            window_s = time.perf_counter() - self._device_trace_t0
+            jax.profiler.stop_trace()
+            return {"host": self.name, "tracing": False,
+                    "window_s": window_s}
+        raise ValueError(f"unknown admin_device_trace verb {verb!r}")
 
     def flightrec_doc(self, last_n: int = 200) -> Dict[str, object]:
         recorder = flightrecorder.DEFAULT_RECORDER
@@ -825,8 +841,6 @@ class ServiceHost(socketserver.ThreadingTCPServer):
         self.scrape.start()
         if timeseries_mod.enabled():
             self.timeseries.start()
-        if hostprof_mod.enabled():
-            self.hostprof.start()
         threading.Thread(target=self.serve_forever, daemon=True,
                          name="cadence-rpc-accept").start()
 
@@ -877,53 +891,61 @@ class _Handler(socketserver.BaseRequestHandler):
                 req = recv_frame(self.request)
             except (OSError, ConnectionError):
                 return
-            # a traced envelope parents this request's span on the caller's
-            # span; untraced traffic (pump loops, heartbeats) stays span-free;
+            # every request is a span, `rpc.<op>`, from the decoded frame to
+            # the reply sent (`rpc.reply`, pickling the answer, is its
+            # child); a traced envelope parents it on the caller's span,
+            # and without one it roots a trace of the background ring;
             # the caller's DEADLINE budget rides the same carrier
             remote_deadline = deadline_mod.peek(req)
             remote_ctx, req = tracing.extract(req)
             matched_poll = None  # (task, task_type) needing dead-socket requeue
-            try:
-                op = req[0] if isinstance(req, tuple) and req else "?"
-                if remote_deadline is not None and remote_deadline.expired():
-                    # the caller has already given up: reject BEFORE burning
-                    # a dispatch (store transaction, kernel launch)
-                    server.metrics.inc("rpc.server",
-                                       "deadline-expired-rejections")
-                    raise DeadlineExceeded(
-                        f"rpc.{op} arrived with its deadline expired")
-                span_cm = (server.tracer.start_span(f"rpc.{op}",
-                                                    child_of=remote_ctx)
-                           if remote_ctx is not None else nullcontext())
-                # bind the remaining budget for the dispatch, so every
-                # outbound hop this handler makes (store writes, peer
-                # engines) inherits the shrinking deadline
-                with span_cm, deadline_mod.bind(remote_deadline):
-                    result, matched_poll = self._dispatch(server, req)
-                response = ("ok", result)
-            except CircuitOpenError as exc:
-                # an outbound dependency of this host is being shed: the
-                # caller sees a typed busy signal, not a mystery
-                # ConnectionError (degrade, don't queue behind a dead host)
-                response = ("err", ServiceBusy(str(exc)))
-            except BaseException as exc:
-                response = ("err", exc)
-            try:
-                send_frame(self.request, response)
-            except (OSError, ConnectionError):
-                if matched_poll is not None:
-                    # a matched task delivered to a dead socket (worker
-                    # died mid-long-poll) must requeue, not vanish
-                    server.matching.local.requeue_task(*matched_poll)
-                return
-            except Exception:
-                # unpicklable result/exception: degrade to a string error
-                # rather than killing the connection
+            op = req[0] if isinstance(req, tuple) and req else "?"
+            with server.tracer.start_span(f"rpc.{op}", child_of=remote_ctx,
+                                          background=True) as rpc_span:
                 try:
-                    send_frame(self.request,
-                               ("err", RuntimeError(repr(response[1]))))
-                except Exception:
+                    if remote_deadline is not None \
+                            and remote_deadline.expired():
+                        # the caller has already given up: reject BEFORE
+                        # burning a dispatch (store transaction, kernel
+                        # launch)
+                        server.metrics.inc("rpc.server",
+                                           "deadline-expired-rejections")
+                        raise DeadlineExceeded(
+                            f"rpc.{op} arrived with its deadline expired")
+                    # bind the remaining budget for the dispatch, so every
+                    # outbound hop this handler makes (store writes, peer
+                    # engines) inherits the shrinking deadline
+                    with deadline_mod.bind(remote_deadline):
+                        result, matched_poll = self._dispatch(server, req)
+                    response = ("ok", result)
+                except CircuitOpenError as exc:
+                    # an outbound dependency of this host is being shed:
+                    # the caller sees a typed busy signal, not a mystery
+                    # ConnectionError (degrade, don't queue behind a dead
+                    # host)
+                    rpc_span.set_tag("error", type(exc).__name__)
+                    response = ("err", ServiceBusy(str(exc)))
+                except BaseException as exc:
+                    rpc_span.set_tag("error", type(exc).__name__)
+                    response = ("err", exc)
+                try:
+                    with server.tracer.start_span("rpc.reply",
+                                                  background=True):
+                        send_frame(self.request, response)
+                except (OSError, ConnectionError):
+                    if matched_poll is not None:
+                        # a matched task delivered to a dead socket (worker
+                        # died mid-long-poll) must requeue, not vanish
+                        server.matching.local.requeue_task(*matched_poll)
                     return
+                except Exception:
+                    # unpicklable result/exception: degrade to a string
+                    # error rather than killing the connection
+                    try:
+                        send_frame(self.request,
+                                   ("err", RuntimeError(repr(response[1]))))
+                    except Exception:
+                        return
 
     @staticmethod
     def _dispatch(server: "ServiceHost", req) -> Tuple[object, Optional[tuple]]:
@@ -1056,6 +1078,15 @@ class _Handler(socketserver.BaseRequestHandler):
         elif op == "admin_hostprof":
             result = server.hostprof_doc(
                 float(req[1]) if len(req) > 1 else 0.0)
+        elif op == "admin_device_trace":
+            # ("admin_device_trace", "start", <dir>) | (..., "stop"): a
+            # jax.profiler trace of this process, the one that holds the
+            # chip; the program's spans are on its timeline
+            result = server.device_trace(*req[1:])
+        elif op == "admin_trace_dump":
+            # the tracer's ring → CADENCE_TPU_TRACE_EXPORT (or req[1])
+            result = {"host": server.name, "file": server.tracer.dump(
+                req[1] if len(req) > 1 else None)}
         elif op == "admin_flightrec":
             result = server.flightrec_doc(
                 req[1] if len(req) > 1 else 200)

@@ -25,7 +25,6 @@ import argparse
 import socketserver
 import threading
 import time
-from contextlib import nullcontext
 from typing import Dict, Tuple
 
 from ..engine.persistence import Stores
@@ -89,10 +88,10 @@ class _Handler(socketserver.BaseRequestHandler):
                                          "deadline-expired-rejections")
                     raise DeadlineExceeded(
                         f"store rpc.{op} arrived with its deadline expired")
-                span_cm = (tracing.DEFAULT_TRACER.start_span(
-                               f"rpc.{op}", child_of=remote_ctx)
-                           if remote_ctx is not None else nullcontext())
-                with span_cm, deadline_mod.bind(remote_deadline):
+                with tracing.DEFAULT_TRACER.start_span(
+                        f"rpc.{op}", child_of=remote_ctx,
+                        background=True), \
+                        deadline_mod.bind(remote_deadline):
                     result = self._dispatch(server, req)
                 response = ("ok", result)
             except BaseException as exc:  # service errors cross the wire
@@ -123,6 +122,9 @@ class _Handler(socketserver.BaseRequestHandler):
             return server.peers(req[1])
         if op == "ping":
             return "pong"
+        if op == "admin_trace_dump":
+            return tracing.DEFAULT_TRACER.dump(
+                req[1] if len(req) > 1 else None)
         raise ValueError(f"unknown op {op!r}")
 
 

@@ -22,8 +22,11 @@ periodically walks sys._current_frames() and, per live thread,
 Results land as host.prof/* gauges on the registry (scraped flat) and as
 the structured rollup() doc (GET /hostprof, `admin hostprof`).
 
-Knobs: CADENCE_TPU_HOSTPROF=0 disables the ServiceHost profiler thread,
-CADENCE_TPU_HOSTPROF_PERIOD_MS sets the sampling period (default 20ms).
+A ServiceHost samples ON DEMAND, for the duration an `admin_hostprof` /
+`GET /hostprof?duration_s=` request asks for: the sampler holds the GIL
+that sets the served path's pace, and left running it cost two fifths of
+the median op (PERF.md, PR 25). Knob: CADENCE_TPU_HOSTPROF_PERIOD_MS sets
+the sampling period (default 20ms).
 """
 from __future__ import annotations
 
@@ -37,12 +40,7 @@ from typing import Dict, List, Optional
 
 from . import metrics as m
 
-ENV_ENABLED = "CADENCE_TPU_HOSTPROF"
 ENV_PERIOD_MS = "CADENCE_TPU_HOSTPROF_PERIOD_MS"
-
-
-def enabled() -> bool:
-    return os.environ.get(ENV_ENABLED, "1") not in ("0", "false", "no")
 
 
 def default_period_s() -> float:
@@ -330,6 +328,20 @@ class HostProfiler:
                           round(cpu_s, 4))
         except Exception:
             pass  # telemetry must never take the host down
+
+    def rollup_after(self, duration_s: float = 0.0) -> Dict[str, object]:
+        """The on-demand profile: unless the sampler thread is running,
+        sample this process for `duration_s` (one sample at least when it
+        is positive), then roll up everything sampled so far."""
+        running = self._thread is not None and self._thread.is_alive()
+        if duration_s > 0 and not running:
+            deadline = time.monotonic() + duration_s
+            while True:
+                self.sample_once()
+                if time.monotonic() >= deadline:
+                    break
+                time.sleep(self.period_s)
+        return self.rollup()
 
     # -- lifecycle ----------------------------------------------------------
 

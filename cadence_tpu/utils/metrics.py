@@ -34,6 +34,8 @@ SCOPE_HISTORY_SIGNAL = "history.signal-workflow-execution"
 SCOPE_HISTORY_RESET = "history.reset-workflow-execution"
 SCOPE_FRONTEND_START = "frontend.start-workflow-execution"
 SCOPE_FRONTEND_SIGNAL = "frontend.signal-workflow-execution"
+SCOPE_FRONTEND_SIGNAL_WITH_START = (
+    "frontend.signal-with-start-workflow-execution")
 SCOPE_QUEUE_TRANSFER = "queue.transfer"
 SCOPE_QUEUE_TIMER = "queue.timer"
 SCOPE_REPLICATION = "replication.task-processor"
@@ -43,7 +45,7 @@ SCOPE_PACK_CACHE = "tpu.pack-cache"
 SCOPE_TPU_FALLBACK = "tpu.fallback"
 SCOPE_TPU_RESIDENT = "tpu.resident"
 #: the mesh-aware bulk executor's own scope (engine/executor.py):
-#: chunks-dispatched / pack-queue-wait / device-busy, with PER-DEVICE
+#: chunks-dispatched / pack-queue-wait / launches-in-flight, with PER-DEVICE
 #: series (device_metric) when the executor runs over a mesh
 SCOPE_TPU_EXECUTOR = "tpu.executor"
 #: the native (C++) host-packing seam (native/packing.py + native/
@@ -237,13 +239,15 @@ M_LADDER_CACHE_HITS = "compile-cache-hits"
 M_LADDER_CACHE_MISSES = "compile-cache-misses"
 #: mesh-aware executor counters (engine/executor.py, SCOPE_TPU_EXECUTOR):
 #: chunks dispatched to the device mesh (plus a device_metric series per
-#: mesh position) and the per-device busy gauge — in-flight chunks whose
-#: shard slice occupies that device; rows-dispatched counts REAL workflow
+#: mesh position) and the per-device in-flight gauge — chunks launched and
+#: not yet read back whose shard slice occupies that device; rows-dispatched counts REAL workflow
 #: rows per device slice (padding excluded), so skewed shard population
 #: is visible on a scrape
 M_EXEC_CHUNKS = "chunks-dispatched"
 M_EXEC_ROWS = "rows-dispatched"
-M_EXEC_DEVICE_BUSY = "device-busy"
+#: launches-in-flight: the HOST's count of chunks launched and not yet read
+#: back. Not a device share: the device's busy time is on its own trace
+M_EXEC_IN_FLIGHT = "launches-in-flight"
 #: admission-control counters (SCOPE_QUOTAS): requests the multi-stage
 #: limiter admitted vs shed (typed ServiceBusyError with retry-after)
 M_QUOTA_ADMITTED = "admitted"
@@ -370,7 +374,7 @@ def ladder_rung_rows(rung: int) -> str:
 
 
 def device_metric(name: str, device: int) -> str:
-    """Per-device series name: chunks-dispatched-dev0, device-busy-dev3,
+    """Per-device series name: chunks-dispatched-dev0, launches-in-flight-dev3,
     ... — the device label of the mesh-aware executor's metrics (the
     registry keys on flat (scope, name), so the label rides the name the
     same way ladder_rung_rows carries the rung)."""
@@ -390,10 +394,6 @@ def domain_metric(name: str, domain: str) -> str:
 DEFAULT_BUCKETS: Tuple[float, ...] = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
-
-#: byte-size buckets (h2d transfer sizes: KBs to the 256MB frame cap)
-BYTE_BUCKETS: Tuple[float, ...] = (
-    1024.0, 16384.0, 262144.0, 1048576.0, 16777216.0, 268435456.0)
 
 
 class HistogramStat:

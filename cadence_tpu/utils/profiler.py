@@ -11,7 +11,8 @@ ops/replay.replay_corpus) wraps its phases in a ReplayProfiler:
                     host packing is starving the device; near-zero means
                     the device side is the bottleneck
   h2d             — host→device transfer dispatch (+ bytes, M_H2D_BYTES)
-  kernel          — device replay compute, measured to block_until_ready
+  kernel          — host time blocked on the device, to block_until_ready
+                    (on the timeline this leg's span is `device-wait`)
   readback        — device→host pull of payload rows / CRCs / errors
   fallback        — capacity-escalation ladder (engine/ladder.py): gather
                     + widened-K re-replay of overflow-flagged rows; the
@@ -23,19 +24,27 @@ ops/replay.replay_corpus) wraps its phases in a ReplayProfiler:
 Legs land as histograms under the component's scope (SCOPE_TPU_REPLAY by
 default, SCOPE_REBUILD for the rebuilder), so `/metrics` scrapes, the
 admin snapshot, and bench.py can all diff the legs across rounds.
+
+A leg is a span of the program's one recorder (utils/tracing.py), which
+keeps the time: the span's close observes the leg's histogram, and with a
+profiler session live the leg is an event on the device trace's timeline.
 """
 from __future__ import annotations
 
-import time
-from contextlib import contextmanager
 from typing import Dict, Optional
 
 from . import metrics as m
+from . import tracing
 
 #: the leg metric names, in pipeline order
 LEGS = (m.M_PROFILE_PACK, m.M_PROFILE_PACK_WAIT, m.M_PROFILE_H2D,
         m.M_PROFILE_KERNEL, m.M_PROFILE_READBACK, m.M_PROFILE_FALLBACK,
         m.M_PROFILE_SERVING)
+
+
+#: a leg whose timeline name differs from its histogram's: `kernel` is host
+#: time blocked on the device, not device time, and reads so on a timeline
+SPAN_NAMES = {m.M_PROFILE_KERNEL: "device-wait"}
 
 
 class ReplayProfiler:
@@ -47,23 +56,17 @@ class ReplayProfiler:
         self.registry = registry if registry is not None else m.DEFAULT_REGISTRY
         self.scope = scope
 
-    @contextmanager
-    def leg(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.registry.observe(self.scope, name,
-                                  time.perf_counter() - t0)
-
-    def observe(self, name: str, seconds: float) -> None:
-        self.registry.observe(self.scope, name, seconds)
+    def leg(self, name: str, span: Optional[str] = None) -> tracing.Span:
+        """`with prof.leg(name) as leg:` — a span named `span` (default:
+        the leg's name) whose seconds land in the histogram `name`;
+        `leg.duration_s` holds them once the block has ended."""
+        return tracing.Span(
+            tracing.DEFAULT_TRACER, span or SPAN_NAMES.get(name, name),
+            observe=(self.registry.observe, self.scope, name))
 
     def h2d(self, nbytes: int) -> None:
-        """One host→device transfer of `nbytes` (count + size histogram)."""
+        """One host→device transfer of `nbytes`."""
         self.registry.inc(self.scope, m.M_H2D_BYTES, int(nbytes))
-        self.registry.observe(self.scope, m.M_H2D_BYTES + "-per-transfer",
-                              float(nbytes), buckets=m.BYTE_BUCKETS)
 
     def summary(self) -> Dict[str, object]:
         """Leg breakdown for reports (the bench JSON / `admin profile`)."""

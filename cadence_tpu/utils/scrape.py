@@ -11,7 +11,8 @@ Onebox.scrape_server() exposes the in-process cluster the same way.
   GET /health     → application/json from the owner's health_fn
   GET /traces     → application/json finished spans grouped by trace_id
   GET /timeseries → application/json ring-buffer windows (timeseries_fn)
-  GET /hostprof   → application/json profiler rollup (hostprof_fn)
+  GET /hostprof   → application/json profiler rollup (hostprof_fn;
+                    ?duration_s=<seconds> samples that long first)
   GET /flightrec  → application/json flight-recorder snapshot (flightrec_fn)
 
 The three telemetry endpoints take provider callables rather than the
@@ -25,6 +26,7 @@ from __future__ import annotations
 import json
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from urllib.parse import parse_qs, urlsplit
 from typing import Callable, Dict, Optional, Tuple
 
 
@@ -86,7 +88,11 @@ class ObservabilityHTTPServer:
                           and owner.timeseries_fn is not None):
                         self._reply_json(owner.timeseries_fn())
                     elif path == "/hostprof" and owner.hostprof_fn is not None:
-                        self._reply_json(owner.hostprof_fn())
+                        # ?duration_s=<seconds>: sample that long first
+                        asked = parse_qs(urlsplit(self.path).query).get(
+                            "duration_s")
+                        self._reply_json(owner.hostprof_fn(float(asked[0]))
+                                         if asked else owner.hostprof_fn())
                     elif (path == "/flightrec"
                           and owner.flightrec_fn is not None):
                         self._reply_json(owner.flightrec_fn())

@@ -18,7 +18,7 @@ raw_series() read) and folds the deltas into a fixed-width window ring
                    fallback / serving, summed over the replay + rebuild
                    scopes) — `binding_resource` is the leg with the most
                    busy time, "idle" when none ran
-  saturation       serving queue depth vs capacity, executor busy gauge,
+  saturation       serving queue depth vs capacity, executor launches in flight,
                    and the pack-queue-wait share of the window's leg time
   utilization      total leg-busy seconds / window seconds, clipped [0,1]
 
@@ -256,8 +256,8 @@ class TimeSeriesSampler:
             if cap_v > 0:
                 window.saturation["queue_capacity"] = cap_v
                 window.saturation["queue_fill"] = min(1.0, depth / cap_v)
-        window.saturation["device_busy"] = window.gauges.get(
-            (m.SCOPE_TPU_EXECUTOR, m.M_EXEC_DEVICE_BUSY), 0.0)
+        window.saturation["launches_in_flight"] = window.gauges.get(
+            (m.SCOPE_TPU_EXECUTOR, m.M_EXEC_IN_FLIGHT), 0.0)
         total_busy = sum(window.legs.values())
         if total_busy > 1e-9:
             window.saturation["queue_wait_share"] = (

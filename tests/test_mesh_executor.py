@@ -198,11 +198,11 @@ class TestEngineMeshVerify:
                 m.device_metric(m.M_EXEC_ROWS, d)) >= 1
         # busy gauge settled back to zero after the run
         assert reg.gauge_value(m.SCOPE_TPU_EXECUTOR,
-                               m.M_EXEC_DEVICE_BUSY) == 0.0
+                               m.M_EXEC_IN_FLIGHT) == 0.0
         prom = reg.to_prometheus()
         assert 'cadence_chunks_dispatched_dev0_total{scope="tpu.executor"}' \
             in prom
-        assert 'cadence_device_busy_dev1{scope="tpu.executor"}' in prom
+        assert 'cadence_launches_in_flight_dev1{scope="tpu.executor"}' in prom
         # sharded resident pool: per-device occupancy gauges
         assert reg.gauge_value(m.SCOPE_TPU_RESIDENT,
                                m.device_metric(m.M_RESIDENT_BYTES, 0)) \

@@ -135,14 +135,14 @@ class TestTimeSeriesSampler:
         assert window.binding_resource == "idle"
         assert window.utilization == 0.0
 
-    def test_saturation_queue_fill_and_device_busy(self):
+    def test_saturation_queue_fill_and_launches_in_flight(self):
         reg = MetricsRegistry()
         sampler = TimeSeriesSampler(reg, period_s=1.0)
         sampler.set_capacity(m.SCOPE_TPU_SERVING, m.M_SERVING_QUEUE_DEPTH,
                              lambda: 8)
         sampler.sample_once(now=0.0)
         reg.gauge(m.SCOPE_TPU_SERVING, m.M_SERVING_QUEUE_DEPTH, 6.0)
-        reg.gauge(m.SCOPE_TPU_EXECUTOR, m.M_EXEC_DEVICE_BUSY, 0.5)
+        reg.gauge(m.SCOPE_TPU_EXECUTOR, m.M_EXEC_IN_FLIGHT, 0.5)
         reg.record(m.SCOPE_TPU_REPLAY, m.M_PROFILE_PACK_WAIT, 0.3)
         reg.record(m.SCOPE_TPU_REPLAY, m.M_PROFILE_KERNEL, 0.1)
         window = sampler.sample_once(now=1.0)
@@ -150,7 +150,7 @@ class TestTimeSeriesSampler:
         assert sat["queue_depth"] == 6.0
         assert sat["queue_capacity"] == 8.0
         assert sat["queue_fill"] == pytest.approx(0.75)
-        assert sat["device_busy"] == 0.5
+        assert sat["launches_in_flight"] == 0.5
         assert sat["queue_wait_share"] == pytest.approx(0.75)
 
     def test_fraction_over_bucket_boundary_semantics(self):
@@ -765,7 +765,7 @@ class TestFleetTelemetryWire:
             name = sorted(cluster.hosts)[0]
             ts = cluster.admin(name, "admin_timeseries", 50)
             assert ts["windows"] and ts["host"] == name
-            hp = cluster.admin(name, "admin_hostprof", 0.0)
+            hp = cluster.admin(name, "admin_hostprof", 0.2)
             assert hp["samples"] >= 1
             assert hp["attributed_share"] >= 0.9  # every host thread named
             fr = cluster.admin(name, "admin_flightrec", 100, None)
