@@ -12,9 +12,9 @@ on a mesh of 1 and of 4, the widened-K ladder rung, and the visibility
 scans at a 2^20-row bucket.
 
 There are no Pallas kernels in this repo; the risk is in programs that
-had only ever met XLA's CPU backend with x64 on: int64 lanes and the
-slice-by-8 CRC are emulated on the TPU, and the scans carry wide
-per-workflow state.
+had only ever met XLA's CPU backend with x64 on: int64 lanes are
+emulated on the TPU, the scans carry wide per-workflow state, and the
+payload CRC is a bf16 matrix product that must stay one (no gather).
 
 A compile that passes is not a chip run and is never reported as one:
 nothing executes, so this says nothing about results or times.
@@ -130,11 +130,18 @@ def test_wirec_bulk_kernels(one_chip, wirec_shape, W):
     suite width."""
     from cadence_tpu.ops.replay import replay_wirec_to_crc
     from cadence_tpu.parallel.mesh import _replay_wirec_crc_with_stats
+    from tests.test_device_crc import ops_under_scope
 
     args = _wirec_args(W, wirec_shape, lambda nd: one_chip)
     profile = wirec_shape[3]
     for fn in (replay_wirec_to_crc, _replay_wirec_crc_with_stats):
-        _fits(fn.lower(*args, profile, DEFAULT_LAYOUT).compile())
+        compiled = fn.lower(*args, profile, DEFAULT_LAYOUT).compile()
+        _fits(compiled)
+        # the chip's compiler keeps the CRC a product on the MXU: the
+        # v5e serialises a lane gather (33.6 us for 4,096 lanes, PERF.md)
+        ops = ops_under_scope(compiled.as_text(), "crc32")
+        assert ops["convolution"] > 0, ops
+        assert ops["gather"] == 0 and ops["while"] == 0, ops
 
 
 def test_wirec_stream_on_four_chips(mesh4, wirec_shape):

@@ -99,9 +99,9 @@ class TestGenerator:
     @pytest.mark.parametrize("n", [1, 8])
     def test_sharded_crc_equals_single_device(self, n):
         """The north-star form (bench.py, chip_smoke.py): the shard_map
-        kernel reduced to CRCs on device. The CRC scan's initial carry
-        has to vary across the mesh axis like the rows it hashes, or
-        shard_map's typing rejects the scan — on a mesh of 1 too."""
+        kernel reduced to CRCs on device. Inside shard_map the rows vary
+        across the mesh axis and the CRC's matrix is replicated; their
+        product is typed varying like the rows — on a mesh of 1 too."""
         import jax
 
         from cadence_tpu.core.checksum import crc32_of_rows

@@ -90,6 +90,30 @@ class TestServingPathParity:
         assert (crcs == crc_ref).all()
         assert (errors == np.asarray(err_ref)).all()
 
+    def test_mesh_4_wirec_crc_program_keeps_its_one_collective(self):
+        """The feeder's program on W-sharded inputs under plain `jit`:
+        the CRC's matrix is replicated and its product contracts the
+        unsharded axis, so the checksum adds no collective to the
+        program's one all-reduce, and the sharded CRCs are the host's."""
+        import re
+
+        from cadence_tpu.core.checksum import crc32_of_rows
+        from cadence_tpu.ops.replay import replay_to_payload, replay_wirec_to_crc
+        from cadence_tpu.ops.wirec import pack_wirec
+        from cadence_tpu.parallel.mesh import shard_wirec
+
+        ev = _events(n=16)
+        corpus = pack_wirec(ev)
+        parts = shard_wirec(corpus, make_mesh(jax.devices()[:4]))
+        compiled = replay_wirec_to_crc.lower(*parts, corpus.profile).compile()
+        collectives = re.findall(
+            r" (all-[a-z\-]+|collective-[a-z\-]+|reduce-scatter)\(",
+            compiled.as_text())
+        assert collectives == ["all-reduce"]
+        crc, _err = compiled(*parts)
+        rows, _ = replay_to_payload(jnp.asarray(ev))
+        assert (np.asarray(crc) == crc32_of_rows(np.asarray(rows))).all()
+
     @pytest.mark.parametrize("suite", ["basic", "timer_retry", "ndc"])
     @pytest.mark.parametrize("n_dev", [2, 4])
     def test_mesh_n_checksum_identity(self, suite, n_dev):
