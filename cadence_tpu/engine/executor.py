@@ -50,6 +50,7 @@ import os
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass
+from threading import Lock
 from typing import Any, Callable, List, Optional
 
 from ..utils import metrics as m
@@ -81,8 +82,9 @@ class PipelineReport:
     depth: int = 0
     pack_s: float = 0.0             # summed host pack seconds (inside pack_fn)
     pack_queue_wait_s: float = 0.0  # consumer stalled on the pack pipeline
-    escalate_s: float = 0.0         # summed escalate_fn seconds (host side
-                                    # of capacity-escalation dispatch)
+    escalate_s: float = 0.0         # summed retire_fn + escalate_fn seconds
+                                    # on the consumer (host side of
+                                    # capacity-escalation dispatch)
     wall_s: float = 0.0
 
 
@@ -112,6 +114,18 @@ class BulkReplayExecutor:
                                 ahead the whole time, so escalation never
                                 stalls the pack pipeline. Its return
                                 value replaces the chunk's output.
+      retire_fn(ci, out)        optional; runs exactly ONCE per chunk, with
+                                chunk ci's device outputs ready, before
+                                anything can write over ring slot
+                                ci % depth: on the pack thread that is
+                                about to reuse the slot, or on the consumer
+                                between consume_fn(ci) and escalate_fn(ci),
+                                whichever gets there first. It is the last
+                                moment pack_fn(ci)'s host buffers are
+                                intact: what a later step needs of them
+                                (the ladder's flagged rows) is copied out
+                                here, and no packer ever waits on the
+                                consumer for it.
     """
 
     def __init__(self, depth: Optional[int] = None,
@@ -129,7 +143,8 @@ class BulkReplayExecutor:
             pack_fn: Callable[[int], Any],
             launch_fn: Callable[[int, Any], Any],
             consume_fn: Optional[Callable[[int, Any], Any]] = None,
-            escalate_fn: Optional[Callable[[int, Any], Any]] = None
+            escalate_fn: Optional[Callable[[int, Any], Any]] = None,
+            retire_fn: Optional[Callable[[int, Any], None]] = None
             ) -> tuple:
         """Returns (outputs, PipelineReport); outputs[ci] is the last
         hook's return value (escalate_fn over consume_fn over
@@ -157,6 +172,27 @@ class BulkReplayExecutor:
         #: ci -> Future resolved with chunk ci's device outputs once
         #: launched; pack tasks block on ci - depth here (ring discipline)
         launched = {ci: Future() for ci in range(num_chunks)}
+        retire_locks = [Lock() for _ in range(num_chunks)] \
+            if retire_fn is not None else []
+        retired = [False] * len(retire_locks)
+
+        def retire(ci: int, out: Any) -> None:
+            with retire_locks[ci]:
+                if not retired[ci]:
+                    retired[ci] = True
+                    retire_fn(ci, out)
+
+        def consume(ci: int) -> None:
+            raw = outs[ci]
+            out = consume_fn(ci, raw)
+            t0 = time.perf_counter()
+            if retire_fn is not None:
+                retire(ci, raw)
+            if escalate_fn is not None:
+                out = escalate_fn(ci, out)
+            report.escalate_s += time.perf_counter() - t0
+            outs[ci] = out
+            busy(-1)
 
         def pack_task(ci: int):
             if ci >= self.depth:
@@ -172,6 +208,8 @@ class BulkReplayExecutor:
                 # chunk.
                 prior = launched[ci - self.depth].result()
                 jax.block_until_ready(prior)
+                if retire_fn is not None and prior is not None:
+                    retire(ci - self.depth, prior)
                 del prior
                 launched.pop(ci - self.depth, None)
             with prof.leg(m.M_PROFILE_PACK) as leg:
@@ -205,15 +243,9 @@ class BulkReplayExecutor:
                     if consume_fn is not None and ci >= 1:
                         # lag-1 readback: chunk ci is in flight while
                         # chunk ci-1 is pulled, and outputs never pile up
-                        outs[ci - 1] = self._consume(ci - 1, outs[ci - 1],
-                                                     consume_fn,
-                                                     escalate_fn, report)
-                        busy(-1)
+                        consume(ci - 1)
                 if consume_fn is not None and num_chunks:
-                    outs[-1] = self._consume(num_chunks - 1, outs[-1],
-                                             consume_fn, escalate_fn,
-                                             report)
-                    busy(-1)
+                    consume(num_chunks - 1)
             finally:
                 # a pack/launch failure must not wedge pool shutdown:
                 # unblock every pack task still waiting on a launch that
@@ -230,18 +262,6 @@ class BulkReplayExecutor:
                     busy(-in_flight[0])
         report.wall_s = time.perf_counter() - t_start
         return outs, report
-
-    @staticmethod
-    def _consume(ci: int, out: Any,
-                 consume_fn: Callable[[int, Any], Any],
-                 escalate_fn: Optional[Callable[[int, Any], Any]],
-                 report: PipelineReport) -> Any:
-        out = consume_fn(ci, out)
-        if escalate_fn is not None:
-            t0 = time.perf_counter()
-            out = escalate_fn(ci, out)
-            report.escalate_s += time.perf_counter() - t0
-        return out
 
 
 # ---------------------------------------------------------------------------

@@ -25,28 +25,39 @@ Costs are amortized and observable:
 - sub-corpus shapes are pow2-bucketed (workflow AND event axes), so
   run-to-run wobble in the flagged count reuses the same executable;
 - counters land under `tpu.fallback/*` (rows per rung, rung compiles,
-  resolved/residual rows) and rung time lands as the profiler's
-  `fallback` leg.
+  resolved/residual rows); the host time blocked on a rung's results is
+  the profiler's `fallback` leg, a span of the one recorder
+  (utils/tracing.py) like `kernel` and `readback`. On a timeline the
+  ladder's host work reads `feed.ladder.gather` (flagged rows copied
+  into a sub-corpus), `feed.ladder.submit` (pad + H2D + launch of a
+  rung) and `feed.ladder.device-wait` (the `fallback` leg), under the
+  bulk path's prefix wherever the ladder runs; the rungs' device work
+  sits under `jax.named_scope("ladder-rung")`.
 
 submit()/finish() split the work so the pipelined executor
 (engine/executor.py) can dispatch rung-1 re-replays asynchronously per
 chunk while later chunks still pack and replay; rungs ≥ 2 run once,
-batched across every chunk's survivors.
+batched across every chunk's survivors. Dense int64 lanes (the verify
+engine) and compressed wirec corpora (the serialized feeder) go through
+the same two calls: a sub-corpus is either, and results stay on the
+device until finish() reads them.
 """
 from __future__ import annotations
 
 import os
-import time
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..core.checksum import DEFAULT_LAYOUT, PayloadLayout
-from ..ops.encode import gather_subcorpus
+from ..ops.encode import LANE_EVENT_ID, gather_subcorpus
 from ..ops.state import CAPACITY_ERRORS, widen_layout
+from ..ops.wirec import WirecCorpus, gather_corpus
 from ..utils import compile_cache
 from ..utils import metrics as m
+from ..utils import tracing
+from ..utils.profiler import ReplayProfiler
 
 #: rungs above base capacity (K→2K→4K with the default 2); bounded — each
 #: rung is one more compiled variant and 2x the per-row state footprint
@@ -55,17 +66,67 @@ DEFAULT_RUNGS = 2
 
 _CAPACITY = np.asarray(CAPACITY_ERRORS, dtype=np.int32)
 
+#: the ladder's host work on a timeline (module docstring)
+SPAN_GATHER = "feed.ladder.gather"
+SPAN_SUBMIT = "feed.ladder.submit"
+SPAN_WAIT = "feed.ladder.device-wait"
+
 
 def _pow2(n: int, floor: int) -> int:
     return max(floor, 1 << (max(1, int(n)) - 1).bit_length())
+
+
+# A flagged sub-corpus is [F, E, L] int64 lanes or a WirecCorpus; these
+# four are all the ladder needs to know of either.
+
+def _dims(sub) -> Tuple[int, int]:
+    return (sub.slab if isinstance(sub, WirecCorpus) else sub).shape[:2]
+
+
+def _take(sub, indices, pad_workflows: int = 0, pad_events: int = 0):
+    gather = gather_corpus if isinstance(sub, WirecCorpus) \
+        else gather_subcorpus
+    return gather(sub, indices, pad_workflows, pad_events)
+
+
+def _real_events(sub) -> int:
+    if isinstance(sub, WirecCorpus):
+        return int(sub.n_events.sum())
+    return int((sub[:, :, LANE_EVENT_ID] > 0).sum())
+
+
+def _concat(subs: list):
+    """Sub-corpora of one profile (or none: dense) as one, on the longest
+    event axis among them."""
+    if len(subs) == 1:
+        return subs[0]
+    E = max(_dims(s)[1] for s in subs)
+    subs = [_take(s, np.arange(_dims(s)[0]), 0, E) for s in subs]
+    if isinstance(subs[0], WirecCorpus):
+        return WirecCorpus(*(np.concatenate(part) for part in
+                             zip(*(s[:3] for s in subs))), subs[0].profile)
+    return np.concatenate(subs)
+
+
+@dataclass
+class RungLaunch:
+    """One dispatched rung: its results stay on the device until
+    finish() reads them."""
+
+    outs: tuple              # device (rows | crc32, err, ovf[, branch])
+    rows: int                # real rows
+    lanes: int               # rows after the pow2 padding
+    events: int              # real events of those rows
+    wire_bytes: int          # bytes of the gathered, unpadded sub-corpus
 
 
 @dataclass
 class PendingEscalation:
     """One chunk's dispatched rung-1 re-replay (submit() → finish())."""
 
-    sub: np.ndarray          # trimmed [F, E, L] sub-corpus (host copy)
-    outs: tuple              # rung-1 device arrays (rows, err, ovf, branch)
+    sub: object              # trimmed flagged sub-corpus (host copy):
+                             # [F, E, L] lanes or a WirecCorpus
+    launch: RungLaunch       # rung 1, in flight
     count: int               # real rows (padding excluded)
 
 
@@ -73,7 +134,8 @@ class PendingEscalation:
 class LadderOutcome:
     """Final arbitration-ready results for F flagged rows."""
 
-    rows: np.ndarray         # [F, base_width] (valid where resolved)
+    rows: np.ndarray         # [F, base_width] (valid where resolved); for
+                             # a wirec sub-corpus their CRC32s, [F] uint32
     resolved: np.ndarray     # [F] bool — device-resolved at some rung
     errors: np.ndarray       # [F] i32 — last rung's error per row
     branch: np.ndarray       # [F] i32 — device-chosen current branch
@@ -100,8 +162,9 @@ class EscalationLadder:
         self.variants = (variants if variants is not None
                          else compile_cache.DEFAULT_VARIANTS)
         #: per-rung accounting of the most recent escalate/finish call
-        #: (bench.py reports per-rung rates from this)
+        #: (bench.py and the feeder's report read this)
         self.last_run: List[dict] = []
+        self._prof = ReplayProfiler(self.metrics, scope=m.SCOPE_TPU_FALLBACK)
 
     # -- shared mechanics ---------------------------------------------------
 
@@ -125,12 +188,16 @@ class EscalationLadder:
         """Local indices of rows whose error a wider K could clear."""
         return np.nonzero(np.isin(np.asarray(errors), _CAPACITY))[0]
 
-    def _record_rung(self, rung: int, rows: int, seconds: float) -> None:
+    def _rung_leg(self, span: Optional[str] = None) -> tracing.Span:
+        """The `fallback` leg: a span whose close observes the histogram
+        (a synchronous rung whole; of a dispatched one the wait)."""
+        return self._prof.leg(m.M_PROFILE_FALLBACK, span=span)
+
+    def _record_rung(self, rung: int, rows: int, seconds: float,
+                     **counts) -> None:
         self.metrics.inc(m.SCOPE_TPU_FALLBACK, m.ladder_rung_rows(rung), rows)
-        self.metrics.observe(m.SCOPE_TPU_FALLBACK, m.M_PROFILE_FALLBACK,
-                             seconds)
         self.last_run.append({"rung": rung, "rows": rows,
-                              "seconds": round(seconds, 6)})
+                              "seconds": round(seconds, 6), **counts})
 
     def _finalize(self, resolved: np.ndarray) -> None:
         n_res = int(resolved.sum())
@@ -161,91 +228,144 @@ class EscalationLadder:
 
         return self.variants.get(key, build, self.metrics)
 
-    def _pad_dense(self, sub: np.ndarray) -> np.ndarray:
-        F, E = sub.shape[:2]
-        Wp, Ep = self._pad_dims(F, E)
-        return gather_subcorpus(sub, np.arange(F), Wp, Ep)
+    def _pad(self, sub):
+        """A trimmed sub-corpus in its pow2 bucket."""
+        F, E = _dims(sub)
+        return _take(sub, np.arange(F), *self._pad_dims(F, E))
 
-    # -- dense-lane path (verify/replay engines) ----------------------------
+    # -- the rungs: submit() per chunk, finish() once -----------------------
 
-    def submit(self, sub: np.ndarray) -> PendingEscalation:
-        """Dispatch the rung-1 re-replay of a trimmed [F, E, L] flagged
-        sub-corpus ASYNCHRONOUSLY (JAX async dispatch returns device
-        handles immediately): the pipelined executor calls this per chunk
-        so rung-1 compute overlaps later chunks' pack/replay."""
-        F = sub.shape[0]
+    def _launch(self, rung: int, sub) -> RungLaunch:
+        """Pad a trimmed sub-corpus to its pow2 bucket and dispatch its
+        re-replay at `rung` ASYNCHRONOUSLY (JAX async dispatch returns
+        device handles at once)."""
+        with tracing.span(SPAN_SUBMIT):
+            padded = self._pad(sub)
+            Wp, Ep = _dims(padded)
+            if isinstance(sub, WirecCorpus):
+                fn = self._wirec_fn(rung, Wp, Ep, sub.profile)
+                wire_bytes = sub.wire_bytes
+            else:
+                fn = self._dense_fn(rung, Wp, Ep, keep_state=False)
+                wire_bytes = sub.nbytes
+            return RungLaunch(fn(padded), _dims(sub)[0], Wp,
+                              _real_events(sub), wire_bytes)
+
+    def submit(self, sub) -> PendingEscalation:
+        """Dispatch the rung-1 re-replay of a trimmed flagged sub-corpus
+        ([F, E, L] lanes or a WirecCorpus): the pipelined executor calls
+        this per chunk so rung-1 compute overlaps later chunks'
+        pack/replay."""
+        F = _dims(sub)[0]
         self.metrics.inc(m.SCOPE_TPU_FALLBACK, m.M_LADDER_FLAGGED, F)
-        padded = self._pad_dense(sub)
-        fn = self._dense_fn(1, padded.shape[0], padded.shape[1],
-                            keep_state=False)
-        return PendingEscalation(sub=sub, outs=fn(padded), count=F)
+        return PendingEscalation(sub=sub, launch=self._launch(1, sub),
+                                 count=F)
+
+    def submit_wirec(self, corpus: WirecCorpus, indices
+                     ) -> PendingEscalation:
+        """submit() of rows `indices` of a packed wirec corpus: they are
+        COPIED out first, so `corpus` may be a ring slot's view."""
+        with tracing.span(SPAN_GATHER):
+            sub = gather_corpus(corpus, np.asarray(indices, dtype=np.int64))
+        return self.submit(sub)
+
+    def _collect(self, rung: int, launches: list,
+                 outcomes: List[LadderOutcome]) -> Tuple[np.ndarray,
+                                                         np.ndarray]:
+        """Read one rung's launches back into the outcomes; returns the
+        (pending index, local row) pairs a wider rung could still clear.
+        A launch is (RungLaunch, pending index of each row, its local row
+        there)."""
+        import jax
+
+        with self._rung_leg(SPAN_WAIT) as leg:
+            host = jax.device_get([launch.outs for launch, _, _ in launches])
+        still_pi, still_j = [], []
+        for (launch, pis, js), (values, err, ovf, *branch) in zip(launches,
+                                                                  host):
+            n = launch.rows
+            values, err, ovf = values[:n], err[:n], ovf[:n]
+            ok = (err == 0) & ~ovf
+            for pi in np.unique(pis):
+                sel, o = pis == pi, outcomes[pi]
+                o.errors[js[sel]] = err[sel]
+                if branch:
+                    o.branch[js[sel]] = branch[0][:n][sel]
+                good = sel & ok
+                o.rows[js[good]] = values[good]
+                o.resolved[js[good]] = True
+            cap = np.isin(err, _CAPACITY)
+            still_pi.append(pis[cap])
+            still_j.append(js[cap])
+        self._record_rung(
+            rung, sum(launch.rows for launch, _, _ in launches),
+            leg.duration_s,
+            **{key: sum(getattr(launch, key) for launch, _, _ in launches)
+               for key in ("lanes", "events", "wire_bytes")})
+        return np.concatenate(still_pi), np.concatenate(still_j)
+
+    def _relaunch(self, rung: int, pending: Sequence[PendingEscalation],
+                  pis: np.ndarray, js: np.ndarray) -> list:
+        """One launch at `rung` over every pending's survivors (one for
+        each wirec profile among them: a profile is a jit key)."""
+        groups: dict = {}
+        for pi in np.unique(pis):
+            sub = pending[pi].sub
+            groups.setdefault(sub.profile if isinstance(sub, WirecCorpus)
+                              else None, []).append(int(pi))
+        launches = []
+        for members in groups.values():
+            rows = [js[pis == pi] for pi in members]
+            with tracing.span(SPAN_GATHER):
+                cur = _concat([_take(pending[pi].sub, idx)
+                               for pi, idx in zip(members, rows)])
+            launches.append((
+                self._launch(rung, cur),
+                np.concatenate([np.full(len(idx), pi)
+                                for pi, idx in zip(members, rows)]),
+                np.concatenate(rows)))
+        return launches
 
     def finish(self, pending: Sequence[PendingEscalation]
                ) -> List[LadderOutcome]:
         """Collect rung-1 results and run rungs ≥ 2 ONCE, batched across
         every pending chunk's survivors. Returns one outcome per pending,
         aligned with its submitted rows."""
-        import jax
-
-        outcomes: List[LadderOutcome] = []
         self.last_run = []
-        rung1_rows = sum(p.count for p in pending)
-        # (chunk index in `pending`, local row index) of rung-1 survivors
-        still: List[Tuple[int, int]] = []
-        t0 = time.perf_counter()
-        for pi, p in enumerate(pending):
-            jax.block_until_ready(p.outs)
-            # np.array (not asarray): rungs ≥ 2 patch these in place, and
-            # device readbacks come back as read-only views
-            rows, err, ovf, branch = (np.array(a) for a in p.outs)
-            F = p.count
-            rows, err, ovf, branch = rows[:F], err[:F], ovf[:F], branch[:F]
-            resolved = (err == 0) & ~ovf
-            outcomes.append(LadderOutcome(rows=rows, resolved=resolved,
-                                          errors=err, branch=branch))
-            still.extend((pi, int(j)) for j in self.capacity_flagged(err))
-        if rung1_rows:
-            self._record_rung(1, rung1_rows, time.perf_counter() - t0)
-
-        for rung in range(2, self.max_rungs + 1):
-            if not still:
+        outcomes = []
+        for p in pending:
+            rows = (np.zeros(p.count, np.uint32)
+                    if isinstance(p.sub, WirecCorpus)
+                    else np.zeros((p.count, self.layout.width), np.int64))
+            outcomes.append(LadderOutcome(
+                rows=rows, resolved=np.zeros(p.count, bool),
+                errors=np.zeros(p.count, np.int32),
+                branch=np.zeros(p.count, np.int32)))
+        launches = [(p.launch, np.full(p.count, pi), np.arange(p.count))
+                    for pi, p in enumerate(pending)]
+        for rung in range(1, self.max_rungs + 1):
+            if not launches:
                 break
-            t0 = time.perf_counter()
-            subs = []
-            flat = []
-            for pi in sorted({q for q, _ in still}):
-                idx = [j for q, j in still if q == pi]
-                subs.append(gather_subcorpus(pending[pi].sub, idx))
-                flat.extend((pi, j) for j in idx)
-            E = max(s.shape[1] for s in subs)
-            cur = np.concatenate([
-                gather_subcorpus(s, np.arange(s.shape[0]), 0, E)
-                for s in subs])
-            padded = self._pad_dense(cur)
-            fn = self._dense_fn(rung, padded.shape[0], padded.shape[1],
-                                keep_state=False)
-            rows, err, ovf, branch = (np.asarray(a)
-                                      for a in fn(padded))
-            next_still = []
-            for k, (pi, j) in enumerate(flat):
-                outcomes[pi].errors[j] = err[k]
-                outcomes[pi].branch[j] = branch[k]
-                if err[k] == 0 and not ovf[k]:
-                    outcomes[pi].rows[j] = rows[k]
-                    outcomes[pi].resolved[j] = True
-                elif err[k] in _CAPACITY:
-                    next_still.append((pi, j))
-            self._record_rung(rung, len(flat), time.perf_counter() - t0)
-            still = next_still
-
+            pis, js = self._collect(rung, launches, outcomes)
+            launches = (self._relaunch(rung + 1, pending, pis, js)
+                        if len(pis) and rung < self.max_rungs else [])
         for o in outcomes:
             o.rungs = list(self.last_run)
             self._finalize(o.resolved)
         return outcomes
 
-    def escalate(self, sub: np.ndarray) -> LadderOutcome:
+    def escalate(self, sub) -> LadderOutcome:
         """Synchronous full ladder over one trimmed sub-corpus."""
         return self.finish([self.submit(sub)])[0]
+
+    def escalate_wirec(self, corpus: WirecCorpus, indices
+                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Synchronous full ladder over flagged rows of a wirec corpus,
+        reduced on device to base-width CRC32s. Returns (crc32 [F]
+        uint32, resolved [F] bool, errors [F] i32) aligned with
+        `indices`."""
+        o = self.finish([self.submit_wirec(corpus, indices)])[0]
+        return o.rows, o.resolved, o.errors
 
     # -- full-state path (engine/rebuild.py hydration) ----------------------
 
@@ -266,16 +386,16 @@ class EscalationLadder:
         active = np.arange(F)
         cur = sub
         for rung in range(1, self.max_rungs + 1):
-            t0 = time.perf_counter()
-            padded = self._pad_dense(cur)
-            fn = self._dense_fn(rung, padded.shape[0], padded.shape[1],
-                                keep_state=True)
-            s_dev, rows_dev, err_dev, ovf_dev = fn(padded)
-            arrs = jax.device_get(s_dev)
-            rows = np.asarray(rows_dev)[:len(active)]
-            err = np.asarray(err_dev)[:len(active)]
-            ovf = np.asarray(ovf_dev)[:len(active)]
-            self._record_rung(rung, len(active), time.perf_counter() - t0)
+            with self._rung_leg() as leg:
+                padded = self._pad(cur)
+                fn = self._dense_fn(rung, padded.shape[0], padded.shape[1],
+                                    keep_state=True)
+                s_dev, rows_dev, err_dev, ovf_dev = fn(padded)
+                arrs = jax.device_get(s_dev)
+                rows = np.asarray(rows_dev)[:len(active)]
+                err = np.asarray(err_dev)[:len(active)]
+                ovf = np.asarray(ovf_dev)[:len(active)]
+            self._record_rung(rung, len(active), leg.duration_s)
             ok = (err == 0) & ~ovf
             for k in np.nonzero(ok)[0]:
                 gi = active[k]
@@ -331,30 +451,30 @@ class EscalationLadder:
         cur = sub
         cur_states = states
         for rung in range(base_rung + 1, self.max_rungs + 1):
-            t0 = time.perf_counter()
-            layout_r = self.rung_layout(rung)
-            padded = self._pad_dense(cur)
-            Wp, Ep = padded.shape[:2]
-            s0 = widen_state(cur_states, layout_r)
-            if Wp > len(active):
-                pad_rows = init_state(Wp - len(active), layout_r)
-                s0 = jax.tree_util.tree_map(
-                    lambda a, b: jnp.concatenate([a, b], axis=0),
-                    s0, pad_rows)
-            key = ("resident", self.layout, rung, Wp, Ep)
+            with self._rung_leg() as leg:
+                layout_r = self.rung_layout(rung)
+                padded = self._pad(cur)
+                Wp, Ep = padded.shape[:2]
+                s0 = widen_state(cur_states, layout_r)
+                if Wp > len(active):
+                    pad_rows = init_state(Wp - len(active), layout_r)
+                    s0 = jax.tree_util.tree_map(
+                        lambda a, b: jnp.concatenate([a, b], axis=0),
+                        s0, pad_rows)
+                key = ("resident", self.layout, rung, Wp, Ep)
 
-            def build():
-                from ..ops.replay import replay_from_state_to_payload
-                return lambda ev, st: replay_from_state_to_payload(
-                    jnp.asarray(ev), st, self.layout)
+                def build():
+                    from ..ops.replay import replay_from_state_to_payload
+                    return lambda ev, st: replay_from_state_to_payload(
+                        jnp.asarray(ev), st, self.layout)
 
-            fn = self.variants.get(key, build, self.metrics)
-            s_fin, rows_dev, err_dev, ovf_dev = fn(padded, s0)
-            rows = np.asarray(rows_dev)[:len(active)]
-            err = np.asarray(err_dev)[:len(active)]
-            ovf = np.asarray(ovf_dev)[:len(active)]
-            branch = np.asarray(s_fin.current_branch)[:len(active)]
-            self._record_rung(rung, len(active), time.perf_counter() - t0)
+                fn = self.variants.get(key, build, self.metrics)
+                s_fin, rows_dev, err_dev, ovf_dev = fn(padded, s0)
+                rows = np.asarray(rows_dev)[:len(active)]
+                err = np.asarray(err_dev)[:len(active)]
+                ovf = np.asarray(ovf_dev)[:len(active)]
+                branch = np.asarray(s_fin.current_branch)[:len(active)]
+            self._record_rung(rung, len(active), leg.duration_s)
             ok = (err == 0) & ~ovf
             for k in np.nonzero(ok)[0]:
                 gi = active[k]
@@ -374,47 +494,6 @@ class EscalationLadder:
         return (LadderOutcome(rows=rows_out, resolved=resolved,
                               errors=err_out, branch=branch_out,
                               rungs=list(self.last_run)), states_out)
-
-    # -- wirec path (bench / CRC consumers) ---------------------------------
-
-    def escalate_wirec(self, corpus, indices) -> Tuple[np.ndarray,
-                                                       np.ndarray,
-                                                       np.ndarray]:
-        """Full ladder over flagged rows of a wirec corpus, reduced on
-        device to base-width CRC32s. Returns (crc32 [F] uint32, resolved
-        [F] bool, errors [F] i32) aligned with `indices`."""
-        from ..ops.wirec import gather_corpus
-
-        idx = np.asarray(indices, dtype=np.int64)
-        F = len(idx)
-        self.metrics.inc(m.SCOPE_TPU_FALLBACK, m.M_LADDER_FLAGGED, F)
-        self.last_run = []
-        crcs_out = np.zeros(F, np.uint32)
-        resolved = np.zeros(F, bool)
-        err_out = np.zeros(F, np.int32)
-        active = np.arange(F)
-        cur = gather_corpus(corpus, idx)
-        for rung in range(1, self.max_rungs + 1):
-            t0 = time.perf_counter()
-            Wp, Ep = self._pad_dims(len(active), cur.slab.shape[1])
-            padded = gather_corpus(cur, np.arange(len(active)), Wp, Ep)
-            fn = self._wirec_fn(rung, Wp, Ep, padded.profile)
-            crc_dev, err_dev, ovf_dev = fn(padded)
-            crc = np.asarray(crc_dev)[:len(active)].astype(np.uint32)
-            err = np.asarray(err_dev)[:len(active)]
-            ovf = np.asarray(ovf_dev)[:len(active)]
-            self._record_rung(rung, len(active), time.perf_counter() - t0)
-            ok = (err == 0) & ~ovf
-            crcs_out[active[ok]] = crc[ok]
-            resolved[active[ok]] = True
-            err_out[active] = err
-            still = self.capacity_flagged(err)
-            if not len(still):
-                break
-            cur = gather_corpus(cur, still)
-            active = active[still]
-        self._finalize(resolved)
-        return crcs_out, resolved, err_out
 
     def _wirec_fn(self, rung: int, Wp: int, Ep: int, profile):
         import jax.numpy as jnp

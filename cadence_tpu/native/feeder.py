@@ -53,6 +53,21 @@ class FeedReport:
     #: handoff cost — the pinned-buffer H2D seconds bench records
     native_wirec: bool = False
     h2d_s: float = 0.0
+    #: capacity-escalation ladder inside the call (wirec pipeline only).
+    #: Every rung counted: the rows the rungs replayed, their lanes (rows
+    #: after the pow2 padding), the real events and the wire bytes of the
+    #: gathered sub-corpora. Each flagged row once: resolved by a rung, or
+    #: residual (no rung resolved it: it keeps its error code), and its
+    #: index in the call's results. `ladder_s` is the consumer thread's
+    #: host time inside the ladder. `events` counts no event twice.
+    ladder_rows: int = 0
+    ladder_lanes: int = 0
+    ladder_events: int = 0
+    ladder_wire_bytes: int = 0
+    ladder_resolved: int = 0
+    ladder_residual: int = 0
+    ladder_s: float = 0.0
+    ladder_indices: Sequence[int] = ()
 
     @property
     def events_per_sec(self) -> float:
@@ -227,11 +242,24 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
     refreshed plan becomes the pin for chunks packed after it) — counted
     in the report, never silent. Both encoders measure profiles with the
     identical decision procedure, so pin/refit behavior cannot depend on
-    which one served."""
+    which one served.
+
+    Rows the base pass flags with a CAPACITY error (a workflow holding
+    more pending items than the device's tables) are resolved inside the
+    call, always, through the widened-K ladder (engine/ladder.py): each
+    chunk's flagged rows are copied out of its buffers before its ring
+    slot can be packed over, rung 1 is dispatched as the chunk is read
+    back — it overlaps later chunks' pack and replay — and after the last
+    chunk rung 1 is collected, rungs ≥ 2 run once over every chunk's
+    survivors, and resolved rows' CRCs and error words replace the base
+    pass's. A row no rung resolves keeps its error code and is counted
+    (`ladder_residual`); nothing here calls the Python oracle. A corpus
+    that fits the tables pays one np.isin over each chunk's error words."""
     import jax
 
+    from ..engine.ladder import SPAN_GATHER, EscalationLadder
     from ..ops.replay import replay_wirec_to_crc
-    from ..ops.wirec import ProfileMisfit, pack_wirec
+    from ..ops.wirec import ProfileMisfit, gather_corpus, pack_wirec
     from ..utils.concurrency import pack_threads
     from . import wirec as nwirec
 
@@ -248,6 +276,7 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
         report = FeedReport(workflows=total, depth=executor.depth,
                             native_wirec=use_native)
         prof = ReplayProfiler()
+        ladder = EscalationLadder(layout, registry=registry, mesh=mesh)
         n_chunks = -(-total // chunk_workflows) if total else 0
         # intra-chunk wirec threads: the one CADENCE_TPU_PACK_THREADS knob,
         # split across the pack pool's concurrent workers
@@ -355,6 +384,7 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
         # compression is part of the host pack cost in this pipeline
         # (the executor already recorded the full pack task; fold the
         # split into the report fields instead)
+        corpora[ci] = corpus
         return corpus
 
     def launch(ci, corpus):
@@ -368,7 +398,13 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
             with state_lock:
                 shared["h2d_s"] += time.perf_counter() - t0
             prof.h2d(corpus.wire_bytes)
-        return replay_wirec_to_crc(*parts, corpus.profile, layout)
+        outs = replay_wirec_to_crc(*parts, corpus.profile, layout)
+        # the error words start for the host as soon as the chunk has
+        # replayed: whichever thread retires the chunk (a packer, where
+        # the packers set the pace) then reads them without a round trip
+        # to the device of its own
+        outs[1].copy_to_host_async()
+        return outs
 
     def consume(ci, outs):
         with prof.leg(m.M_PROFILE_KERNEL):
@@ -376,11 +412,54 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
         with prof.leg(m.M_PROFILE_READBACK):
             return np.asarray(outs[0]), np.asarray(outs[1])
 
+    #: chunk ci's packed corpus (the native path's are views of ring slot
+    #: ci % depth) until the chunk retires; its capacity-flagged rows,
+    #: copied out, until the consumer dispatches them; what it dispatched
+    corpora: List[Optional[object]] = [None] * n_chunks
+    salvaged: dict = {}
+    pending: list = []
+
+    def retire(ci, outs):
+        # the executor calls this once a chunk, before anything can pack
+        # over its ring slot, on whichever thread gets there first
+        corpus, corpora[ci] = corpora[ci], None
+        flagged = ladder.capacity_flagged(outs[1])
+        if len(flagged):
+            with tracing.span(SPAN_GATHER):
+                salvaged[ci] = (flagged, gather_corpus(corpus, flagged))
+
+    def escalate(ci, out):
+        if ci in salvaged:
+            flagged, sub = salvaged.pop(ci)
+            pending.append((ci * chunk_workflows + flagged,
+                            ladder.submit(sub)))
+        return out
+
     start = time.perf_counter()
-    results, prep = executor.run(n_chunks, pack, launch, consume)
+    results, prep = executor.run(n_chunks, pack, launch, consume, escalate,
+                                 retire)
     with tracing.span("feed.gather"):
         first = np.concatenate([r for r, _ in results])[:total]
         errors = np.concatenate([e for _, e in results])[:total]
+    report.ladder_s = prep.escalate_s
+    if pending:
+        t0 = time.perf_counter()
+        outcomes = ladder.finish([p for _, p in pending])
+        with tracing.span("feed.ladder.patch"):
+            for (rows, _), o in zip(pending, outcomes):
+                first[rows[o.resolved]] = o.rows[o.resolved]
+                errors[rows[o.resolved]] = 0
+                report.ladder_resolved += int(o.resolved.sum())
+            report.ladder_indices = np.concatenate(
+                [rows for rows, _ in pending])
+        report.ladder_residual = (len(report.ladder_indices)
+                                  - report.ladder_resolved)
+        for rung in ladder.last_run:
+            report.ladder_rows += rung["rows"]
+            report.ladder_lanes += rung["lanes"]
+            report.ladder_events += rung["events"]
+            report.ladder_wire_bytes += rung["wire_bytes"]
+        report.ladder_s += time.perf_counter() - t0
     report.chunks = prep.chunks
     report.pack_queue_wait_s = prep.pack_queue_wait_s
     report.pack_s = shared["pack_s"]

@@ -305,8 +305,9 @@ def replay_escalated(events: jnp.ndarray, layout: PayloadLayout,
     a row is resolved when error == 0 and narrow_overflow is unset."""
     from .payload import payload_rows_narrow
 
-    s = replay_events(events, layout)
-    rows, ovf = payload_rows_narrow(s, out_layout)
+    with jax.named_scope("ladder-rung"):
+        s = replay_events(events, layout)
+        rows, ovf = payload_rows_narrow(s, out_layout)
     return rows, s.error, ovf, s.current_branch
 
 
@@ -318,8 +319,9 @@ def replay_escalated_state(events: jnp.ndarray, layout: PayloadLayout,
     out of the widened state's occupied slots."""
     from .payload import payload_rows_narrow
 
-    s = replay_events(events, layout)
-    rows, ovf = payload_rows_narrow(s, out_layout)
+    with jax.named_scope("ladder-rung"):
+        s = replay_events(events, layout)
+        rows, ovf = payload_rows_narrow(s, out_layout)
     return s, rows, s.error, ovf
 
 
@@ -337,9 +339,10 @@ def replay_wirec_escalated_crc(slab: jnp.ndarray, bases: jnp.ndarray,
     from .crc import crc32_rows
     from .payload import payload_rows_narrow
 
-    s = replay_wirec(slab, bases, n_events, profile, layout)
-    rows, ovf = payload_rows_narrow(s, out_layout)
-    return crc32_rows(rows), s.error, ovf
+    with jax.named_scope("ladder-rung"):
+        s = replay_wirec(slab, bases, n_events, profile, layout)
+        rows, ovf = payload_rows_narrow(s, out_layout)
+        return crc32_rows(rows), s.error, ovf
 
 
 @jax.jit

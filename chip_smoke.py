@@ -286,9 +286,10 @@ def _gen_init() -> None:
 
 
 def _gen_slice(job):
-    """Worker: generate + encode one slice of one suite, and the
-    oracle's CRC32 for the sampled workflows that fall inside it."""
-    suite, seed, lo, hi, target_events, sampled = job
+    """Worker: generate + encode one slice of one suite, the oracle's
+    CRC32 for the sampled workflows that fall inside it and, where the
+    suite is fed as wire bytes, the serialized histories."""
+    suite, seed, lo, hi, target_events, sampled, serialized = job
     import numpy as np
 
     from cadence_tpu.core.checksum import (
@@ -296,6 +297,7 @@ def _gen_slice(job):
         crc32_of_row,
         payload_row,
     )
+    from cadence_tpu.core.codec import serialize_corpus
     from cadence_tpu.gen.corpus import generate_history
     from cadence_tpu.ops.encode import encode_corpus
     from cadence_tpu.oracle.state_builder import StateBuilder
@@ -307,17 +309,21 @@ def _gen_slice(job):
         row = payload_row(StateBuilder().replay_history(histories[i - lo]))
         row[STICKY_ROW_INDEX] = 0
         oracle[i] = int(np.uint32(crc32_of_row(row)))
-    return suite, lo, encode_corpus(histories), oracle
+    blobs = serialize_corpus(histories) if serialized else []
+    return suite, lo, encode_corpus(histories), oracle, blobs
 
 
 class CorpusFarm:
     """Seeded corpora made in bulk by worker processes while the phase
     process drives the device: `suite(name)` returns ([W, E, L] int64
-    lanes, {sampled index: oracle CRC32}). Must be started BEFORE this
-    process opens the chip and is safe after it: the workers are pinned
-    to the CPU platform and only run numpy and the Python oracle."""
+    lanes, {sampled index: oracle CRC32}) and leaves the suite's wire
+    blobs, if it is one of `serialized`, in `blobs[name]`. Must be started
+    BEFORE this process opens the chip and is safe after it: the workers
+    are pinned to the CPU platform and only run numpy and the Python
+    oracle."""
 
-    def __init__(self, suites, size, seed: int, sample_all=()) -> None:
+    def __init__(self, suites, size, seed: int, sample_all=(),
+                 serialized=()) -> None:
         import multiprocessing
         import random
         from concurrent.futures import ProcessPoolExecutor
@@ -331,13 +337,15 @@ class CorpusFarm:
             for lo in range(0, W, step):
                 hi = min(lo + step, W)
                 jobs.append((suite, seed, lo, hi, size["target_events"],
-                             [i for i in picked if lo <= i < hi]))
+                             [i for i in picked if lo <= i < hi],
+                             suite in serialized))
         workers = max(1, min(len(jobs), (os.cpu_count() or 2) - 1))
         self._pool = ProcessPoolExecutor(
             max_workers=workers,
             mp_context=multiprocessing.get_context("spawn"),
             initializer=_gen_init)
         self._futures = {}
+        self.blobs = {}
         for job in jobs:
             self._futures.setdefault(job[0], []).append(
                 self._pool.submit(_gen_slice, job))
@@ -355,9 +363,11 @@ class CorpusFarm:
         events = np.zeros((W, E, NUM_LANES), dtype=np.int64)
         events[:, :, LANE_EVENT_TYPE] = -1  # padding rows, as encode_corpus
         oracle = {}
-        for _suite, lo, lanes, crcs in parts:
+        self.blobs[name] = []
+        for _suite, lo, lanes, crcs, blobs in parts:
             events[lo:lo + lanes.shape[0], :lanes.shape[1]] = lanes
             oracle.update(crcs)
+            self.blobs[name].extend(blobs)
         return events, oracle
 
     def close(self) -> None:
@@ -376,7 +386,7 @@ def _oracle_divergence(crcs, oracle) -> int:
 
 
 def _replay_suite(checks: Checks, suite: str, events, oracle, mesh, layout,
-                  size, expect_clean: bool = True):
+                  size):
     """One suite through both serving-executor paths on `mesh`: the
     compressed stream (native pack -> wirec -> CRC on device) and the
     dense one (int64 lanes -> payload rows). Returns (crcs, errors,
@@ -420,7 +430,7 @@ def _replay_suite(checks: Checks, suite: str, events, oracle, mesh, layout,
     (rows2, _errors2), dense_warm_s = _timed(dense)
 
     flagged = int((err != 0).sum())
-    divergent = _oracle_divergence(crc, oracle) if expect_clean else None
+    divergent = _oracle_divergence(crc, oracle)
     say(phase=checks.phase, suite=suite, devices=n, workflows=W,
         events=real, event_axis=int(events.shape[1]),
         encoder="native" if native else "python", h2d=h2d_path(),
@@ -438,11 +448,10 @@ def _replay_suite(checks: Checks, suite: str, events, oracle, mesh, layout,
                   and bool((crc32_of_rows(rows)[err == 0]
                             == crc[err == 0]).all()),
                   f"{suite}: dense rows and wirec CRCs disagree")
-    if expect_clean:
-        checks.expect(flagged == 0, f"{suite}: {flagged} kernel error flags")
-        checks.expect(divergent == 0,
-                      f"{suite}: {divergent} of {len(oracle)} sampled "
-                      "workflows diverge from the oracle")
+    checks.expect(flagged == 0, f"{suite}: {flagged} kernel error flags")
+    checks.expect(divergent == 0,
+                  f"{suite}: {divergent} of {len(oracle)} sampled "
+                  "workflows diverge from the oracle")
     return np.asarray(crc), np.asarray(err), corpus
 
 
@@ -503,13 +512,13 @@ def phase_bulk(args, size) -> int:
     from cadence_tpu.gen.corpus import SUITES
 
     farm = CorpusFarm(SUITES + ("overflow",), size, args.seed,
-                      sample_all=("overflow",))
+                      sample_all=("overflow",), serialized=("overflow",))
     try:
         device = _open_device(checks, args.rehearse, 1)
         import numpy as np
 
         from cadence_tpu.core.checksum import DEFAULT_LAYOUT as layout
-        from cadence_tpu.engine.ladder import EscalationLadder
+        from cadence_tpu.native.feeder import feed_serialized_wirec
         from cadence_tpu.parallel.mesh import make_mesh, serving_mesh
         from cadence_tpu.utils import metrics as m
 
@@ -522,31 +531,43 @@ def phase_bulk(args, size) -> int:
             _replay_suite(checks, suite, events, oracle, mesh, layout, size)
 
         # overflow: ~2.7% of workflows exceed the device's pending
-        # tables; the ladder re-replays exactly those at widened K, on
-        # device, and nothing may be left for the host oracle
+        # tables; the serialized feeder re-replays exactly those at
+        # widened K, on device, inside the call (the path the benchmark's
+        # replay.overflow-1chip times), and nothing may be left for the
+        # host oracle
         events, oracle = farm.suite("overflow")
-        crc, err, corpus = _replay_suite(checks, "overflow", events, oracle,
-                                         mesh, layout, size,
-                                         expect_clean=False)
-        ladder = EscalationLadder(layout)
-        flagged = np.nonzero(err != 0)[0]
-        cap = ladder.capacity_flagged(err)
-        (crc_l, resolved, _err_l), ladder_s = _timed(
-            lambda: ladder.escalate_wirec(corpus, cap))
-        fixed = crc.copy()
-        fixed[cap[resolved]] = crc_l[resolved]
-        residual = (len(flagged) - len(cap)) + int((~resolved).sum())
-        divergent = _oracle_divergence(fixed, oracle)
+        chunk = -(-events.shape[0] // size["chunks"])
+
+        def feed():
+            return feed_serialized_wirec(farm.blobs["overflow"],
+                                         events.shape[1],
+                                         chunk_workflows=chunk,
+                                         layout=layout)
+
+        (crc, err, rep), first_s = _timed(feed)
+        (crc2, err2, rep), warm_s = _timed(feed)
+        flagged = len(rep.ladder_indices)
+        residual = int((err != 0).sum())
+        divergent = _oracle_divergence(crc, oracle)
         say(phase="bulk", suite="overflow", ladder=True,
-            flagged=int(len(flagged)), capacity_flagged=int(len(cap)),
-            resolved_on_device=int(resolved.sum()),
-            residual_oracle_rows=residual, rungs=ladder.last_run,
-            ladder_s=ladder_s, oracle_checked=len(oracle),
-            oracle_divergent=divergent)
-        checks.expect(len(flagged) >= max(1, events.shape[0] // 100),
+            workflows=int(events.shape[0]), events=int(rep.events),
+            chunks=int(rep.chunks),
+            encoder="native" if rep.native_wirec else "python",
+            flagged=flagged, resolved_on_device=int(rep.ladder_resolved),
+            residual_oracle_rows=residual,
+            ladder_rows=int(rep.ladder_rows),
+            ladder_lanes=int(rep.ladder_lanes),
+            ladder_events=int(rep.ladder_events), ladder_s=rep.ladder_s,
+            feed_first_s=first_s, feed_warm_s=warm_s,
+            oracle_checked=len(oracle), oracle_divergent=divergent)
+        checks.expect(rep.native_wirec,
+                      "overflow: packed by the pure-Python encoder")
+        checks.expect(bool((crc == crc2).all() and (err == err2).all()),
+                      "overflow: two runs of the feeder disagree")
+        checks.expect(flagged >= max(1, events.shape[0] // 100),
                       "overflow: the suite flagged almost nothing, so the "
                       "ladder was not exercised")
-        checks.expect(residual == 0,
+        checks.expect(residual == 0 and rep.ladder_residual == 0,
                       f"overflow: {residual} rows left to the host oracle")
         checks.expect(divergent == 0, f"overflow: {divergent} workflows "
                       "diverge from the oracle after the ladder")
