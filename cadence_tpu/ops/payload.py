@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from ..core.checksum import DEFAULT_LAYOUT, PAD, PayloadLayout
 from ..core.checksum import fnv64 as _fnv64  # noqa: F401 (sticky always empty → 0)
-from .state import ReplayState
+from .state import ReplayState, pick_branch
 
 
 def _sorted_ids(occ: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
@@ -71,13 +71,9 @@ def payload_rows_narrow(s: ReplayState, out_layout: PayloadLayout
         ],
         axis=1,
     )
-    bidx = s.current_branch.astype(jnp.int32)
-    vh_event_ids = jnp.take_along_axis(
-        s.vh_event_ids, bidx[:, None, None], axis=1).squeeze(1)
-    vh_versions = jnp.take_along_axis(
-        s.vh_versions, bidx[:, None, None], axis=1).squeeze(1)
-    vh_count = jnp.take_along_axis(s.vh_count, bidx[:, None],
-                                   axis=1).squeeze(1)
+    vh_event_ids = pick_branch(s.vh_event_ids, s.current_branch)
+    vh_versions = pick_branch(s.vh_versions, s.current_branch)
+    vh_count = pick_branch(s.vh_count, s.current_branch)
     overflow = vh_count.astype(jnp.int64) > Kv
     vh_pairs = jnp.stack(
         [vh_event_ids[:, :Kv], vh_versions[:, :Kv]], axis=2

@@ -167,6 +167,26 @@ CAPACITY_ERRORS = (
 )
 
 
+def pick_branch(arr: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """Each workflow's row `idx[w]` of a version-history table: `arr`
+    [W, B, ...] → [W, ...]. The one way the `vh_*` tables are indexed by
+    branch (ops/transitions.step, ops/payload).
+
+    B is static, so the pick is a chain of B - 1 selects over slices of
+    axis 1 and never a gather. On the v5e a gather here costs twice: the
+    chip serialises it, and it pins the tables' layout with Kv = 8 on the
+    128 lanes, so every other op over them runs lane-sparse too; selects
+    fuse into their consumers and leave W on the lanes (PERF.md §6,
+    PR 28: the replay kernel 95.7 → 4.1 ns an event). `idx` must lie in
+    [0, B - 1] — `step` clips the event's lanes, and `current_branch` is
+    only ever written from a clipped index; row 0 is the chain's default."""
+    idx = idx.reshape(idx.shape + (1,) * (arr.ndim - 2))
+    out = arr[:, 0]
+    for j in range(1, arr.shape[1]):
+        out = jnp.where(idx == j, arr[:, j], out)
+    return out
+
+
 def widen_layout(layout: PayloadLayout, factor: int) -> PayloadLayout:
     """The escalation-rung layout: every kernel capacity multiplied by
     `factor` (the reference's pending maps are unbounded Go maps —

@@ -46,7 +46,7 @@ from .encode import (
     LANE_TIMESTAMP,
     LANE_VERSION,
 )
-from .state import ErrorCode, ReplayState, reset_rows
+from .state import ErrorCode, ReplayState, pick_branch, reset_rows
 
 _I64 = jnp.int64
 
@@ -202,18 +202,12 @@ def step(s: ReplayState, ev: jnp.ndarray,
     b = jnp.clip(branch, 0, B - 1)
     p = jnp.clip(parent, 0, B - 1)
 
-    def gather_branch(arr, idx):
-        # arr [W, B, ...] → rows of branch idx [W, ...]
-        return jnp.take_along_axis(
-            arr, idx.astype(jnp.int32).reshape((-1, 1) + (1,) * (arr.ndim - 2)),
-            axis=1).squeeze(1)
-
-    b_ids = gather_branch(s.vh_event_ids, b)        # [W, Kv]
-    b_versions = gather_branch(s.vh_versions, b)    # [W, Kv]
-    b_count = gather_branch(s.vh_count[..., None], b).squeeze(-1)  # [W]
-    p_ids = gather_branch(s.vh_event_ids, p)
-    p_versions = gather_branch(s.vh_versions, p)
-    p_count = gather_branch(s.vh_count[..., None], p).squeeze(-1)
+    b_ids = pick_branch(s.vh_event_ids, b)        # [W, Kv]
+    b_versions = pick_branch(s.vh_versions, b)    # [W, Kv]
+    b_count = pick_branch(s.vh_count, b)          # [W]
+    p_ids = pick_branch(s.vh_event_ids, p)
+    p_versions = pick_branch(s.vh_versions, p)
+    p_count = pick_branch(s.vh_count, p)
 
     # fork-inherit: copy the parent's item prefix covering events < ev_id,
     # capping the covering item at ev_id - 1 (the LCA event)
@@ -251,8 +245,8 @@ def step(s: ReplayState, ev: jnp.ndarray,
     )
 
     # current branch's last version (for UpdateCurrentVersion on completed)
-    cur_versions = gather_branch(s.vh_versions, s.current_branch)
-    cur_count = gather_branch(s.vh_count[..., None], s.current_branch).squeeze(-1)
+    cur_versions = pick_branch(s.vh_versions, s.current_branch)
+    cur_count = pick_branch(s.vh_count, s.current_branch)
     cur_last_idx = jnp.maximum(cur_count - 1, 0)
     cur_last_onehot = jnp.arange(Kv)[None, :] == cur_last_idx[:, None]
     cur_last_version = jnp.where(

@@ -113,6 +113,18 @@ def _wirec_args(W, shape, sharding_for):
                                  sharding=sharding_for(1)))
 
 
+def _no_gather(compiled):
+    """No `gather` instruction anywhere in the module. Counted over the
+    whole text, not under the `transition` scope: the 64-bit split
+    rewrites an int64 gather into u32 gathers whose metadata is the bare
+    `op_name="gather"`, so a scoped count reads 0 with them there (the
+    program before `ops/state.pick_branch` held 13: eight in the scan
+    body, the branch picks, and five in `payload`)."""
+    held = [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if " gather(" in line]
+    assert not held, held
+
+
 def _fits(compiled):
     """The program's own bytes on one device, against the chip's HBM
     less what the resident tier may hold there at the same time."""
@@ -142,6 +154,8 @@ def test_wirec_bulk_kernels(one_chip, wirec_shape, W):
         ops = ops_under_scope(compiled.as_text(), "crc32")
         assert ops["convolution"] > 0, ops
         assert ops["gather"] == 0 and ops["while"] == 0, ops
+        # and the transition picks its version-history branch by select
+        _no_gather(compiled)
 
 
 def test_wirec_stream_on_four_chips(mesh4, wirec_shape):
@@ -157,6 +171,7 @@ def test_wirec_stream_on_four_chips(mesh4, wirec_shape):
     compiled = _replay_wirec_crc_with_stats.lower(
         *args, wirec_shape[3], DEFAULT_LAYOUT).compile()
     _fits(compiled)
+    _no_gather(compiled)
     assert set(re.findall(
         r"all-reduce|all-gather|reduce-scatter|collective-permute|"
         r"all-to-all", compiled.as_text())) == {"all-reduce"}
@@ -238,17 +253,22 @@ def test_fused_generator_kernel(topo, n):
     _fits(fn.lower(seed, offsets).compile())
 
 
-def test_ladder_rung_at_widened_k(one_chip, wirec_shape):
-    """engine/ladder's first rung over a wirec sub-corpus: replay at 2x
-    the pending-table capacities, payload narrowed back to base width,
-    CRC on device."""
+@pytest.mark.parametrize("rung", [1, 2])
+def test_ladder_rung_at_widened_k(one_chip, wirec_shape, rung):
+    """engine/ladder's rungs over a wirec sub-corpus: replay at 2x and 4x
+    the pending-table capacities (B = 4 and 8 version-history branches),
+    payload narrowed back to base width, CRC on device — and the branch
+    pick still a chain of selects at those widths, no gather."""
     from cadence_tpu.ops.replay import replay_wirec_escalated_crc
     from cadence_tpu.ops.state import widen_layout
 
     args = _wirec_args(LADDER_W, wirec_shape, lambda nd: one_chip)
-    _fits(replay_wirec_escalated_crc.lower(
-        *args, wirec_shape[3], widen_layout(DEFAULT_LAYOUT, 2),
-        DEFAULT_LAYOUT).compile())
+    wide = widen_layout(DEFAULT_LAYOUT, 2 ** rung)
+    assert wide.max_branches == DEFAULT_LAYOUT.max_branches * 2 ** rung
+    compiled = replay_wirec_escalated_crc.lower(
+        *args, wirec_shape[3], wide, DEFAULT_LAYOUT).compile()
+    _fits(compiled)
+    _no_gather(compiled)
 
 
 def test_visibility_scans_at_a_million_rows(one_chip):
