@@ -333,7 +333,7 @@ def main(argv=None) -> int:
     # open-loop load harness (bench/ + canary/ load tooling,
     # cadence_tpu/loadgen/): launches a REAL wire cluster, drives seeded
     # open-loop traffic, evaluates latency SLOs, optionally records a
-    # LOADGEN_r0N.json trajectory next to BENCH_r*.json
+    # LOADGEN_r0N.json trajectory in the working directory
     load_grp = sub.add_parser("load").add_subparsers(dest="cmd",
                                                      required=True)
     # the serving-tier comparison (in-process, tier on vs off; records

@@ -1,12 +1,10 @@
 """Pipelined, MESH-AWARE bulk-replay executor: the ONE hot path every
 bulk consumer shares (engine/tpu_engine.py, engine/rebuild.py,
-native/feeder.py, bench.py) — and, since ISSUE 7, the one sharded code
-path the dryrun_multichip scaling diagnostic exercises too.
+native/feeder.py) — and, since ISSUE 7, the one sharded code path the
+dryrun_multichip scaling diagnostic exercises too.
 
-BENCH_r05 showed the end-to-end replay path at ~740k events/s while the
-warm kernel alone sustains ~3.9M: the device idled ~80% of the time
-waiting on single-threaded host packing. The fix is a producer/consumer
-pipeline:
+With one thread packing, the device idles most of a bulk replay waiting
+on the host. The fix is a producer/consumer pipeline:
 
 - a bounded pack THREAD POOL produces host chunks ahead of the device
   consumer — the double-buffer reuse discipline the feeder used at
@@ -268,10 +266,10 @@ class BulkReplayExecutor:
 # The mesh-aware serving paths — ONE code path at every device count.
 # replay_corpus_mesh serves a packed dense corpus from N devices through
 # the pipelined executor above; stream_wirec_mesh does the same for a
-# compressed wirec corpus reduced to CRCs on device. bench.py's
-# measurement path, __graft_entry__.dryrun_multichip's scaling
-# diagnostic, and the perf-gate mesh tests all call these two functions,
-# so the diagnostic and the serving path can never drift.
+# compressed wirec corpus reduced to CRCs on device. chip_smoke.py's
+# bulk and mesh phases, __graft_entry__.dryrun_multichip's scaling
+# diagnostic, and tests/test_mesh_executor.py all call these two
+# functions, so the diagnostic and the serving path can never drift.
 # ---------------------------------------------------------------------------
 
 
@@ -382,8 +380,7 @@ def stream_wirec_mesh(corpus, mesh=None, layout=None, n_chunks: int = 1,
     `n_chunks` workflow chunks: each chunk's compressed slab splits into
     per-device slice copies whose H2D overlaps the previous chunk's
     sharded replay, and the device reduces to CRC32s (4 bytes/workflow
-    back). `n_chunks` must divide W and keep shards whole — the same
-    contract bench's transfer-included measurement always had.
+    back). `n_chunks` must divide W and keep shards whole.
 
     Returns (crc32 [W] uint32, errors [W], PipelineReport)."""
     import jax

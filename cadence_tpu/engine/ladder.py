@@ -4,8 +4,8 @@ The reference never degrades on capacity — its pending maps are unbounded
 Go maps (mutable_state_builder.go) — but the kernel's tables are fixed at
 PayloadLayout's K, so a workflow that transiently holds more than K
 pending items flags TABLE_OVERFLOW and, before this module, exited the
-batched kernel into a per-workflow Python oracle (BENCH_r05: 2.7% flagged
-workflows collapsed the mixed rate 3x, `oracle_leg_s_median` = 1.078s).
+batched kernel into a per-workflow Python oracle: a few percent of
+flagged workflows set the pace of the whole corpus.
 
 The ladder replaces that scalar leg with batched device work: rows
 flagged with a CAPACITY error (ops/state.CAPACITY_ERRORS) are gathered
@@ -162,7 +162,7 @@ class EscalationLadder:
         self.variants = (variants if variants is not None
                          else compile_cache.DEFAULT_VARIANTS)
         #: per-rung accounting of the most recent escalate/finish call
-        #: (bench.py and the feeder's report read this)
+        #: (the feeder's report reads this)
         self.last_run: List[dict] = []
         self._prof = ReplayProfiler(self.metrics, scope=m.SCOPE_TPU_FALLBACK)
 

@@ -131,7 +131,7 @@ class AppendResult:
 
 @dataclass
 class AppendReport:
-    """Per-call accounting (bench's incremental suite reads this)."""
+    """Per-call accounting of `replay_append_report`."""
 
     transactions: int = 0
     events_appended: int = 0
@@ -661,8 +661,8 @@ class ResidentStateCache:
 
 
 def _encode_suffix_cold(key, batches, from_batch: int) -> np.ndarray:
-    """Pack-cache-free suffix encoder (standalone consumers: bench,
-    tests): a full resumable encode sliced at the prefix row count —
+    """Pack-cache-free suffix encoder (standalone consumers: tests): a
+    full resumable encode sliced at the prefix row count —
     byte-identical to the pack cache's suffix path, just without the
     O(suffix) warm cost."""
     from ..ops.encode import encode_batches_resumable

@@ -4,7 +4,8 @@ Produces the five BASELINE workload shapes (BASELINE.md / BASELINE.json
 configs) as synthetic-but-valid workflow histories, used for:
 
 - differential testing: oracle replayer vs TPU kernel (checksum parity),
-- benchmarking: bench.py replays generated corpora at scale.
+- benchmarking: the replay cells (benchmarks/) replay the same shapes at
+  scale, from their own copy of this module.
 
 Workload shapes mirror the reference load/canary suites:
   basic            /root/reference/bench/load/basic/stressWorkflow.go
@@ -542,7 +543,7 @@ def generate_history(suite: str, seed: int, workflow_index: int = 0,
     `"fuzz"` / `"fuzz:<profile>"` route to the compositional fuzzer
     (gen/fuzz.py) — the whole decision surface behind the same
     `(suite, seed, workflow_index)` addressing every consumer
-    (bench.py, tests, promoted CorpusSpecs) already speaks."""
+    (tests, promoted CorpusSpecs) already speaks."""
     if suite == "fuzz" or suite.startswith("fuzz:"):
         from .fuzz import generate_fuzz_history
         profile = suite.partition(":")[2] or "mixed"

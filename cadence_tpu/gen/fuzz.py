@@ -33,9 +33,9 @@ gen/corpus.py), across processes and platforms; `history_digest` is the
 canonical byte witness the shrinker reports and tests pin.
 
 Promotion: interesting shapes become named `CorpusSpec` JSON files
-(fuzz_specs/*.json) that `bench.py` and `generate_corpus("fuzz:...")`
-consume — a discovered adversarial structure graduates into a permanent
-bench suite and perf-gate input via `fuzz promote` (cli.py).
+(fuzz_specs/*.json) via `fuzz promote` (cli.py) — a discovered
+adversarial structure graduates into a permanent, digest-guarded corpus
+that `spec.generate()` rebuilds byte for byte.
 """
 from __future__ import annotations
 
@@ -652,7 +652,7 @@ def fork_ndc_branch(stores, key: Tuple[str, str, str], seed: int,
 
 
 # ---------------------------------------------------------------------------
-# Promotion: named corpus specs consumable by bench.py
+# Promotion: named corpus specs
 # ---------------------------------------------------------------------------
 
 SPEC_SCHEMA = "fuzz-corpus-spec-v1"
@@ -838,8 +838,7 @@ def write_fuzz_trajectory(doc: dict, root: str = ".",
 
 
 def load_specs(root: str = ".") -> List[CorpusSpec]:
-    """Every promoted spec under root/fuzz_specs, name-sorted (bench.py
-    consumes these as permanent suites)."""
+    """Every promoted spec under root/fuzz_specs, name-sorted."""
     directory = os.path.join(root, SPEC_DIR)
     if not os.path.isdir(directory):
         return []

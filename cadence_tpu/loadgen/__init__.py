@@ -12,7 +12,7 @@ low throughput. This package is that tooling for the wire cluster:
                   op's INTENDED send time, so coordinated omission is
                   structurally impossible;
 - `slo.py`        per-op/per-domain latency SLO evaluation (p50/p99/p999);
-- `report.py`     LOADGEN_r0N.json trajectory files next to BENCH_r*.json;
+- `report.py`     LOADGEN_r0N.json trajectory files, one per recorded run;
 - `scenarios.py`  end-to-end scenarios against a real `rpc/cluster.py`
                   cluster — notably the two-domain overload proof that
                   admission control sheds the aggressor while the victim

@@ -1,10 +1,11 @@
 """Open-loop load generator: scheduled arrivals, intended-time latency.
 
-The defining property (and the reason bench.py cannot measure a latency
-trajectory): this driver is OPEN-LOOP. The schedule of intended send
-times is fixed before the run (mixes.build_schedule), and every op's
-latency is measured from its INTENDED send time — not from when a free
-thread finally got around to sending it. When the server (or the
+The defining property (and the reason a closed replay loop cannot
+measure a latency trajectory): this driver is OPEN-LOOP. The schedule
+of intended send times is fixed before the run
+(mixes.build_schedule), and every op's latency is measured from its
+INTENDED send time — not from when a free thread finally got around to
+sending it. When the server (or the
 dispatch pool) falls behind, the backlog shows up as GROWING latency,
 exactly as queueing users would experience it; a closed-loop driver
 would instead slow its own arrivals and report a flattering

@@ -1,10 +1,9 @@
 """LOADGEN_r0N.json latency trajectory files.
 
-The loadgen's analog of the BENCH_r*.json trajectory: one JSON document
-per recorded run, numbered r01, r02, ... next to the bench files, so
-the latency story (p50/p99/p999 per op per domain, shed/admit counts,
-SLO verdicts, checksum-verify outcome) accretes run over run the same
-way the throughput story does.
+One JSON document per recorded run, numbered r01, r02, ... in the
+directory the run was started from, so the latency story (p50/p99/p999
+per op per domain, shed/admit counts, SLO verdicts, checksum-verify
+outcome) accretes run over run.
 """
 from __future__ import annotations
 

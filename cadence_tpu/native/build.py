@@ -106,16 +106,6 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_int64),   # out
             ctypes.c_int64,                   # num_threads
         ]
-        lib.cadence_pack_corpus32.restype = ctypes.c_int64
-        lib.cadence_pack_corpus32.argtypes = [
-            ctypes.c_char_p,                  # blob
-            ctypes.POINTER(ctypes.c_int64),   # offsets
-            ctypes.c_int64,                   # num_workflows
-            ctypes.c_int64,                   # max_events
-            ctypes.c_int64,                   # num_lanes (NUM_LANES32)
-            ctypes.POINTER(ctypes.c_int32),   # out
-            ctypes.c_int64,                   # num_threads
-        ]
     return _load_lib(_SRC, "cadence_packer", configure)
 
 

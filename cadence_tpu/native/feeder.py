@@ -5,15 +5,18 @@ so packing and replay overlap. The pipeline itself is the shared bulk
 executor (engine/executor.py): a bounded pack THREAD POOL produces chunks
 up to `depth` ahead of the device consumer into a ring of preallocated
 buffers (no per-chunk allocation), the ring-slot reuse discipline blocks a
-packer until the chunk that last used its slot has fully replayed (the
-depth-2 discipline of the old double-buffer loop, generalized to depth N),
-and the consumer's `pack-queue-wait` profiler leg says which side of the
-pipeline is starving. Every chunk shares one [C, E, L] shape, so a single
-compiled executable serves the whole stream.
+packer until the chunk that last used its slot has fully replayed, and the
+consumer's `pack-queue-wait` profiler leg says which side of the pipeline
+is starving. Every chunk shares one shape and, short of a refit, one
+wirec profile, so a single compiled executable serves the whole stream.
 
-The feeder is the production ingest path the bench and bulk-replay flows
-use; `FeedReport` carries the sustained end-to-end rate next to the
-packer's standalone rate so the pipeline's overhead is always measured.
+Two host→device formats: wirec (ops/wirec.py) is the transfer format, and
+`feed_serialized_wirec` its one pipelined loop; the dense int64 lanes
+(ops/replay.py `replay_to_payload`) are the reference, which rebuild and
+the serving tier also replay.
+
+`FeedReport` carries the sustained end-to-end rate next to the packer's
+standalone rate so the pipeline's overhead is always measured.
 """
 from __future__ import annotations
 
@@ -44,16 +47,16 @@ class FeedReport:
     #: consumer stalled waiting on the pack pool (engine/executor.py)
     depth: int = 0
     pack_queue_wait_s: float = 0.0
-    #: wirec pipeline only: host compression cost and wire density
+    #: host compression cost and wire density
     compress_s: float = 0.0
     wire_bytes: int = 0
     profile_refits: int = 0
     #: which encoder packed the chunks (native C++ fused pass vs the
     #: byte-identical pure-Python path) and what the staged host→device
-    #: handoff cost — the pinned-buffer H2D seconds bench records
+    #: handoff cost (the pinned-buffer H2D seconds)
     native_wirec: bool = False
     h2d_s: float = 0.0
-    #: capacity-escalation ladder inside the call (wirec pipeline only).
+    #: capacity-escalation ladder inside the call.
     #: Every rung counted: the rows the rungs replayed, their lanes (rows
     #: after the pow2 padding), the real events and the wire bytes of the
     #: gathered sub-corpora. Each flagged row once: resolved by a rung, or
@@ -114,102 +117,6 @@ def _chunk_blobs(blobs: Sequence[bytes], lo: int,
     if pad:
         chunk.extend([_EMPTY_BLOB] * pad)
     return chunk
-
-
-@tracing.spanned("feed.call")
-def _feed(blobs: Sequence[bytes], max_events: int, chunk_workflows: int,
-          layout: PayloadLayout, num_threads: Optional[int],
-          num_lanes: int, dtype, pack_fn, replay_fn,
-          depth: Optional[int] = None, mesh=None
-          ) -> Tuple[np.ndarray, np.ndarray, FeedReport]:
-    """The pipelined feed loop, shared by the int64 and wire32 formats,
-    on the bulk executor: ring of `depth` pack buffers, pack pool runs
-    ahead of the device, a buffer is reused only after the chunk that
-    last used it has fully replayed (the depth-2 buffer-reuse race fix
-    of VERDICT r3 weak #1, generalized). Under a serving mesh each
-    chunk's workflow axis shards over 'shard' with per-device slice
-    copies — the ingest pipeline feeds N devices from one host."""
-    import jax
-
-    with tracing.span("feed.setup"):
-        mesh = _resolve_mesh(mesh)
-        chunk_workflows = _mesh_chunk(chunk_workflows, mesh)
-        total = len(blobs)
-        executor = BulkReplayExecutor(depth=depth, mesh=mesh)
-        report = FeedReport(workflows=total, depth=executor.depth)
-        prof = ReplayProfiler()
-        buffers = [np.empty((chunk_workflows, max_events, num_lanes),
-                            dtype=dtype) for _ in range(executor.depth)]
-    n_chunks = -(-total // chunk_workflows) if total else 0
-    chunk_events = [0] * n_chunks
-
-    def pack(ci):
-        chunk = _chunk_blobs(blobs, ci * chunk_workflows, chunk_workflows)
-        packed = pack_fn(chunk, max_events, num_threads=num_threads,
-                         out=buffers[ci % executor.depth])
-        chunk_events[ci] = int((packed[:, :, 0] > 0).sum())
-        return packed
-
-    def launch(ci, packed):
-        # async dispatch: the device crunches while later chunks pack
-        with prof.leg(m.M_PROFILE_H2D):
-            if mesh is not None:
-                from ..parallel.mesh import place_corpus
-                device_chunk = place_corpus(packed, mesh)
-            else:
-                device_chunk = jax.device_put(packed)
-            prof.h2d(packed.nbytes)
-        return replay_fn(device_chunk, layout)
-
-    def consume(ci, outs):
-        with prof.leg(m.M_PROFILE_KERNEL):
-            jax.block_until_ready(outs)
-        with prof.leg(m.M_PROFILE_READBACK):
-            return np.asarray(outs[0]), np.asarray(outs[1])
-
-    start = time.perf_counter()
-    results, prep = executor.run(n_chunks, pack, launch, consume)
-    with tracing.span("feed.gather"):
-        first = np.concatenate([r for r, _ in results])[:total]
-        errors = np.concatenate([e for _, e in results])[:total]
-    report.chunks = prep.chunks
-    report.pack_s = prep.pack_s
-    report.pack_queue_wait_s = prep.pack_queue_wait_s
-    report.events = sum(chunk_events)
-    report.wall_s = time.perf_counter() - start
-    return first, errors, report
-
-
-def feed_serialized(blobs: Sequence[bytes], max_events: int,
-                    chunk_workflows: int = 4096,
-                    layout: PayloadLayout = DEFAULT_LAYOUT,
-                    num_threads: Optional[int] = None,
-                    depth: Optional[int] = None, mesh=None
-                    ) -> Tuple[np.ndarray, np.ndarray, FeedReport]:
-    """Replay W serialized histories chunk-by-chunk; returns
-    (payload rows [W, width], errors [W], FeedReport)."""
-    from ..ops.replay import replay_to_payload
-
-    return _feed(blobs, max_events, chunk_workflows, layout, num_threads,
-                 packing.NUM_LANES, np.int64, packing.pack_serialized,
-                 replay_to_payload, depth=depth, mesh=mesh)
-
-
-def feed_serialized32(blobs: Sequence[bytes], max_events: int,
-                      chunk_workflows: int = 4096,
-                      layout: PayloadLayout = DEFAULT_LAYOUT,
-                      num_threads: Optional[int] = None,
-                      depth: Optional[int] = None, mesh=None
-                      ) -> Tuple[np.ndarray, np.ndarray, FeedReport]:
-    """The production ingest pipeline: wire bytes → C++ wire32 packer →
-    int32 H2D (44% of the int64 bytes) → device replay+checksum → 4
-    bytes/workflow back. Returns (crc32 [W] uint32, errors [W], report)."""
-    from ..ops.encode import NUM_LANES32
-    from ..ops.replay import replay_to_crc32
-
-    return _feed(blobs, max_events, chunk_workflows, layout, num_threads,
-                 NUM_LANES32, np.int32, packing.pack_serialized32,
-                 replay_to_crc32, depth=depth, mesh=mesh)
 
 
 @tracing.spanned("feed.call")
@@ -470,82 +377,6 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
     report.h2d_s = shared["h2d_s"]
     report.wall_s = time.perf_counter() - start
     return first, errors, report
-
-
-def feed_corpus(histories, chunk_workflows: int = 4096,
-                layout: PayloadLayout = DEFAULT_LAYOUT,
-                max_events: int = 0,
-                depth: Optional[int] = None, mesh=None
-                ) -> Tuple[np.ndarray, np.ndarray, FeedReport]:
-    """Convenience: serialize + feed an in-memory corpus."""
-    from ..core.codec import serialize_corpus
-    from ..ops.encode import history_length
-
-    if max_events <= 0:
-        max_events = max(history_length(h) for h in histories)
-    return feed_serialized(serialize_corpus(histories), max_events,
-                           chunk_workflows, layout, depth=depth, mesh=mesh)
-
-
-def feed_corpus32(histories, chunk_workflows: int = 4096,
-                  layout: PayloadLayout = DEFAULT_LAYOUT,
-                  max_events: int = 0,
-                  depth: Optional[int] = None, mesh=None
-                  ) -> Tuple[np.ndarray, np.ndarray, FeedReport]:
-    """Convenience: serialize + feed a corpus through the wire32 pipeline."""
-    from ..core.codec import serialize_corpus
-    from ..ops.encode import history_length
-
-    if max_events <= 0:
-        max_events = max(history_length(h) for h in histories)
-    return feed_serialized32(serialize_corpus(histories), max_events,
-                             chunk_workflows, layout, depth=depth, mesh=mesh)
-
-
-def feed_appends(items, resident_cache, pack_cache
-                 ) -> Tuple[list, FeedReport]:
-    """The SUFFIX-APPEND ingest path: the feeder twin of an append/
-    re-verify transaction stream. Each item is (workflow key, CURRENT
-    batches); suffix lanes come from engine/cache.PackCache.encode_suffix
-    — the resumed-interner suffix repack, O(new events) host cost,
-    byte-identical to the matching slice of a cold pack — and replay
-    against the HBM-resident states through the pipelined executor
-    (engine/resident.ResidentStateCache.replay_append): chunk shapes are
-    sized by the longest SUFFIX, so an append stream costs by appended
-    events, never history length (gated in test_perf_gate.py
-    TestFeederGate).
-
-    Returns (one AppendResult per item — exact hits served from the
-    resident payload without touching the device, misses ok=False for
-    the caller's cold full-replay path — , FeedReport whose events/
-    events_per_sec count APPENDED events only)."""
-    from ..engine.resident import AppendResult
-
-    t_start = time.perf_counter()
-    results: List[Optional[AppendResult]] = [None] * len(items)
-    suffix_items, suffix_pos = [], []
-    for i, (key, batches) in enumerate(items):
-        hit = resident_cache.lookup(key, batches)
-        if hit is None:
-            results[i] = AppendResult(ok=False)
-        elif hit[0] == "exact":
-            entry = hit[1]
-            results[i] = AppendResult(ok=True, payload=entry.payload,
-                                      branch=entry.branch, rung=entry.rung)
-        else:
-            suffix_pos.append(i)
-            suffix_items.append((key, hit[1], batches))
-    events = chunks = 0
-    if suffix_items:
-        outs, append_report = resident_cache.replay_append_report(
-            suffix_items, encode_suffix=pack_cache.encode_suffix)
-        for i, res in zip(suffix_pos, outs):
-            results[i] = res
-        events = append_report.events_appended
-        chunks = len(append_report.chunk_shapes)
-    return results, FeedReport(workflows=len(items), events=events,
-                               chunks=chunks,
-                               wall_s=time.perf_counter() - t_start)
 
 
 def feed_corpus_wirec(histories, chunk_workflows: int = 4096,

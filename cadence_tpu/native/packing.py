@@ -2,7 +2,7 @@
 from __future__ import annotations
 
 import ctypes
-from typing import List, Optional, Sequence
+from typing import Optional, Sequence
 
 import numpy as np
 
@@ -25,18 +25,15 @@ def blob_offsets(blobs: Sequence[bytes]):
     return blob, offsets
 
 
-def raise_pack_error(rc: int, wire32: bool = False) -> None:
+def raise_pack_error(rc: int) -> None:
     """Decode a native packer failure (-(workflow+1)*1000 - err) into
     the typed ValueError — shared by every caller of the corpus entry
     points so the error-code table can't drift per call site."""
     workflow = (-rc) // 1000 - 1
     err = (-rc) % 1000
-    codes = ("1=truncated, 2=unknown attr, 3=history exceeds max_events"
-             + (", 4=lane exceeds int32 — use the int64 path"
-                if wire32 else ""))
     raise ValueError(
         f"native packer failed on workflow {workflow} (code {err}: "
-        f"{codes})")
+        f"1=truncated, 2=unknown attr, 3=history exceeds max_events)")
 
 
 def pack_serialized(blobs: Sequence[bytes], max_events: int,
@@ -67,54 +64,3 @@ def pack_serialized(blobs: Sequence[bytes], max_events: int,
     if rc < 0:
         raise_pack_error(rc)
     return out
-
-
-def pack_serialized32(blobs: Sequence[bytes], max_events: int,
-                      num_threads: Optional[int] = None,
-                      out: Optional[np.ndarray] = None) -> np.ndarray:
-    """Pack W serialized histories into the wire32 transfer format
-    [W, max_events, NUM_LANES32] int32 (ops/encode.py: timestamp +
-    expiration split lo/hi, everything else range-checked) — 44% of the
-    int64 tensor's bytes on the host→device link."""
-    from ..ops.encode import NUM_LANES32
-
-    lib = _build.load()
-    if lib is None:
-        raise RuntimeError("native packer unavailable (no C++ toolchain)")
-    num_threads = pack_threads(num_threads, cap=max(1, len(blobs)))
-    W = len(blobs)
-    blob, offsets = blob_offsets(blobs)
-    if out is None:
-        out = np.empty((W, max_events, NUM_LANES32), dtype=np.int32)
-    else:
-        assert out.shape == (W, max_events, NUM_LANES32) and out.dtype == np.int32
-    rc = lib.cadence_pack_corpus32(
-        blob,
-        offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-        W, max_events, NUM_LANES32,
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        num_threads,
-    )
-    if rc < 0:
-        raise_pack_error(rc, wire32=True)
-    return out
-
-
-def encode_corpus_native(histories, max_events: int = 0) -> np.ndarray:
-    """Drop-in native replacement for ops.encode.encode_corpus.
-
-    Continue-as-new chains (batches with new_run_events) are not yet wired
-    through the wire codec / C++ packer — refuse loudly rather than silently
-    dropping the chained run (the Python packer chains via FLAG_RUN_RESET)."""
-    from ..core.codec import serialize_corpus
-
-    for h in histories:
-        for b in h:
-            if b.new_run_events:
-                raise ValueError(
-                    "native packer does not chain new_run_events yet; use "
-                    "ops.encode.encode_corpus for continued-as-new histories"
-                )
-    if max_events <= 0:
-        max_events = max(sum(len(b.events) for b in h) for h in histories)
-    return pack_serialized(serialize_corpus(histories), max_events)

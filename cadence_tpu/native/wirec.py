@@ -1,9 +1,8 @@
 """Native wirec pipeline: ctypes binding, reusable staging buffers, and
 the native/Python dispatcher every wirec-packing hot path routes through.
 
-BENCH_r05: device replay sustains ~3.9M events/s transfer-included while
-the streaming feeder sustains ~622k — the numpy wirec emit is the
-production bottleneck. `wirec.cc` ports measure/emit to C++ (threaded,
+The numpy wirec emit cannot keep pace with the device's replay, so
+`wirec.cc` ports measure/emit to C++ (threaded,
 byte-identical, same ProfileMisfit refit contract) and adds a FUSED
 entry point: wire blobs → int64 lanes → wirec adaptive-columnar buffers
 in one multi-threaded call, writing into preallocated reusable host

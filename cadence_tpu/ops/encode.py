@@ -414,39 +414,3 @@ def decode_lanes(rows: np.ndarray, domain_id: str = "bench-domain",
     if events:
         raise ValueError("lanes end mid-batch (no batch_last marker)")
     return batches
-
-
-# ---------------------------------------------------------------------------
-# wire32: the int32 transfer format
-# ---------------------------------------------------------------------------
-# Host→device bytes are the scarce resource; all but
-# two lanes fit int32 (event IDs, versions, timeouts, interned keys —
-# state_builder.go:132-646 consumes nothing wider), so the wire format
-# ships 20 int32 lanes instead of 18 int64: the two 64-bit values
-# (LANE_TIMESTAMP nanos, and the Started event's absolute
-# expiration_timestamp in attr lane 4) travel split as lo/hi halves and
-# are reconstructed exactly on device (ops/replay.py widen_wire32).
-
-LANE32_TS_HI = NUM_LANES       # hi-32 of LANE_TIMESTAMP
-LANE32_A4_HI = NUM_LANES + 1   # hi-32 of attr lane a4 (expiration nanos)
-NUM_LANES32 = NUM_LANES + 2    # 20
-
-_WIDE_LANES = (LANE_TIMESTAMP, LANE_A0 + 4)
-
-
-def to_wire32(events: np.ndarray) -> np.ndarray:
-    """[.., NUM_LANES] int64 → [.., NUM_LANES32] int32 (exact: wide lanes
-    split lo/hi). Raises OverflowError if any lane that must fit int32
-    doesn't — callers then stay on the int64 path rather than corrupt."""
-    ev = np.asarray(events, dtype=np.int64)
-    narrow = [i for i in range(NUM_LANES) if i not in _WIDE_LANES]
-    lo, hi = np.iinfo(np.int32).min, np.iinfo(np.int32).max
-    bad = (ev[..., narrow] < lo) | (ev[..., narrow] > hi)
-    if bad.any():
-        lanes = sorted({narrow[i] for i in np.argwhere(bad)[:, -1]})
-        raise OverflowError(f"lanes {lanes} exceed int32; use the int64 path")
-    out = np.empty(ev.shape[:-1] + (NUM_LANES32,), dtype=np.int32)
-    out[..., :NUM_LANES] = ev.astype(np.int32)  # wraps → lo32 halves
-    out[..., LANE32_TS_HI] = (ev[..., LANE_TIMESTAMP] >> 32).astype(np.int32)
-    out[..., LANE32_A4_HI] = (ev[..., LANE_A0 + 4] >> 32).astype(np.int32)
-    return out

@@ -88,47 +88,6 @@ def replay_to_payload_branch(events: jnp.ndarray,
     return payload_rows(s, layout), s.error, s.current_branch
 
 
-def widen_wire32(ev32: jnp.ndarray) -> jnp.ndarray:
-    """[.., NUM_LANES32] int32 → [.., NUM_LANES] int64, reconstructing the
-    two wide lanes exactly from their lo/hi halves (encode.to_wire32)."""
-    from .encode import LANE32_A4_HI, LANE32_TS_HI, LANE_A0, LANE_TIMESTAMP, NUM_LANES
-
-    base = ev32[..., :NUM_LANES].astype(jnp.int64)
-    lo_ts = ev32[..., LANE_TIMESTAMP].astype(jnp.uint32).astype(jnp.int64)
-    ts = (ev32[..., LANE32_TS_HI].astype(jnp.int64) << 32) | lo_ts
-    lo_a4 = ev32[..., LANE_A0 + 4].astype(jnp.uint32).astype(jnp.int64)
-    a4 = (ev32[..., LANE32_A4_HI].astype(jnp.int64) << 32) | lo_a4
-    return base.at[..., LANE_TIMESTAMP].set(ts).at[..., LANE_A0 + 4].set(a4)
-
-
-@partial(jax.jit, static_argnames=("layout",))
-def replay_events32(events32: jnp.ndarray,
-                    layout: PayloadLayout = DEFAULT_LAYOUT) -> ReplayState:
-    """Replay wire32-packed events [W, E, L32] int32: the device-resident
-    tensor stays int32 (44% of the int64 bytes in HBM and over the host
-    link); each scan step widens its [W, L32] slice on the fly."""
-    s0 = init_state(events32.shape[0], layout)
-
-    def body(s, ev32):
-        return step(s, widen_wire32(ev32)), None
-
-    s, _ = jax.lax.scan(body, s0, jnp.swapaxes(events32, 0, 1))
-    return s
-
-
-@partial(jax.jit, static_argnames=("layout",))
-def replay_to_crc32(events32: jnp.ndarray,
-                    layout: PayloadLayout = DEFAULT_LAYOUT
-                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """wire32 replay reduced to (crc32 [W] uint32, error [W]): the
-    minimal-transfer configuration — int32 lanes up, 4 bytes/workflow
-    down (few bytes back over the host link)."""
-    from .crc import crc32_rows
-
-    s = replay_events32(events32, layout)
-    return crc32_rows(payload_rows(s, layout)), s.error
-
-
 @partial(jax.jit, static_argnames=("profile", "layout"))
 def replay_wirec(slab: jnp.ndarray, bases: jnp.ndarray,
                  n_events: jnp.ndarray, profile,

@@ -1,9 +1,10 @@
 """wirec: the compressed host→device wire format (columnar, adaptive width).
 
 The host link is the product bottleneck: every byte the device replays
-has to cross it first, and wire32 spends 80 B/event on lanes whose
-information content is a handful of bits: event ids advance by 1, timestamps by a fixed tick,
-half the lanes are constant per corpus. wirec exploits that shape the way
+has to cross it first, and the dense int64 lanes spend 144 B/event on
+values whose information content is a handful of bits: event ids advance
+by 1, timestamps by a fixed tick, half the lanes are constant per corpus.
+wirec ships 10-20 B/event by exploiting that shape the way
 the reference's serializers exploit thrift compactness
 (common/persistence/serialization/, parquet-style columnar encoding) —
 but decodes ON DEVICE with pure vectorized XLA ops, so the dense form
@@ -32,7 +33,7 @@ Format. A corpus [W, E, NUM_LANES] int64 becomes:
 
 Decoding is exact: every transform is integer-reversible, so the decoded
 tensor is bit-identical to the int64 lane tensor (tests assert equality
-and CRC parity with the wire32 path). Widths are chosen from the actual
+and CRC parity with the dense replay). Widths are chosen from the actual
 data, so pathological corpora degrade gracefully toward raw width-8
 columns instead of failing.
 

@@ -127,36 +127,6 @@ def replay_sharded(events: jnp.ndarray, mesh: Mesh,
     return _replay_with_stats(events, layout)
 
 
-@partial(jax.jit, static_argnames=("layout",))
-def _replay_crc_with_stats(ev32: jnp.ndarray, layout: PayloadLayout):
-    from ..ops.crc import crc32_rows
-    from ..ops.replay import replay_events32
-
-    s = replay_events32(ev32, layout)
-    rows = payload_rows(s, layout)
-    stats = jnp.stack([
-        (s.error != 0).sum().astype(jnp.int64),
-        (s.close_status != 0).sum().astype(jnp.int64),
-    ])
-    return crc32_rows(rows), s.error, stats
-
-
-def shard_events32(events32: jnp.ndarray, mesh: Mesh) -> jnp.ndarray:
-    """Place wire32 [W, E, L32] int32 events sharded over 'shard'."""
-    return jax.device_put(events32,
-                          NamedSharding(mesh, P(SHARD_AXIS, None, None)))
-
-
-def replay_sharded_crc(events32: jnp.ndarray, mesh: Mesh,
-                       layout: PayloadLayout = DEFAULT_LAYOUT
-                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """SPMD wire32 replay reduced on device to (crc32 [W], errors [W],
-    global stats [2]) — the production bulk-replay configuration: int32
-    lanes in, 4 bytes/workflow out, checksum computed on chip."""
-    events32 = shard_events32(events32, mesh)
-    return _replay_crc_with_stats(events32, layout)
-
-
 @partial(jax.jit, static_argnames=("profile", "layout"))
 def _replay_wirec_crc_with_stats(slab, bases, n_events, profile,
                                  layout: PayloadLayout):

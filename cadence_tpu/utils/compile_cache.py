@@ -69,8 +69,8 @@ class KernelVariantCache:
     zero compile work) or builds it once (a MISS — exactly one XLA
     compile, itself served from the persistent disk cache above on warm
     processes). Hit/miss counters land on `tpu.fallback/*` so a warm
-    re-run can PROVE it paid zero ladder recompiles — the acceptance bar
-    bench.py reports against.
+    re-run can PROVE it paid zero ladder recompiles (tests/test_ladder.py,
+    tests/test_feeder_ladder.py).
 
     Shape keys should be pow2-bucketed by the caller: flagged-row counts
     wobble run to run, and bucketing keeps them landing on the same

@@ -23,7 +23,7 @@ ops/replay.replay_corpus) wraps its phases in a ReplayProfiler:
 
 Legs land as histograms under the component's scope (SCOPE_TPU_REPLAY by
 default, SCOPE_REBUILD for the rebuilder), so `/metrics` scrapes, the
-admin snapshot, and bench.py can all diff the legs across rounds.
+admin snapshot and the benchmark's traced runs can all read the legs.
 
 A leg is a span of the program's one recorder (utils/tracing.py), which
 keeps the time: the span's close observes the leg's histogram, and with a

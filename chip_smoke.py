@@ -8,7 +8,8 @@
 Phases, each through the entry points a user would call and each checked
 by the repo's own means (the Python oracle, the tiers' own counters):
 
-  bulk        all five corpus suites at bench.py's suite width (16,384
+  bulk        all five corpus suites at the suite width of the
+              benchmark's replay cells (16,384
               distinct workflows x ~120 events, seeded), packed by the
               native wirec encoder and replayed through the serving
               executor (engine/executor.stream_wirec_mesh, and the dense
@@ -60,7 +61,7 @@ import time
 DEADLINE_S = 1150.0
 
 SIZES = {
-    # the sizes the repo's bench defaults and ROADMAP call real
+    # the sizes the benchmark's replay cells and ROADMAP call real
     "full": dict(
         suite_w=16384, target_events=120, oracle_sample=256, chunks=4,
         gen_slice=1024, fused_w=16384, fused_events=1000, fused_sample=64,
@@ -457,7 +458,7 @@ def _replay_suite(checks: Checks, suite: str, events, oracle, mesh, layout,
 
 def _fused_chunk(checks: Checks, mesh, layout, size, seed: int):
     """One chunk of the fused generator+replay+CRC kernel on `mesh`,
-    with the oracle spot parity bench.py's north star does."""
+    checked against the oracle on a seeded sample of its rows."""
     import numpy as np
 
     from cadence_tpu.core.checksum import (

@@ -8,8 +8,8 @@
 # seed, zero tpu.serving/tpu.migration/replication parity divergence
 # summed across every live host, and a clean closing verify_all. The
 # run records the next CHAOS_r0N.json trajectory (kill/partition/flap
-# counts, checksum identity, fsck findings) next to the BENCH/FUZZ
-# files. A validation arm (--shrink) proves ddmin reduces an injected
+# counts, checksum identity, fsck findings) at the repo's root
+# (git-ignored). A validation arm (--shrink) proves ddmin reduces an injected
 # kill-then-signal regression to its 1-minimal 2-op campaign.
 #
 # Usage: deploy/smoke_fleetchaos.sh [extra `fuzz cluster` args]

@@ -9,7 +9,7 @@
 # crashpoint kills — must hold tpu.serving/parity-divergence == 0 with
 # final checksums byte-identical to a fault-free run and a clean
 # recovery fsck at every kill. The run records the next FUZZ_r0N.json
-# trajectory next to the BENCH/LOADGEN files.
+# trajectory at the repo's root (git-ignored).
 #
 # Usage: deploy/smoke_fuzz.sh [extra `fuzz run` args]
 # CPU gate: it checks parity, counts and SLOs on XLA's CPU backend and no

@@ -19,7 +19,7 @@ from cadence_tpu.engine.executor import BulkReplayExecutor, pipeline_depth
 from cadence_tpu.engine.persistence import Stores
 from cadence_tpu.engine.tpu_engine import TPUReplayEngine
 from cadence_tpu.gen.corpus import generate_corpus
-from cadence_tpu.ops.encode import assemble_corpus, encode_corpus, to_wire32
+from cadence_tpu.ops.encode import assemble_corpus, encode_corpus
 from cadence_tpu.utils import metrics as m
 
 # ---------------------------------------------------------------------------
@@ -99,11 +99,12 @@ class TestPackCacheParity:
 
     def test_suffix_pack_byte_identical_both_wire_formats(self):
         """A cache hit after appending a batch must produce byte-identical
-        packed lanes and identical crc_xor to a cold pack — int64/wire32
+        packed lanes and identical crc_xor to a cold pack — int64 lanes
         AND wirec."""
         import jax.numpy as jnp
 
-        from cadence_tpu.ops.replay import replay_to_crc32, replay_wirec_to_crc
+        from cadence_tpu.core.checksum import crc32_of_rows
+        from cadence_tpu.ops.replay import replay_to_payload, replay_wirec_to_crc
         from cadence_tpu.ops.wirec import pack_wirec
 
         hists = self._corpus()
@@ -122,16 +123,15 @@ class TestPackCacheParity:
         warm = assemble_corpus(warm_rows, cold.shape[1])
         assert warm.shape == cold.shape and (warm == cold).all()
 
-        # wire32: identical int32 lanes, identical device CRCs
-        w32_cold, w32_warm = to_wire32(cold), to_wire32(warm)
-        assert (w32_cold == w32_warm).all()
-        crc_cold, err_cold = replay_to_crc32(jnp.asarray(w32_cold))
-        crc_warm, err_warm = replay_to_crc32(jnp.asarray(w32_warm))
-        crc_cold, crc_warm = np.asarray(crc_cold), np.asarray(crc_warm)
+        # dense reference: the warm lanes replay to the cold lanes' CRCs
+        rows_cold, err_cold = replay_to_payload(jnp.asarray(cold))
+        rows_warm, _err_warm = replay_to_payload(jnp.asarray(warm))
+        crc_cold = crc32_of_rows(np.asarray(rows_cold))
+        crc_warm = crc32_of_rows(np.asarray(rows_warm))
         assert (np.asarray(err_cold) == 0).all()
         assert (crc_cold == crc_warm).all()
-        assert (int(np.bitwise_xor.reduce(crc_cold.astype(np.uint32)))
-                == int(np.bitwise_xor.reduce(crc_warm.astype(np.uint32))))
+        assert (int(np.bitwise_xor.reduce(crc_cold))
+                == int(np.bitwise_xor.reduce(crc_warm)))
 
         # wirec: identical slab/bases/counts, identical device CRCs
         wc_cold = pack_wirec(cold)
@@ -146,6 +146,7 @@ class TestPackCacheParity:
             jnp.asarray(wc_warm.slab), jnp.asarray(wc_warm.bases),
             jnp.asarray(wc_warm.n_events), wc_warm.profile)
         assert (np.asarray(crc_c) == np.asarray(crc_w)).all()
+        assert (np.asarray(crc_c) == crc_cold).all()
 
     def test_exact_hit_returns_cached_rows(self):
         hists = self._corpus()
@@ -335,39 +336,25 @@ class TestVerifyAllExecutor:
 
 
 class TestFeederDepth:
-    @pytest.mark.parametrize("depth", [3, 4])
-    def test_deep_ring_matches_direct_replay(self, depth):
-        from cadence_tpu.native import packing
-        from cadence_tpu.native.feeder import feed_corpus
-        from cadence_tpu.ops.replay import replay_corpus
-
-        if not packing.native_available():
-            pytest.skip("native packer unavailable")
-        hists = generate_corpus("basic", num_workflows=26, seed=7,
-                                target_events=30)
-        rows_direct, _, errors_direct = replay_corpus(hists)
-        # 26 workflows / chunk 4 = 7 chunks: several full ring wraps
-        rows, errors, report = feed_corpus(hists, chunk_workflows=4,
-                                           depth=depth)
-        assert report.depth == depth and report.chunks == 7
-        assert (errors == errors_direct).all()
-        assert (rows == rows_direct).all()
-        assert report.pack_queue_wait_s >= 0
-
-    @pytest.mark.parametrize("depth", [4])
-    def test_deep_ring_wirec(self, depth):
-        from cadence_tpu.core.checksum import crc32_of_rows
+    @pytest.mark.parametrize("depth,suite,workflows,seed", [
+        (3, "basic", 26, 7), (4, "basic", 26, 7),
+        (4, "echo_signal", 18, 11)])
+    def test_deep_ring_matches_direct_replay(self, depth, suite, workflows,
+                                             seed):
         from cadence_tpu.native import packing
         from cadence_tpu.native.feeder import feed_corpus_wirec
         from cadence_tpu.ops.replay import replay_corpus
 
         if not packing.native_available():
             pytest.skip("native packer unavailable")
-        hists = generate_corpus("echo_signal", num_workflows=18, seed=11,
-                                target_events=24)
-        rows_direct, crcs_direct, _ = replay_corpus(hists)
+        hists = generate_corpus(suite, num_workflows=workflows, seed=seed,
+                                target_events=30)
+        _, crcs_direct, errors_direct = replay_corpus(hists)
+        # chunks of 4: several full wraps of the ring
         crcs, errors, report = feed_corpus_wirec(hists, chunk_workflows=4,
                                                  depth=depth)
-        assert (errors == 0).all()
-        assert (crcs == crcs_direct).all()
         assert report.depth == depth
+        assert report.chunks == -(-workflows // 4)
+        assert (errors == errors_direct).all()
+        assert (crcs == crcs_direct).all()
+        assert report.pack_queue_wait_s >= 0

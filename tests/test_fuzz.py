@@ -18,7 +18,7 @@ The contract under test (gen/fuzz.py, gen/shrink.py, gen/interleave.py):
   tpu.serving/parity-divergence == 0 and a clean recovery fsck at every
   kill.
 - PROMOTION: `fuzz promote` specs regenerate byte-identically (drift
-  guarded by digest) and feed bench.py as permanent suites.
+  guarded by digest) as permanent corpora.
 """
 import numpy as np
 import pytest
@@ -240,8 +240,7 @@ class TestPromotion:
             bad.generate()
 
     def test_promoted_spec_parity(self, tmp_path):
-        """A promoted corpus replays parity-clean — the gate bench.py's
-        fuzz suite re-asserts on every run."""
+        """A promoted corpus replays parity-clean."""
         spec = fuzz.make_spec("bench-feed", seed=3, workflows=6,
                               target_events=60, profile="chain")
         histories = spec.generate()

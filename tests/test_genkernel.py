@@ -96,9 +96,20 @@ class TestGenerator:
         with pytest.raises(ValueError):
             generate_and_replay_sharded(11, 0, 65, E, mesh)
 
+    def test_fused_generator_crc_matches_rows(self):
+        """The fused generate+replay kernel reduced to CRCs on device
+        checksums exactly the rows its unreduced twin returns."""
+        from cadence_tpu.core.checksum import crc32_of_rows
+        from cadence_tpu.ops.genkernel import generate_and_replay_crc
+
+        rows, errors = generate_and_replay(11, 0, 64, E)
+        crc, errors2 = generate_and_replay_crc(11, 0, 64, E)
+        assert (np.asarray(crc) == crc32_of_rows(np.asarray(rows))).all()
+        assert (np.asarray(errors2) == np.asarray(errors)).all()
+
     @pytest.mark.parametrize("n", [1, 8])
     def test_sharded_crc_equals_single_device(self, n):
-        """The north-star form (bench.py, chip_smoke.py): the shard_map
+        """The north-star form (chip_smoke.py): the shard_map
         kernel reduced to CRCs on device. Inside shard_map the rows vary
         across the mesh axis and the CRC's matrix is replicated; their
         product is typed varying like the rows — on a mesh of 1 too."""

@@ -114,6 +114,44 @@ class TestServingPathParity:
         rows, _ = replay_to_payload(jnp.asarray(ev))
         assert (np.asarray(crc) == crc32_of_rows(np.asarray(rows))).all()
 
+    @pytest.mark.parametrize("n_chunks,depth", [(4, 3), (2, 2)])
+    def test_pipelined_wirec_crc_equals_oneshot_full_mesh(self, n_chunks,
+                                                          depth):
+        """Chunked executor streaming over the whole mesh == a single
+        sharded launch, CRC for CRC: overlapping the per-device H2D
+        slices with the previous chunk's replay changes no result."""
+        from cadence_tpu.ops.wirec import pack_wirec
+        from cadence_tpu.parallel.mesh import replay_wirec_sharded_crc
+
+        mesh = make_mesh()
+        corpus = pack_wirec(_events(n=64, seed=29))
+        assert (64 // n_chunks) % int(mesh.devices.size) == 0
+        crcs_p, errs_p, report = stream_wirec_mesh(
+            corpus, mesh, n_chunks=n_chunks, depth=depth)
+        assert report.chunks == n_chunks
+        crc_1, err_1, _ = replay_wirec_sharded_crc(corpus, mesh)
+        assert (crcs_p == np.asarray(crc_1).astype(np.uint32)).all()
+        assert (errs_p == np.asarray(err_1)).all()
+
+    def test_warm_pass_zero_recompiles_across_seen_mesh_shapes(self):
+        """Mesh shapes already seen recompile nothing on a warm pass:
+        every chunk-kernel variant is a hit of the executor's cache."""
+        ev = _events(n=48, seed=31)
+        devices = jax.devices()
+        meshes = [make_mesh(devices[:1]), make_mesh(devices[:2])]
+        for mesh in meshes:  # first pass: compiles allowed
+            replay_corpus_mesh(ev, mesh, chunk_workflows=16)
+        reg = m.DEFAULT_REGISTRY
+        misses0 = reg.counter(m.SCOPE_TPU_EXECUTOR, m.M_LADDER_CACHE_MISSES)
+        hits0 = reg.counter(m.SCOPE_TPU_EXECUTOR, m.M_LADDER_CACHE_HITS)
+        for mesh in meshes:  # warm pass: every variant must hit
+            replay_corpus_mesh(ev, mesh, chunk_workflows=16)
+        assert reg.counter(m.SCOPE_TPU_EXECUTOR,
+                           m.M_LADDER_CACHE_MISSES) == misses0, \
+            "a warm serving pass recompiled a mesh shape already seen"
+        assert reg.counter(m.SCOPE_TPU_EXECUTOR,
+                           m.M_LADDER_CACHE_HITS) >= hits0 + len(meshes)
+
     @pytest.mark.parametrize("suite", ["basic", "timer_retry", "ndc"])
     @pytest.mark.parametrize("n_dev", [2, 4])
     def test_mesh_n_checksum_identity(self, suite, n_dev):
@@ -245,6 +283,20 @@ class TestEngineMeshVerify:
         assert cache.n_shards == 2 and len(cache) == 0
 
 
+def _feeder_case():
+    """(histories, dense CRCs, dense errors) for the feeder-over-a-mesh
+    cases; skips where the native packer cannot be built."""
+    from cadence_tpu.native import packing
+    from cadence_tpu.ops.replay import replay_corpus
+
+    if not packing.native_available():
+        pytest.skip("native packer unavailable")
+    hists = generate_corpus("basic", num_workflows=18, seed=7,
+                            target_events=24)
+    _, crcs_direct, errors_direct = replay_corpus(hists)
+    return hists, crcs_direct, errors_direct
+
+
 class TestMeshConsumers:
     def test_rebuilder_mesh_parity(self):
         from cadence_tpu.core.checksum import STICKY_ROW_INDEX, payload_row
@@ -264,22 +316,37 @@ class TestMeshConsumers:
             expected[STICKY_ROW_INDEX] = 0
             assert (got == expected).all()
 
-    def test_feeder_mesh_parity(self):
-        from cadence_tpu.native import packing
-        from cadence_tpu.native.feeder import feed_corpus
-        from cadence_tpu.ops.replay import replay_corpus
+    @pytest.mark.parametrize("n_dev,chunk_workflows,chunks", [
+        (2, 6, 3), (4, 6, 3), (8, 8, 3)])
+    def test_feeder_mesh_parity(self, n_dev, chunk_workflows, chunks):
+        """The feeder over a mesh against the dense one-shot replay; a
+        chunk width that is no multiple of the mesh is rounded up to a
+        whole slice per device (6 → 8 on four devices)."""
+        from cadence_tpu.native.feeder import feed_corpus_wirec
 
-        if not packing.native_available():
-            pytest.skip("native packer unavailable")
-        hists = generate_corpus("basic", num_workflows=18, seed=7,
-                                target_events=24)
-        rows_direct, _, errors_direct = replay_corpus(hists)
-        rows, errors, report = feed_corpus(
-            hists, chunk_workflows=6, depth=3,
-            mesh=make_mesh(jax.devices()[:2]))
-        assert report.chunks == 3
+        hists, crcs_direct, errors_direct = _feeder_case()
+        crcs, errors, report = feed_corpus_wirec(
+            hists, chunk_workflows=chunk_workflows, depth=3,
+            mesh=make_mesh(jax.devices()[:n_dev]))
+        assert report.chunks == chunks
         assert (errors == errors_direct).all()
-        assert (rows == rows_direct).all()
+        assert (crcs == crcs_direct).all()
+
+    def test_feeder_resolves_mesh_from_env_knob(self, monkeypatch):
+        """With no mesh handed in, CADENCE_TPU_MESH_DEVICES decides:
+        the feeder shards every chunk over that many devices."""
+        from cadence_tpu.native.feeder import feed_corpus_wirec
+
+        hists, crcs_direct, errors_direct = _feeder_case()
+        monkeypatch.setenv("CADENCE_TPU_MESH_DEVICES", "2")
+        reg = m.DEFAULT_REGISTRY
+        name = m.device_metric(m.M_EXEC_CHUNKS, 1)
+        before = reg.counter(m.SCOPE_TPU_EXECUTOR, name)
+        crcs, errors, report = feed_corpus_wirec(hists, chunk_workflows=5)
+        assert report.chunks == 3  # 5 rounds up to 6: a whole slice each
+        assert (errors == errors_direct).all()
+        assert (crcs == crcs_direct).all()
+        assert reg.counter(m.SCOPE_TPU_EXECUTOR, name) == before + 3
 
     def test_serving_mesh_env_knob(self, monkeypatch):
         monkeypatch.delenv("CADENCE_TPU_MESH_DEVICES", raising=False)

@@ -10,7 +10,8 @@ from cadence_tpu.core.codec import deserialize_history, serialize_history
 from cadence_tpu.gen.corpus import SUITES, generate_corpus, generate_history
 from cadence_tpu.ops.encode import encode_corpus
 from cadence_tpu.native import build as native_build
-from cadence_tpu.native.packing import encode_corpus_native, pack_serialized
+from cadence_tpu.core.codec import serialize_corpus
+from cadence_tpu.native.packing import pack_serialized
 
 native = pytest.mark.skipif(native_build.load() is None,
                             reason="no C++ toolchain")
@@ -24,7 +25,7 @@ def test_native_matches_python_packer(suite):
     histories = generate_corpus(suite, num_workflows=6, seed=31,
                                 target_events=90)
     expected = encode_corpus(histories)
-    got = encode_corpus_native(histories, max_events=expected.shape[1])
+    got = pack_serialized(serialize_corpus(histories), expected.shape[1])
     mism = np.nonzero(got != expected)
     assert got.shape == expected.shape
     assert (got == expected).all(), (
@@ -35,7 +36,6 @@ def test_native_matches_python_packer(suite):
 @native
 def test_native_rejects_truncated_blob():
     histories = generate_corpus("basic", 2, seed=1, target_events=40)
-    from cadence_tpu.core.codec import serialize_corpus
     blobs = serialize_corpus(histories)
     blobs[1] = blobs[1][:len(blobs[1]) // 2]
     with pytest.raises(ValueError, match="workflow 1"):
@@ -45,7 +45,6 @@ def test_native_rejects_truncated_blob():
 @native
 def test_native_rejects_overlong_history():
     histories = generate_corpus("basic", 1, seed=1, target_events=60)
-    from cadence_tpu.core.codec import serialize_corpus
     with pytest.raises(ValueError, match="code 3"):
         pack_serialized(serialize_corpus(histories), max_events=8)
 
@@ -100,93 +99,51 @@ def test_codec_roundtrip_parent_and_retry():
             rp.expiration_interval_seconds) == (2, 1.5, 30, 4, 120)
 
 
-class TestNativePacker32:
-    def test_wire32_matches_python_to_wire32(self):
-        """C++ int32 emission must equal encode.to_wire32(python int64)."""
-        import numpy as np
+@native
+def test_fully_loaded_start_event_packs():
+    """A child-workflow Started event with retry policy + cron + parent
+    linkage carries 20 wire attrs — the packer must accept it (the
+    attr-list bound is kMaxAttrCode, not a smaller guess)."""
+    from cadence_tpu.core.enums import ContinueAsNewInitiator, EventType
+    from cadence_tpu.core.events import HistoryBatch, HistoryEvent, RetryPolicy
 
-        from cadence_tpu.core.codec import serialize_corpus
-        from cadence_tpu.gen.corpus import SUITES, generate_corpus
-        from cadence_tpu.native.packing import pack_serialized32
-        from cadence_tpu.ops.encode import encode_corpus, to_wire32
+    start = HistoryEvent(
+        id=1, event_type=EventType.WorkflowExecutionStarted,
+        version=0, timestamp=1_700_000_000_000_000_000, task_id=1001,
+        attrs=dict(
+            execution_start_to_close_timeout_seconds=3600,
+            task_start_to_close_timeout_seconds=10,
+            first_decision_task_backoff_seconds=5,
+            attempt=2,
+            expiration_timestamp=1_700_000_900_000_000_000,
+            task_list="tl", workflow_type="wt", cron_schedule="* * * * *",
+            first_execution_run_id="r0",
+            parent_workflow_id="pw", parent_run_id="pr",
+            parent_domain_id="pd", parent_initiated_event_id=7,
+            retry_policy=RetryPolicy(
+                initial_interval_seconds=1, backoff_coefficient=2.0,
+                maximum_interval_seconds=60, maximum_attempts=5,
+                expiration_interval_seconds=900),
+            initiator=int(ContinueAsNewInitiator.RetryPolicy),
+        ))
+    sched = HistoryEvent(
+        id=2, event_type=EventType.DecisionTaskScheduled, version=0,
+        timestamp=1_700_000_000_000_001_000, task_id=1002,
+        attrs=dict(task_list="tl", start_to_close_timeout_seconds=10,
+                   attempt=0))
+    hist = [[HistoryBatch(domain_id="d", workflow_id="w", run_id="r",
+                          events=[start, sched])]]
+    ev = encode_corpus(hist)
+    blobs = serialize_corpus(hist)
+    got = pack_serialized(blobs, ev.shape[1])
+    assert (got == ev).all()
+    # and through the fused blobs → lanes → wirec call the feeder packs with
+    from cadence_tpu.native.wirec import pack_serialized_wirec
+    from cadence_tpu.ops.wirec import decode_wirec
 
-        for suite in SUITES:
-            if suite == "ndc":
-                continue  # branch lanes ride the python packer only
-            hists = generate_corpus(suite, num_workflows=12, seed=21,
-                                    target_events=70)
-            hists = [h for h in hists
-                     if not any(b.new_run_events for b in h)]
-            ev = encode_corpus(hists)
-            want = to_wire32(ev)
-            got = pack_serialized32(serialize_corpus(hists), ev.shape[1])
-            assert (got == want).all(), f"suite {suite} wire32 mismatch"
-
-    def test_wire32_replays_to_same_crc(self):
-        import jax.numpy as jnp
-        import numpy as np
-
-        from cadence_tpu.core.checksum import DEFAULT_LAYOUT, crc32_of_rows
-        from cadence_tpu.core.codec import serialize_corpus
-        from cadence_tpu.gen.corpus import generate_corpus
-        from cadence_tpu.native.packing import pack_serialized32
-        from cadence_tpu.ops.encode import encode_corpus
-        from cadence_tpu.ops.replay import replay_to_crc32, replay_to_payload
-
-        hists = generate_corpus("echo_signal", num_workflows=8, seed=4,
-                                target_events=50)
-        ev = encode_corpus(hists)
-        rows, _ = replay_to_payload(jnp.asarray(ev), DEFAULT_LAYOUT)
-        want = crc32_of_rows(np.asarray(rows))
-        wire = pack_serialized32(serialize_corpus(hists), ev.shape[1])
-        crc, errors = replay_to_crc32(jnp.asarray(wire), DEFAULT_LAYOUT)
-        assert (np.asarray(crc) == want).all()
-        assert (np.asarray(errors) == 0).all()
-
-    def test_fully_loaded_start_event_packs(self):
-        """A child-workflow Started event with retry policy + cron + parent
-        linkage carries 20 wire attrs — the packer must accept it (the
-        attr-list bound is kMaxAttrCode, not a smaller guess)."""
-        import numpy as np
-
-        from cadence_tpu.core.codec import serialize_corpus
-        from cadence_tpu.core.enums import ContinueAsNewInitiator, EventType
-        from cadence_tpu.core.events import HistoryBatch, HistoryEvent, RetryPolicy
-        from cadence_tpu.native.packing import pack_serialized, pack_serialized32
-        from cadence_tpu.ops.encode import encode_corpus, to_wire32
-
-        start = HistoryEvent(
-            id=1, event_type=EventType.WorkflowExecutionStarted,
-            version=0, timestamp=1_700_000_000_000_000_000, task_id=1001,
-            attrs=dict(
-                execution_start_to_close_timeout_seconds=3600,
-                task_start_to_close_timeout_seconds=10,
-                first_decision_task_backoff_seconds=5,
-                attempt=2,
-                expiration_timestamp=1_700_000_900_000_000_000,
-                task_list="tl", workflow_type="wt", cron_schedule="* * * * *",
-                first_execution_run_id="r0",
-                parent_workflow_id="pw", parent_run_id="pr",
-                parent_domain_id="pd", parent_initiated_event_id=7,
-                retry_policy=RetryPolicy(
-                    initial_interval_seconds=1, backoff_coefficient=2.0,
-                    maximum_interval_seconds=60, maximum_attempts=5,
-                    expiration_interval_seconds=900),
-                initiator=int(ContinueAsNewInitiator.RetryPolicy),
-            ))
-        sched = HistoryEvent(
-            id=2, event_type=EventType.DecisionTaskScheduled, version=0,
-            timestamp=1_700_000_000_000_001_000, task_id=1002,
-            attrs=dict(task_list="tl", start_to_close_timeout_seconds=10,
-                       attempt=0))
-        hist = [[HistoryBatch(domain_id="d", workflow_id="w", run_id="r",
-                              events=[start, sched])]]
-        ev = encode_corpus(hist)
-        blobs = serialize_corpus(hist)
-        got = pack_serialized(blobs, ev.shape[1])
-        assert (got == ev).all()
-        got32 = pack_serialized32(blobs, ev.shape[1])
-        assert (got32 == to_wire32(ev)).all()
+    c, _ = pack_serialized_wirec(blobs, ev.shape[1])
+    assert (np.asarray(decode_wirec(c.slab, c.bases, c.n_events,
+                                    c.profile)) == ev).all()
 
 
 def _assert_corpus_equal(a, b, ctx=""):

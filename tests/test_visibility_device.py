@@ -448,6 +448,35 @@ class TestRoutingAndOps:
                            m.M_VIS_DEVICE_SERVED) == served
         view.stop()
 
+    def test_warm_queries_recompile_nothing(self, vis_env):
+        """Warm repeats of a seen query shape compile no new kernel
+        variant, and every answer still passes the host parity check."""
+        store = _seed_store(random.Random(77), 400, (("P", "num"),))
+        queries = ["", "CloseStatus = -1", "WorkflowType = 'type-2'",
+                   "P >= 5 AND CloseStatus = 0",
+                   "StartTime > 12000 OR P < 2"]
+        reg = m.DEFAULT_REGISTRY
+
+        def counters():
+            return [reg.counter(m.SCOPE_TPU_VISIBILITY, name)
+                    for name in (m.M_LADDER_CACHE_MISSES, m.M_VIS_DIVERGENCE,
+                                 m.M_VIS_PARITY_CHECKS)]
+
+        for q in queries:  # cold pass compiles each shape once
+            store.count(DOMAIN, q)
+            store.query(DOMAIN, q)
+        misses0, diverged0, checks0 = counters()
+        for _ in range(3):
+            for q in queries:
+                store.count(DOMAIN, q)
+                store.query(DOMAIN, q)
+        misses, diverged, checks = counters()
+        assert misses == misses0, \
+            "warm visibility queries recompiled kernel variants"
+        assert diverged == diverged0
+        assert checks >= checks0 + 4 * len(queries)
+        store._device.stop()
+
     def test_onebox_frontend_and_admin_rollup(self, vis_env):
         from cadence_tpu.engine.admin import AdminHandler
         from cadence_tpu.engine.onebox import Onebox

@@ -2,18 +2,19 @@
 streaming profile pin/refit.
 
 The host link is the product bottleneck (SURVEY §7 hard part 6); wirec
-ships ~10-18 B/event instead of wire32's 80 by GCD-scaled columnar
-delta/abs/const coding chosen per lane from the measured corpus, decoded
-exactly on device (ops/wirec.py). These tests pin the exactness contract:
-decode(pack(x)) == x bit-for-bit, and the replay CRCs match the wire32
-path on every suite.
+ships ~10-18 B/event instead of the dense int64 lanes' 144 by GCD-scaled
+columnar delta/abs/const coding chosen per lane from the measured corpus,
+decoded exactly on device (ops/wirec.py). These tests pin the exactness
+contract: decode(pack(x)) == x bit-for-bit, and the replay CRCs match the
+dense int64 reference (`replay_to_payload` + `crc32_of_rows`) on every
+suite.
 """
 import numpy as np
 import pytest
 
-from cadence_tpu.core.checksum import DEFAULT_LAYOUT
+from cadence_tpu.core.checksum import DEFAULT_LAYOUT, crc32_of_rows
 from cadence_tpu.gen.corpus import SUITES, generate_corpus
-from cadence_tpu.ops.encode import NUM_LANES, encode_corpus, to_wire32
+from cadence_tpu.ops.encode import NUM_LANES, encode_corpus
 from cadence_tpu.ops.wirec import (
     KIND_CONST,
     KIND_DELTA,
@@ -28,6 +29,16 @@ def _corpus(suite, n=16, seed=9, target_events=80):
                                          target_events=target_events))
 
 
+def _dense_reference(ev):
+    """(crc32 [W], error [W]) of the dense int64 replay of `ev`."""
+    import jax.numpy as jnp
+
+    from cadence_tpu.ops.replay import replay_to_payload
+
+    rows, errors = replay_to_payload(jnp.asarray(ev), DEFAULT_LAYOUT)
+    return crc32_of_rows(np.asarray(rows)), np.asarray(errors)
+
+
 class TestWirecRoundTrip:
     @pytest.mark.parametrize("suite", SUITES)
     def test_decode_is_exact(self, suite):
@@ -39,12 +50,14 @@ class TestWirecRoundTrip:
         assert (back == ev).all()
 
     @pytest.mark.parametrize("suite", SUITES)
-    def test_density_beats_wire32(self, suite):
-        """The whole point: ≤20 B/event vs wire32's 80 (VERDICT r4 #2)."""
+    def test_density_beats_dense_lanes(self, suite):
+        """The whole point: ≤20 B/event (what the replay cells read as
+        `h2d.bytes_per_event`) against the dense lanes' 144."""
         ev = _corpus(suite, n=64)
         c = pack_wirec(ev)
+        assert ev.itemsize * NUM_LANES == 144
         assert c.bytes_per_event() <= 20.0
-        assert c.wire_bytes < to_wire32(ev).nbytes / 3
+        assert c.wire_bytes < ev.nbytes / 5
 
     def test_adversarial_values_still_exact(self):
         """Pathological lanes (wide random values, negatives, 64-bit
@@ -77,40 +90,37 @@ class TestWirecRoundTrip:
 
 class TestWirecReplayParity:
     @pytest.mark.parametrize("suite", SUITES)
-    def test_crc_matches_wire32_path(self, suite):
+    def test_crc_matches_dense_reference(self, suite):
         import jax.numpy as jnp
 
-        from cadence_tpu.ops.replay import replay_to_crc32, replay_wirec_to_crc
+        from cadence_tpu.ops.replay import replay_wirec_to_crc
 
         ev = _corpus(suite)
-        crc32_, err32 = replay_to_crc32(jnp.asarray(to_wire32(ev)),
-                                        DEFAULT_LAYOUT)
+        crc_ref, err_ref = _dense_reference(ev)
         c = pack_wirec(ev)
         crcw, errw = replay_wirec_to_crc(jnp.asarray(c.slab),
                                          jnp.asarray(c.bases),
                                          jnp.asarray(c.n_events),
                                          c.profile, DEFAULT_LAYOUT)
-        assert (np.asarray(crcw) == np.asarray(crc32_)).all()
-        assert (np.asarray(errw) == np.asarray(err32)).all()
+        assert (np.asarray(crcw) == crc_ref).all()
+        assert (np.asarray(errw) == err_ref).all()
 
     def test_sharded_crc_matches(self):
         """SPMD wirec replay over the 8-device CPU mesh: compressed in,
         identical CRCs out."""
         from cadence_tpu.parallel.mesh import (
             make_mesh,
-            replay_sharded_crc,
             replay_wirec_sharded_crc,
-            shard_events32,
         )
 
         ev = _corpus("ndc", n=32)
-        mesh = make_mesh()
-        crc32_, _, _ = replay_sharded_crc(
-            shard_events32(np.ascontiguousarray(to_wire32(ev)), mesh),
-            mesh, DEFAULT_LAYOUT)
+        crc_ref, err_ref = _dense_reference(ev)
         c = pack_wirec(ev)
-        crcw, _, _ = replay_wirec_sharded_crc(c, mesh, DEFAULT_LAYOUT)
-        assert (np.asarray(crcw) == np.asarray(crc32_)).all()
+        crcw, errw, stats = replay_wirec_sharded_crc(c, make_mesh(),
+                                                     DEFAULT_LAYOUT)
+        assert (np.asarray(crcw) == crc_ref).all()
+        assert (np.asarray(errw) == err_ref).all()
+        assert int(stats[0]) == int((err_ref != 0).sum())
 
 
 class TestWirecStreaming:
@@ -120,6 +130,17 @@ class TestWirecStreaming:
         c2 = pack_wirec(ev, profile=c.profile)
         assert (c2.slab == c.slab).all()
         assert (c2.bases == c.bases).all()
+
+    def test_chunk_parallel_pack_byte_identical(self):
+        """The chunk-parallel packer (per-lane planning + per-row-block
+        emit fan-out) emits the serial packer's bytes."""
+        ev = _corpus("timer_retry", n=640, seed=23, target_events=24)
+        serial = pack_wirec(ev)
+        threaded = pack_wirec(ev, num_threads=4)
+        assert serial.profile == threaded.profile
+        assert (serial.slab == threaded.slab).all()
+        assert (serial.bases == threaded.bases).all()
+        assert (serial.n_events == threaded.n_events).all()
 
     def test_profile_misfit_raises_not_corrupts(self):
         """A chunk outside the pinned widths/scales must REFUSE, so the
@@ -131,20 +152,25 @@ class TestWirecStreaming:
         with pytest.raises(ProfileMisfit):
             pack_wirec(wild, profile=c.profile)
 
-    def test_feeder_wirec_matches_wire32(self):
+    @pytest.mark.parametrize("chunk_workflows", [16, 20, 48])
+    def test_feeder_wirec_matches_dense_reference(self, chunk_workflows):
         """End-to-end ingest parity: serialized blobs → C++ packer →
-        wirec → device decode+replay vs the wire32 pipeline."""
+        wirec → device decode+replay vs the dense one-shot replay, in
+        whole chunks, with a padded tail chunk, and as one chunk."""
         from cadence_tpu.native import packing
-        from cadence_tpu.native.feeder import feed_corpus32, feed_corpus_wirec
+        from cadence_tpu.native.feeder import feed_corpus_wirec
+        from cadence_tpu.ops.replay import replay_corpus
 
         if not packing.native_available():
             pytest.skip("native packer not built")
         histories = generate_corpus("echo_signal", num_workflows=48, seed=5,
                                     target_events=60)
-        crcw, errw, report = feed_corpus_wirec(histories, chunk_workflows=16)
-        crc3, err3, _ = feed_corpus32(histories, chunk_workflows=16)
-        assert (crcw == crc3).all()
-        assert (errw == err3).all()
+        crcw, errw, report = feed_corpus_wirec(
+            histories, chunk_workflows=chunk_workflows)
+        _rows, crc_ref, err_ref = replay_corpus(histories)
+        assert (crcw == crc_ref).all()
+        assert (errw == err_ref).all()
+        assert report.chunks == -(-48 // chunk_workflows)
         assert report.profile_refits == 0
         assert report.bytes_per_event <= 25  # tiny chunks amortize worse
 
