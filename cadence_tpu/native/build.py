@@ -147,6 +147,14 @@ def load_wirec() -> Optional[ctypes.CDLL]:
             I64P, I64P, I64P, I64P,           # kinds/widths/scales/consts
             ctypes.c_int64,                   # num_threads
         ]
+        lib.cadence_wirec_measure_blobs.restype = ctypes.c_int64
+        lib.cadence_wirec_measure_blobs.argtypes = [
+            ctypes.c_char_p,                  # blob
+            I64P,                             # offsets [W + 1]
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,  # W, E, L
+            I64P, I64P, I64P, I64P,           # kinds/widths/scales/consts
+            ctypes.c_int64,                   # num_threads
+        ]
         lib.cadence_wirec_emit.restype = ctypes.c_int64
         lib.cadence_wirec_emit.argtypes = [
             I64P,                             # lanes
@@ -164,7 +172,6 @@ def load_wirec() -> Optional[ctypes.CDLL]:
             ctypes.c_char_p,                  # blob
             I64P,                             # offsets [W + 1]
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,  # W, E, L
-            I64P,                             # lanes scratch [W, E, L]
             I64P, I64P, I64P, I64P, I64P, I64P, I64P,  # profile columns
             ctypes.c_int64,                   # P
             ctypes.c_int64, ctypes.c_int64,   # B, K

@@ -56,6 +56,12 @@ class FeedReport:
     #: handoff cost (the pinned-buffer H2D seconds)
     native_wirec: bool = False
     h2d_s: float = 0.0
+    #: whole decodes of a chunk's wire blobs that its pack was made of.
+    #: The native encoder keeps no lane tensor, so a chunk packed with no
+    #: profile (chunk 0, each refit) is decoded twice, measure then emit:
+    #: chunks + 1 + refits; the pinned attempt a refit abandons is not
+    #: counted. The Python encoder decodes once a chunk.
+    decode_passes: int = 0
     #: capacity-escalation ladder inside the call.
     #: Every rung counted: the rows the rungs replayed, their lanes (rows
     #: after the pow2 padding), the real events and the wire bytes of the
@@ -134,14 +140,16 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
     Two host encoders serve the pack stage, byte-identical by contract
     (tests/test_native_packer.py fuzzes the parity): the NATIVE pipeline
     (native/wirec.cc via CADENCE_TPU_NATIVE_WIREC, default on when the
-    .so is loadable) runs wire blobs → int64 lanes → wirec buffers in
-    ONE multi-threaded C++ call per chunk, staging straight into
-    preallocated ring-slot buffers (WirecBuffers — zero Python-side
-    allocation per chunk) that hand off to the device through
-    stage_corpus (dlpack where the backend accepts it); the pure-Python
-    fallback is the original pack_serialized + pack_wirec pair. Which
-    encoder served is a /metrics scrape (tpu.native/*) and rides the
-    report's native_wirec flag.
+    .so is loadable) turns wire blobs into wirec buffers a ROW at a
+    time — each workflow decoded into a one-row scratch and emitted from
+    there, no [W, E, L] lane tensor on the way — in ONE multi-threaded
+    C++ call per chunk, staging straight into preallocated ring-slot
+    buffers (WirecBuffers — zero Python-side allocation per chunk) that
+    hand off to the device through stage_corpus (dlpack where the
+    backend accepts it); the pure-Python fallback is the original
+    pack_serialized + pack_wirec pair over a dense tensor. Which encoder
+    served is a /metrics scrape (tpu.native/*) and rides the report's
+    native_wirec flag.
 
     The wirec profile is measured on the FIRST chunk and pinned so every
     chunk shares one executable; a later chunk whose values fall outside
@@ -149,7 +157,9 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
     refreshed plan becomes the pin for chunks packed after it) — counted
     in the report, never silent. Both encoders measure profiles with the
     identical decision procedure, so pin/refit behavior cannot depend on
-    which one served.
+    which one served. The native encoder measures by decoding the chunk
+    once more (span `pack.measure`): `decode_passes` and the
+    tpu.native/decode-passes counter say what that cost.
 
     Rows the base pass flags with a CAPACITY error (a workflow holding
     more pending items than the device's tables) are resolved inside the
@@ -190,10 +200,9 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
         wirec_threads = (num_threads if num_threads is not None
                          else max(1, pack_threads() // executor.depth))
         if use_native:
-            # reusable staging: lanes scratch + wirec output triple per
-            # ring slot, fully overwritten by every emit (no zeroing, no
-            # per-chunk allocation) — the pinned host buffers the H2D
-            # stages from
+            # reusable staging: the wirec output triple per ring slot,
+            # fully overwritten by every emit (no zeroing, no per-chunk
+            # allocation) — the pinned host buffers the H2D stages from
             buffers = [nwirec.WirecBuffers(chunk_workflows, max_events)
                        for _ in range(executor.depth)]
         else:
@@ -205,21 +214,22 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
     # (a refit replaces it under the lock)
     first_profile: Future = Future()
     state_lock = Lock()
-    shared = {"profile": None, "refits": 0,
+    shared = {"profile": None, "refits": 0, "decode_passes": 0,
               "pack_s": 0.0, "compress_s": 0.0,
               "events": 0, "wire_bytes": 0, "h2d_s": 0.0}
 
     def _encode_native(ci, chunk, slot):
-        """Fused native chunk: blobs → lanes → wirec in one ctypes call
-        (decode + compress are one pass, so pack_s carries the whole
-        host cost and compress_s stays 0)."""
+        """Streamed native chunk: blobs → wirec a row at a time (decode
+        + compress are one pass, so pack_s carries the whole host cost
+        and compress_s stays 0). Returns (corpus, compress seconds,
+        decode passes)."""
         if ci == 0:
             corpus, _ = nwirec.pack_serialized_wirec(
                 chunk, max_events, num_threads=wirec_threads, out=slot)
             with state_lock:
                 shared["profile"] = corpus.profile
             first_profile.set_result(corpus.profile)
-            return corpus, 0.0
+            return corpus, 0.0, 2
         with tracing.span("pack.first-profile-wait"):
             first_profile.result()
         with state_lock:
@@ -230,15 +240,15 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
                 num_threads=wirec_threads, out=slot)
         except ProfileMisfit:
             # refit: fresh plan, recompile; later chunks pin it. The
-            # fused call decodes blobs into the slot's lanes scratch
-            # BEFORE reporting the emit misfit, so re-measure + emit
-            # from those lanes instead of re-decoding the wire bytes
-            corpus = nwirec.pack_wirec_native(
-                slot.lanes, num_threads=wirec_threads, out=slot)
+            # streamed pass keeps no decoded lanes, so the chunk is
+            # packed again the way chunk 0 is: measure, then emit
+            corpus, _ = nwirec.pack_serialized_wirec(
+                chunk, max_events, num_threads=wirec_threads, out=slot)
             with state_lock:
                 shared["profile"] = corpus.profile
                 shared["refits"] += 1
-        return corpus, 0.0
+            return corpus, 0.0, 2
+        return corpus, 0.0, 1
 
     def _encode_python(ci, chunk, slot):
         packed = packing.pack_serialized(chunk, max_events,
@@ -264,7 +274,7 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
                 with state_lock:
                     shared["profile"] = corpus.profile
                     shared["refits"] += 1
-        return corpus, time.perf_counter() - t1
+        return corpus, time.perf_counter() - t1, 1
 
     def pack(ci):
         chunk = _chunk_blobs(blobs, ci * chunk_workflows, chunk_workflows)
@@ -272,9 +282,9 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
         t0 = time.perf_counter()
         try:
             if use_native:
-                corpus, compress_dt = _encode_native(ci, chunk, slot)
+                corpus, compress_dt, passes = _encode_native(ci, chunk, slot)
             else:
-                corpus, compress_dt = _encode_python(ci, chunk, slot)
+                corpus, compress_dt, passes = _encode_python(ci, chunk, slot)
         except BaseException as exc:
             if ci == 0 and not first_profile.done():
                 first_profile.set_exception(exc)
@@ -283,7 +293,9 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
         registry.inc(m.SCOPE_TPU_NATIVE,
                      m.M_NATIVE_PACKS if use_native
                      else m.M_NATIVE_PY_PACKS)
+        registry.inc(m.SCOPE_TPU_NATIVE, m.M_NATIVE_DECODE_PASSES, passes)
         with state_lock:
+            shared["decode_passes"] += passes
             shared["pack_s"] += pack_dt
             shared["compress_s"] += compress_dt
             shared["events"] += int(corpus.n_events.sum())
@@ -374,6 +386,7 @@ def feed_serialized_wirec(blobs: Sequence[bytes], max_events: int,
     report.events = shared["events"]
     report.wire_bytes = shared["wire_bytes"]
     report.profile_refits = shared["refits"]
+    report.decode_passes = shared["decode_passes"]
     report.h2d_s = shared["h2d_s"]
     report.wall_s = time.perf_counter() - start
     return first, errors, report

@@ -151,25 +151,9 @@ struct Interner {
   }
 };
 
-// wire32 extra lanes (ops/encode.py NUM_LANES32 schema): the two 64-bit
-// values (timestamp nanos, Started-event expiration nanos in attr lane 4)
-// ship split lo/hi; everything else must fit int32
-constexpr int kLane32TsHi = 18;
-constexpr int kLane32A4Hi = 19;
-
-template <typename OutT, bool kWire32>
-inline bool WriteLane(OutT* r, int lane, int64_t v) {
-  if (kWire32) {
-    if (v < INT32_MIN || v > INT32_MAX) return false;
-  }
-  r[lane] = static_cast<OutT>(v);
-  return true;
-}
-
 // one workflow's history -> rows [E, L]; returns events packed or -errcode
-template <typename OutT, bool kWire32>
 int64_t PackOne(const uint8_t* blob, int64_t size, int64_t max_events,
-                int64_t L, OutT* out) {
+                int64_t L, int64_t* out) {
   Cursor c{blob, blob + size};
   Interner intern;
   auto intern_key = [&intern](uint8_t kind, const char* data, uint16_t len) {
@@ -248,26 +232,18 @@ int64_t PackOne(const uint8_t* blob, int64_t size, int64_t max_events,
                            static_cast<uint8_t>(kAParentWorkflowId)})
         miss(code);
 
-      OutT* r = out + row * L;
+      int64_t* r = out + row * L;
       // real rows are fully written: header lanes below, attr lanes cleared
       // here then filled by the per-type switch (supports buffer reuse)
-      std::memset(r + kLaneA0, 0, sizeof(OutT) * (L - kLaneA0));
-      bool fit = true;
-      fit &= WriteLane<OutT, kWire32>(r, kLaneEventId, id);
-      r[kLaneEventType] = static_cast<OutT>(type);
-      fit &= WriteLane<OutT, kWire32>(r, kLaneVersion, version);
-      if (kWire32) {
-        r[kLaneTimestamp] = static_cast<OutT>(static_cast<uint32_t>(ts));
-        r[kLane32TsHi] = static_cast<OutT>(ts >> 32);
-      } else {
-        r[kLaneTimestamp] = static_cast<OutT>(ts);
-      }
-      fit &= WriteLane<OutT, kWire32>(r, kLaneTaskId, task_id);
-      fit &= WriteLane<OutT, kWire32>(r, kLaneBatchFirst, batch_first);
+      std::memset(r + kLaneA0, 0, sizeof(int64_t) * (L - kLaneA0));
+      r[kLaneEventId] = id;
+      r[kLaneEventType] = static_cast<int64_t>(type);
+      r[kLaneVersion] = version;
+      r[kLaneTimestamp] = ts;
+      r[kLaneTaskId] = task_id;
+      r[kLaneBatchFirst] = batch_first;
       r[kLaneBatchLast] = (i == n_events - 1) ? 1 : 0;
-      if (!fit) return -4;  // a narrow lane exceeds int32: int64 path only
-      int64_t a0_vals[8] = {0};
-      int64_t* a0 = a0_vals;
+      int64_t* a0 = r + kLaneA0;
 
       // per-type attribute placement (ops/encode.py _encode_attrs)
       switch (type) {
@@ -326,39 +302,28 @@ int64_t PackOne(const uint8_t* blob, int64_t size, int64_t max_events,
           a0[0] = attrs[kAInitiatedEventId];
           break;
       }
-      // flush attr lanes to the row; wire32 splits a4 (expiration nanos)
-      for (int k = 0; k < 8; ++k) {
-        if (kWire32 && k == 4) {
-          r[kLaneA0 + 4] =
-              static_cast<OutT>(static_cast<uint32_t>(a0_vals[4]));
-          r[kLane32A4Hi] = static_cast<OutT>(a0_vals[4] >> 32);
-        } else if (!WriteLane<OutT, kWire32>(r, kLaneA0 + k, a0_vals[k])) {
-          return -4;
-        }
-      }
       ++row;
     }
   }
   if (!c.ok) return -1;
   // padding tail: zero lanes, event type -1
   for (int64_t e = row; e < max_events; ++e) {
-    std::memset(out + e * L, 0, sizeof(OutT) * L);
-    out[e * L + kLaneEventType] = static_cast<OutT>(-1);
+    std::memset(out + e * L, 0, sizeof(int64_t) * L);
+    out[e * L + kLaneEventType] = -1;
   }
   return row;
 }
 
-template <typename OutT, bool kWire32>
 int64_t PackCorpus(const uint8_t* blob, const int64_t* offsets,
                    int64_t num_workflows, int64_t max_events,
-                   int64_t num_lanes, OutT* out, int64_t num_threads) {
+                   int64_t num_lanes, int64_t* out, int64_t num_threads) {
   if (num_threads < 1) num_threads = 1;
   std::vector<int64_t> totals(static_cast<size_t>(num_threads), 0);
   std::vector<int64_t> errs(static_cast<size_t>(num_threads), 0);
 
   auto work = [&](int64_t t) {
     for (int64_t w = t; w < num_workflows; w += num_threads) {
-      int64_t n = PackOne<OutT, kWire32>(
+      int64_t n = PackOne(
           blob + offsets[w], offsets[w + 1] - offsets[w], max_events,
           num_lanes, out + w * max_events * num_lanes);
       if (n < 0) {
@@ -395,18 +360,8 @@ int64_t cadence_pack_corpus(const uint8_t* blob, const int64_t* offsets,
                             int64_t num_workflows, int64_t max_events,
                             int64_t num_lanes, int64_t* out,
                             int64_t num_threads) {
-  return PackCorpus<int64_t, false>(blob, offsets, num_workflows, max_events,
-                                    num_lanes, out, num_threads);
-}
-
-// wire32 variant: out[W, E, L32] int32 (ops/encode.py NUM_LANES32 schema,
-// timestamp + expiration split lo/hi). err -4: a narrow lane exceeds int32.
-int64_t cadence_pack_corpus32(const uint8_t* blob, const int64_t* offsets,
-                              int64_t num_workflows, int64_t max_events,
-                              int64_t num_lanes, int32_t* out,
-                              int64_t num_threads) {
-  return PackCorpus<int32_t, true>(blob, offsets, num_workflows, max_events,
-                                   num_lanes, out, num_threads);
+  return PackCorpus(blob, offsets, num_workflows, max_events, num_lanes, out,
+                    num_threads);
 }
 
 }  // extern "C"

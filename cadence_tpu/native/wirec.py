@@ -2,12 +2,19 @@
 the native/Python dispatcher every wirec-packing hot path routes through.
 
 The numpy wirec emit cannot keep pace with the device's replay, so
-`wirec.cc` ports measure/emit to C++ (threaded,
-byte-identical, same ProfileMisfit refit contract) and adds a FUSED
-entry point: wire blobs → int64 lanes → wirec adaptive-columnar buffers
-in one multi-threaded call, writing into preallocated reusable host
-buffers sized to the feeder's ring slots so a streaming chunk costs zero
-Python-side allocation or copies before the single H2D transfer.
+`wirec.cc` ports measure/emit to C++ (threaded, byte-identical, same
+ProfileMisfit refit contract) and adds the STREAMED entry points, whose
+unit is the row: a thread decodes one workflow's blob into a one-row
+scratch that stays in its cache and measures or emits it there, so no
+[W, E, L] int64 tensor exists between the wire blobs and the wirec
+buffers. A pinned chunk is one such pass (`cadence_wirec_pack_fused`),
+a chunk with no profile yet two (`cadence_wirec_measure_blobs`, then
+the fused pass under the fresh plan), writing into preallocated
+reusable host buffers sized to the feeder's ring slots so a streaming
+chunk costs zero Python-side allocation or copies before the single H2D
+transfer. The dense entry points (`measure_profile_native`,
+`pack_wirec_native`) serve callers that already hold a lane tensor and
+run the same per-row code.
 
 Path selection: `CADENCE_TPU_NATIVE_WIREC` (default ON when the .so is
 loadable, any of 0/false/off forces the pure-Python path; the fallback
@@ -33,6 +40,7 @@ from ..ops.wirec import (
     pack_wirec,
 )
 from ..utils import metrics as m
+from ..utils import tracing
 from ..utils.concurrency import pack_threads
 from . import build as _build
 
@@ -155,9 +163,9 @@ def _raise_misfit(code: int) -> None:
 
 class WirecBuffers:
     """Preallocated reusable host staging for ONE ring slot of the
-    streaming pipeline: the int64 lanes scratch plus the wirec output
-    triple (slab/bases/n_events), lazily (re)sized when the pinned
-    profile's slab width changes (a refit event — rare by design).
+    streaming pipeline: the wirec output triple (slab/bases/n_events),
+    lazily (re)sized when the pinned profile's slab width changes (a
+    refit event — rare by design).
 
     The native emit fully overwrites every byte it hands out, so slots
     are reused chunk after chunk with no zeroing; the executor's ring
@@ -167,8 +175,6 @@ class WirecBuffers:
     def __init__(self, chunk_workflows: int, max_events: int) -> None:
         self.W = chunk_workflows
         self.E = max_events
-        self.lanes = np.empty((chunk_workflows, max_events, NUM_LANES),
-                              dtype=np.int64)
         self._key: Optional[Tuple[int, int]] = None
         self.slab = self.bases = self.n_events = None
 
@@ -182,6 +188,15 @@ class WirecBuffers:
         return self.slab, self.bases, self.n_events
 
 
+def _measure(entry, *frame, num_threads: int
+             ) -> Tuple[Tuple[LaneCode, ...], int]:
+    """Run one native measure entry point over its call frame (a lane
+    tensor, or blob + offsets, then W, E, L) → (profile, return code)."""
+    plan = [np.zeros(NUM_LANES, dtype=np.int64) for _ in range(4)]
+    rc = entry(*frame, *_col_ptrs(plan), num_threads)
+    return _assemble_profile(list(zip(*(c.tolist() for c in plan)))), rc
+
+
 def measure_profile_native(events64: np.ndarray,
                            num_threads: Optional[int] = None
                            ) -> Tuple[LaneCode, ...]:
@@ -193,16 +208,11 @@ def measure_profile_native(events64: np.ndarray,
     ev = np.ascontiguousarray(events64, dtype=np.int64)
     W, E, L = ev.shape
     assert L == NUM_LANES, f"expected {NUM_LANES} lanes, got {L}"
-    kinds, widths, scales, consts = (np.zeros(L, dtype=np.int64)
-                                     for _ in range(4))
-    rc = lib.cadence_wirec_measure(
-        ev.ctypes.data_as(_I64P), W, E, L,
-        kinds.ctypes.data_as(_I64P), widths.ctypes.data_as(_I64P),
-        scales.ctypes.data_as(_I64P), consts.ctypes.data_as(_I64P),
-        pack_threads(num_threads, cap=L))
+    profile, rc = _measure(
+        lib.cadence_wirec_measure, ev.ctypes.data_as(_I64P), W, E, L,
+        num_threads=pack_threads(num_threads, cap=max(1, W)))
     assert rc == 0, rc
-    return _assemble_profile(list(zip(kinds.tolist(), widths.tolist(),
-                                      scales.tolist(), consts.tolist())))
+    return profile
 
 
 def pack_wirec_native(events64: np.ndarray,
@@ -245,11 +255,17 @@ def pack_serialized_wirec(blobs: Sequence[bytes], max_events: int,
                           num_threads: Optional[int] = None,
                           out: Optional[WirecBuffers] = None
                           ) -> Tuple[WirecCorpus, int]:
-    """The fused streaming chunk: W serialized histories → int64 lanes →
-    wirec buffers in ONE native call (pinned profile) or one pack +
-    measure + emit pass (first chunk). Returns (corpus, total events);
-    raises ProfileMisfit when the chunk falls outside a pinned profile
-    (the caller refits, exactly like the numpy path)."""
+    """The streamed chunk: W serialized histories → wirec buffers, a row
+    at a time inside the native library, with no lane tensor in between.
+    Under a pinned profile that is ONE decode pass (each row decoded,
+    counted and emitted while it is in its thread's cache); with
+    `profile=None` (the first chunk, a refit) it is TWO over the same
+    joined blobs: span `pack.measure` decodes and accumulates the lane
+    statistics, then the fused pass packs under the fresh plan. Returns
+    (corpus, total events); raises ProfileMisfit when the chunk falls
+    outside a pinned profile (the caller refits, exactly like the numpy
+    path) and ValueError naming the lowest workflow that fails to
+    decode."""
     from .packing import blob_offsets, raise_pack_error
 
     lib = _build.load_wirec()
@@ -257,21 +273,17 @@ def pack_serialized_wirec(blobs: Sequence[bytes], max_events: int,
         raise RuntimeError("native wirec unavailable (no C++ toolchain)")
     W = len(blobs)
     blob, offsets = blob_offsets(blobs)
+    frame = (blob, offsets.ctypes.data_as(_I64P), W, max_events, NUM_LANES)
     threads = pack_threads(num_threads, cap=max(1, W))
     if out is not None:
         assert (out.W, out.E) == (W, max_events)
-        lanes = out.lanes
-    else:
-        lanes = np.empty((W, max_events, NUM_LANES), dtype=np.int64)
 
     if profile is None:
-        rc = lib.cadence_pack_corpus(
-            blob, offsets.ctypes.data_as(_I64P), W, max_events, NUM_LANES,
-            lanes.ctypes.data_as(_I64P), threads)
+        with tracing.span("pack.measure"):
+            profile, rc = _measure(lib.cadence_wirec_measure_blobs, *frame,
+                                   num_threads=threads)
         if rc < 0:
             raise_pack_error(rc)
-        corpus = pack_wirec_native(lanes, num_threads=num_threads, out=out)
-        return corpus, int(rc)
 
     B, K = profile_widths(profile)
     if out is not None:
@@ -282,8 +294,7 @@ def pack_serialized_wirec(blobs: Sequence[bytes], max_events: int,
         n_events = np.empty((W,), dtype=np.int32)
     misfit = np.zeros(1, dtype=np.int64)
     rc = lib.cadence_wirec_pack_fused(
-        blob, offsets.ctypes.data_as(_I64P), W, max_events, NUM_LANES,
-        lanes.ctypes.data_as(_I64P),
+        *frame,
         *_col_ptrs(_profile_columns(profile)), len(profile), B, K,
         slab.ctypes.data_as(_U8P), bases.ctypes.data_as(_I64P),
         n_events.ctypes.data_as(_I32P), misfit.ctypes.data_as(_I64P),

@@ -242,6 +242,7 @@ class ServiceHost(socketserver.ThreadingTCPServer):
                            1.0 if native_build.wirec_cached() else 0.0)
         self.metrics.inc(cm.SCOPE_TPU_NATIVE, cm.M_NATIVE_PACKS, 0)
         self.metrics.inc(cm.SCOPE_TPU_NATIVE, cm.M_NATIVE_PY_PACKS, 0)
+        self.metrics.inc(cm.SCOPE_TPU_NATIVE, cm.M_NATIVE_DECODE_PASSES, 0)
         # mesh-aware executor series likewise pre-registered, with the
         # per-device labels the CADENCE_TPU_MESH_DEVICES knob implies
         # (the knob is parsed WITHOUT touching a JAX backend; "all"

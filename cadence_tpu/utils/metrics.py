@@ -256,6 +256,10 @@ M_QUOTA_SHED = "shed"
 M_NATIVE_AVAILABLE = "available"
 M_NATIVE_PACKS = "native-packs"
 M_NATIVE_PY_PACKS = "python-packs"
+#: whole decodes of a chunk's wire blobs (feeder: 1 a pinned chunk, 2 for
+#: chunk 0 and for each refit on the native encoder, which measures by
+#: decoding again instead of keeping a lane tensor)
+M_NATIVE_DECODE_PASSES = "decode-passes"
 #: device-serving transaction tier (engine/serving.py ServingScheduler,
 #: SCOPE_TPU_SERVING): committed history-engine transactions enqueue
 #: into a per-shard coalescing queue and flush as ONE from-state launch

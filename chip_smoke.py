@@ -581,7 +581,7 @@ def phase_bulk(args, size) -> int:
         say(phase="bulk", counters={
             "tpu.native": {k: native.get(k, 0) for k in (
                 m.M_NATIVE_AVAILABLE, m.M_NATIVE_PACKS,
-                m.M_NATIVE_PY_PACKS)},
+                m.M_NATIVE_PY_PACKS, m.M_NATIVE_DECODE_PASSES)},
             "tpu.executor": {m.M_EXEC_CHUNKS: executor.get(m.M_EXEC_CHUNKS)},
         })
         checks.expect(native.get(m.M_NATIVE_AVAILABLE) == 1.0,

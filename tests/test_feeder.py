@@ -193,6 +193,12 @@ class TestFeederNativeWirec:
                          else (m.M_NATIVE_PY_PACKS, m.M_NATIVE_PACKS))
         assert reg.counter(m.SCOPE_TPU_NATIVE, served) == rep.chunks == 3
         assert reg.counter(m.SCOPE_TPU_NATIVE, other) == 0
+        # the native encoder keeps no lane tensor: chunk 0 is decoded
+        # twice (measure, then emit), every pinned chunk once
+        assert rep.profile_refits == 0
+        assert rep.decode_passes == rep.chunks + (1 if native else 0)
+        assert reg.counter(m.SCOPE_TPU_NATIVE,
+                           m.M_NATIVE_DECODE_PASSES) == rep.decode_passes
 
     def test_native_feed_matches_direct_replay_crc(self):
         """Native-fed CRCs == a one-shot replay of the same corpus."""
@@ -300,8 +306,9 @@ class TestFeederNativeWirec:
         """A stream whose later chunks fall outside chunk 0's pinned
         profile must REFIT (counted, never silent) on both encoders and
         still land on identical CRCs — the refit contract is
-        path-independent, including the native fast path that re-emits
-        from the already-decoded lanes scratch."""
+        path-independent. The native encoder keeps no decoded lanes, so
+        a refit packs the chunk again as chunk 0 was packed (measure,
+        then emit): `decode_passes` = chunks + 1 + refits."""
         from cadence_tpu.native import wirec as nwirec
 
         hists = generate_corpus("basic", num_workflows=16, seed=3,
@@ -316,3 +323,7 @@ class TestFeederNativeWirec:
             "the heterogeneous stream no longer exercises the refit path"
         assert (crc_n == crc_p).all()
         assert (err_n == err_p).all()
+        assert rep_p.decode_passes == rep_p.chunks
+        if rep_n.native_wirec:
+            assert rep_n.decode_passes == (rep_n.chunks + 1
+                                           + rep_n.profile_refits)

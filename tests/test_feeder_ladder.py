@@ -135,6 +135,9 @@ def test_flagged_rows_leave_their_ring_slot_before_it_is_packed_over(
     monkeypatch.setattr(EscalationLadder, "submit", slow_submit)
     crc, err, rep = _feed(hists, chunk_workflows=16, depth=2)
     assert rep.chunks == 8 and len(slots) == 2 and len(subs) == 8
+    # a ring slot is the wirec triple and nothing else: the native pack
+    # keeps no [W, E, L] lane tensor
+    assert all(not hasattr(slot, "lanes") for slot in slots)
     for sub in subs:
         for slot in slots:
             assert not np.shares_memory(sub.slab, slot.slab)

@@ -398,3 +398,244 @@ class TestNativeWirec:
                 jnp.asarray(nat.n_events), nat.profile, DEFAULT_LAYOUT)
             assert (np.asarray(crc_p) == np.asarray(crc_n)).all(), suite
             assert (np.asarray(err_p) == np.asarray(err_n)).all(), suite
+
+
+# ---------------------------------------------------------------------------
+# The streamed path (PR 31): blobs → wirec a row at a time, no lane tensor.
+# Its reference is the dense pair pack_wirec(pack_serialized(...)).
+# ---------------------------------------------------------------------------
+
+_EMPTY_BLOB = b"\x00\x00\x00\x00"  # feeder._EMPTY_BLOB: the tail padding
+STREAM_SUITES = SUITES + ("overflow",)
+#: rows a streamed chunk holds: 10 real + 3 of padding = 13, a W that no
+#: thread count below divides but 1 and 13
+_STREAM_W = 13
+
+
+def _stream_chunk(suite, seed=17):
+    from cadence_tpu.ops.encode import history_length
+
+    hists = generate_corpus(suite, num_workflows=_STREAM_W - 3, seed=seed,
+                            target_events=50)
+    blobs = serialize_corpus(hists) + [_EMPTY_BLOB] * 3
+    return blobs, max(history_length(h) for h in hists)
+
+
+def _streamed_vs_dense(blobs, max_events, threads, pinned, reuse, ctx):
+    """pack_serialized_wirec against pack_wirec(pack_serialized(...)):
+    profile, slab, bases, n_events and the event total. `pinned` packs
+    under the dense side's measured profile; `reuse` packs twice into
+    one WirecBuffers, over bytes that a stale slot would leave behind."""
+    from cadence_tpu.native.wirec import WirecBuffers, pack_serialized_wirec
+    from cadence_tpu.ops.wirec import pack_wirec
+
+    expect = pack_wirec(pack_serialized(blobs, max_events))
+    profile = expect.profile if pinned else None
+    out = WirecBuffers(len(blobs), max_events) if reuse else None
+    if reuse:
+        stale, _ = pack_serialized_wirec(blobs[::-1], max_events, out=out,
+                                         num_threads=threads)
+        stale.slab[:] = 0xA5
+        stale.bases[:] = -7
+        stale.n_events[:] = 99
+    got, total = pack_serialized_wirec(blobs, max_events, profile=profile,
+                                       num_threads=threads, out=out)
+    _assert_corpus_equal(expect, got, ctx)
+    assert total == int(expect.n_events.sum()), ctx
+    if reuse and got.slab.shape == stale.slab.shape:
+        assert np.shares_memory(got.slab, out.slab), ctx
+
+
+@native_wirec
+@pytest.mark.parametrize("reuse", [True, False], ids=["out", "fresh"])
+@pytest.mark.parametrize("pinned", [True, False], ids=["pinned", "measure"])
+@pytest.mark.parametrize("threads", [1, 3, 4, 64])
+@pytest.mark.parametrize("suite", STREAM_SUITES)
+def test_streamed_pack_byte_parity(suite, threads, pinned, reuse):
+    """The row-at-a-time pack equals the dense pair byte for byte, for
+    every suite, with a tail of `_EMPTY_BLOB` padding, a W (13) that 3
+    and 4 threads do not divide, more threads than rows, under a pin
+    and measuring, into a reused slot and into fresh arrays."""
+    blobs, max_events = _stream_chunk(suite)
+    _streamed_vs_dense(blobs, max_events, threads, pinned, reuse,
+                       f"{suite}/t{threads}/pinned={pinned}/out={reuse}")
+
+
+def _event(eid, ts, etype, **attrs):
+    from cadence_tpu.core.events import HistoryEvent
+
+    return HistoryEvent(id=eid, event_type=etype, version=0, timestamp=ts,
+                        task_id=1000 + eid, attrs=attrs)
+
+
+def _history(events):
+    from cadence_tpu.core.events import HistoryBatch
+
+    return [HistoryBatch(domain_id="d", workflow_id="w", run_id="r",
+                         events=list(events))]
+
+
+def _plain_history(n=6, t0=1_700_000_000_000_000_000, version=0,
+                   expiration=0, attempt=0):
+    """Started + (n - 1) decision-task events, 1000 ns apart. `version`
+    sets lane 2 of every event, `expiration` lane a4 of the Started
+    event (absolute nanos, the TSREL_NZ shape), `attempt` lane a3."""
+    from cadence_tpu.core.enums import EventType
+
+    ev = [_event(1, t0, EventType.WorkflowExecutionStarted, task_list="tl",
+                 workflow_type="wt",
+                 execution_start_to_close_timeout_seconds=60,
+                 task_start_to_close_timeout_seconds=10, attempt=attempt,
+                 expiration_timestamp=expiration)]
+    for i in range(2, n + 1):
+        ev.append(_event(i, t0 + 1000 * i, EventType.DecisionTaskScheduled,
+                         task_list="tl", start_to_close_timeout_seconds=10,
+                         attempt=0))
+    for e in ev:
+        e.version = version
+    return _history(ev)
+
+
+def _merge_cases():
+    """name → 12 blobs whose plan the MERGE of per-thread statistics has
+    to get right (4 threads: blocks of 3 rows)."""
+    t0 = 1_700_000_000_000_000_000
+    plain = serialize_corpus([_plain_history()])[0]
+    return {
+        # the version lane reads 0 in every block but the third: CONST
+        # inside each block, not CONST over the chunk
+        "const-in-every-block-but-one": (
+            [plain] * 6
+            + serialize_corpus([_plain_history(version=9)]) * 3
+            + [plain] * 3),
+        # block 0 is all padding: `first`, and the CONST lanes' value,
+        # come from a later block
+        "first-value-in-a-later-block": (
+            [_EMPTY_BLOB] * 3
+            + serialize_corpus([_plain_history(attempt=2, version=5)]) * 9),
+        # two blocks hold one value each, and they differ
+        "two-constants": (
+            serialize_corpus([_plain_history(version=3)]) * 6
+            + serialize_corpus([_plain_history(version=4)]) * 6),
+        # a sparse absolute-nanos lane: zeros and huge values, the huge
+        # ones in the last block only
+        "tsrel-lane": (
+            [plain] * 9 + serialize_corpus(
+                [_plain_history(expiration=t0 + 900_000_000_000 * k)
+                 for k in (1, 2, 3)])),
+        # GCDs that only the merge brings down: ticks of 4000 and 6000
+        "gcd-across-blocks": (
+            serialize_corpus([_history(
+                _plain_history()[0].events[:1]
+                + [_event(2, t0 + 4000, 4, task_list="tl", attempt=0,
+                          start_to_close_timeout_seconds=10)])]) * 6
+            + serialize_corpus([_history(
+                _plain_history()[0].events[:1]
+                + [_event(2, t0 + 6000, 4, task_list="tl", attempt=0,
+                          start_to_close_timeout_seconds=10)])]) * 6),
+    }
+
+
+@native_wirec
+@pytest.mark.parametrize("threads", [1, 4, 12])
+@pytest.mark.parametrize("case", sorted(_merge_cases()))
+def test_streamed_measure_merges_blocks_like_one_scan(case, threads):
+    """The streamed measure keeps one set of lane statistics a thread
+    and merges them; the plan must be the one a single scan decides."""
+    from cadence_tpu.native.wirec import measure_profile_native
+    from cadence_tpu.ops.wirec import (
+        KIND_CONST,
+        KIND_TSREL_NZ,
+        pack_wirec,
+    )
+
+    blobs = _merge_cases()[case]
+    dense = pack_serialized(blobs, 8)
+    expect = pack_wirec(dense)
+    # the case is what its name says, on the reference side
+    if case == "tsrel-lane":
+        assert any(e.kind == KIND_TSREL_NZ for e in expect.profile)
+    if case in ("const-in-every-block-but-one", "two-constants"):
+        assert expect.profile[2].kind != KIND_CONST
+    if case == "first-value-in-a-later-block":
+        assert expect.profile[2][:2] == (2, KIND_CONST)
+        assert expect.profile[2].const == 5
+    if case == "gcd-across-blocks":
+        assert expect.profile[3].scale == 2000
+    _streamed_vs_dense(blobs, 8, threads, False, False, case)
+    # the dense entry point runs the same accumulate / merge / finish
+    assert measure_profile_native(dense, num_threads=threads) \
+        == expect.profile, case
+
+
+@native_wirec
+@pytest.mark.parametrize("threads", [1, 3])
+@pytest.mark.parametrize("pinned", [True, False], ids=["pinned", "measure"])
+@pytest.mark.parametrize("fault,code", [("truncated", 1),
+                                        ("unknown-attr", 2),
+                                        ("overlong", 3)])
+def test_streamed_pack_decode_errors(fault, code, pinned, threads):
+    """A blob that does not decode raises the ValueError it always did:
+    the workflow's index in the chunk and the packer's code, from the
+    pinned pass and from the measure pass alike."""
+    from cadence_tpu.native.wirec import pack_serialized_wirec
+    from cadence_tpu.ops.wirec import pack_wirec
+
+    hists = generate_corpus("basic", 7, seed=1, target_events=40)
+    blobs = serialize_corpus(hists)
+    max_events = 64
+    profile = (pack_wirec(pack_serialized(blobs, max_events)).profile
+               if pinned else None)
+    bad = 5
+    if fault == "truncated":
+        blobs[bad] = blobs[bad][:len(blobs[bad]) // 2]
+    elif fault == "unknown-attr":
+        # header: u32 batches, u16 events, then id(8) type(1) version(8)
+        # ts(8) task(8) n_attrs(1); the first attr code follows
+        b = bytearray(blobs[bad])
+        b[4 + 2 + 8 + 1 + 8 + 8 + 8 + 1] = 0xEE
+        blobs[bad] = bytes(b)
+    else:
+        blobs[bad] = serialize_corpus(
+            generate_corpus("basic", 1, seed=2, target_events=200))[0]
+    with pytest.raises(ValueError, match=f"workflow {bad} .code {code}:"):
+        pack_serialized(blobs, max_events)
+    with pytest.raises(ValueError, match=f"workflow {bad} .code {code}:"):
+        pack_serialized_wirec(blobs, max_events, profile=profile,
+                              num_threads=threads)
+
+
+@native_wirec
+@pytest.mark.parametrize("threads", [1, 4])
+@pytest.mark.parametrize("what", ["const", "scale", "width"])
+def test_streamed_pack_misfit_names_the_lane_pack_wirec_names(what, threads):
+    """A chunk outside the pinned plan raises ProfileMisfit from the
+    streamed pass with the lane and the reason pack_wirec gives."""
+    import re
+
+    from cadence_tpu.native.wirec import pack_serialized_wirec
+    from cadence_tpu.ops.wirec import ProfileMisfit, pack_wirec
+
+    pin_blobs = serialize_corpus([_plain_history()] * 8)
+    off = {"const": _plain_history(version=7),           # lane 2 is CONST 0
+           "scale": _history(                            # lane 3 ticks 1000
+               _plain_history()[0].events[:1]
+               + [_event(2, 1_700_000_000_000_000_000 + 1500, 4,
+                         task_list="tl", attempt=0,
+                         start_to_close_timeout_seconds=10)]),
+           "width": _history(                            # one byte a delta
+               _plain_history()[0].events[:1]
+               + [_event(2, 1_700_000_000_000_000_000 + 1000 * 4096, 4,
+                         task_list="tl", attempt=0,
+                         start_to_close_timeout_seconds=10)])}[what]
+    blobs = pin_blobs[:5] + serialize_corpus([off]) + pin_blobs[:2]
+    pin = pack_wirec(pack_serialized(pin_blobs, 8)).profile
+    with pytest.raises(ProfileMisfit) as py:
+        pack_wirec(pack_serialized(blobs, 8), profile=pin)
+    with pytest.raises(ProfileMisfit) as nat:
+        pack_serialized_wirec(blobs, 8, profile=pin, num_threads=threads)
+    lane = re.match(r"lane (\d+):", str(py.value)).group(1)
+    assert str(nat.value).startswith(f"lane {lane}:"), (py.value, nat.value)
+    reason = {"const": "non-const", "scale": "misfit", "width": "overflow"}
+    assert reason[what] in str(py.value) and reason[what] in str(nat.value)
+    assert int(lane) == {"const": 2, "scale": 3, "width": 3}[what]

@@ -568,6 +568,11 @@ class TestBulkPathSpans:
         pool = [s.operation for s in spans if s.trace_id != call.trace_id]
         assert pool.count("pack") == 3
         assert pool.count("pack.first-profile-wait") == 2
+        # chunk 0's measure pass (the native encoder's second decode of
+        # the chunk) has a name of its own inside its `pack`
+        assert pool.count("pack.measure") == (1 if report.native_wirec
+                                              else 0)
+        assert report.decode_passes == 3 + (1 if report.native_wirec else 0)
         # the legs' histograms hold what the spans measured, and the
         # report's own wait is the sum of the two kinds of wait span
         waits = [s.duration_s for s in spans if s.operation in
