@@ -83,11 +83,18 @@ class Driver:
             self.mesh = make_mesh(devices[:n_mesh])
             self.devices = devices[:n_mesh]
         t0 = time.perf_counter()
-        self.blobs, self.max_events, self.real_events = self.farm.collect()
+        self.blobs, longest, self.real_events = self.farm.collect()
         self.farm.close()
+        # the shape the feeder is handed is the traffic file's, the same
+        # for every seed: no seed's longest history may pass it
+        self.max_events = int(self.traffic["max_events"])
+        if longest > self.max_events:
+            raise SystemExit(
+                f"seed {self.opts.seed} made a history of {longest} events: "
+                f"over the traffic file's max_events, {self.max_events}")
         say(driver="replay", corpus_s=time.perf_counter() - t0,
             workflows=len(self.blobs), events=self.real_events,
-            max_events=self.max_events,
+            longest=longest, max_events=self.max_events,
             blob_bytes=sum(len(b) for b in self.blobs))
         # warm-up: one whole pass, so that every executable a profile
         # refit needs exists before the window
@@ -141,7 +148,13 @@ class Driver:
             if trace_dir and not tracing and n == trace_after:
                 shutil.rmtree(trace_dir, ignore_errors=True)
                 os.makedirs(trace_dir, exist_ok=True)
-                self.jax.profiler.start_trace(trace_dir)
+                options = self.jax.profiler.ProfileOptions()
+                # the pack threads and the consumer are the system under
+                # test: their spans and the runtime's events are traced,
+                # every Python call is not
+                options.python_tracer_level = 0
+                self.jax.profiler.start_trace(trace_dir,
+                                              profiler_options=options)
                 tracing, t_trace = True, time.perf_counter()
             call = self._call()
             call["traced"] = tracing
@@ -161,10 +174,20 @@ class Driver:
 
     def end_to_end(self) -> Dict[str, float]:
         events = sum(c["events"] for c in self.calls)
+        slowest = max(self.calls, key=lambda c: c["wall_s"])
         say(driver="replay", passes=len(self.calls), events=events,
             window_s=self.window_s,
             refits=sum(c["refits"] for c in self.calls),
-            pass_s=[round(c["wall_s"], 4) for c in self.calls])
+            pass_s=[round(c["wall_s"], 4) for c in self.calls],
+            # which of the program's legs the window's time went to
+            legs_s={leg: sum(c["legs"][leg] for c in self.calls)
+                    for leg in LEGS},
+            # where a stalled pass spent its time, by the program's legs
+            slowest=dict(
+                {key: slowest[key] for key in (
+                    "wall_s", "legs", "pack_s", "pack_queue_wait_s", "h2d_s",
+                    "ladder_s") if key in slowest},
+                **{"pass": self.calls.index(slowest)}))
         return {"replay_events_per_s": events / self.window_s}
 
     def context(self, device: dict, reduced_trace: Optional[dict]) -> dict:

@@ -18,7 +18,8 @@ the plane `/host:CPU`.
 - `modules`: device seconds and runs per compiled program, summed likewise;
 - `compilations`: host events that are a compilation (`backend_compile`,
   `XlaCompile`), which a steady window must not have;
-- `host_at(ns)`: what the host's busiest traced thread was doing at a time.
+- `host_at(ns)`: what the host was doing at a time: the program's span on
+  the thread that launches, else the busiest traced thread's event.
 
 `python benchmarks/trace_reduce.py <file>` prints a summary to look at.
 """
@@ -29,6 +30,13 @@ import os
 import sys
 from typing import Dict, List, Optional, Tuple
 
+_READERS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "layer_metrics")
+if _READERS not in sys.path:
+    sys.path.insert(0, _READERS)
+
+import _spans  # noqa: E402  (what a program span is: the readers' own rule)
+
 DEVICE_PLANE = "/device:"
 HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
@@ -38,6 +46,8 @@ MODULES_LINE = "XLA Modules"
 #: follows runs; nothing read this way is a device number
 CPU_CLIENT_LINES = ("tf_XLAPjRtCpuClient", "tf_XLAEigen", "tf_XLATfrtCpuClient")
 COMPILE_EVENTS = ("backend_compile", "XlaCompile", "xla_compile")
+#: the runtime's own event around every call of a jitted function
+LAUNCH_EVENT = "PjitFunction("
 
 Interval = Tuple[float, float]
 
@@ -159,9 +169,28 @@ def reduce_trace(path: str, rehearse: bool = False) -> dict:
     }
 
 
+def launching_lines(reduced: dict) -> List[list]:
+    """The host threads that launch programs, the one that launches most
+    first: a jitted call is a `PjitFunction(<name>)` event of the thread
+    that makes it, whatever the Python tracer's level."""
+    launches = [(sum(1 for name, _lo, _hi in events
+                     if name.startswith(LAUNCH_EVENT)), events)
+                for _line, events in reduced["_host_lines"]]
+    return [events for n, events in sorted(
+        launches, key=lambda item: -item[0]) if n]
+
+
 def host_at(reduced: dict, at_ns: float) -> str:
-    """The innermost traced host event that covers `at_ns`, on the thread
-    with most events that has one; "untraced" where none does."""
+    """What the host was doing at `at_ns`: the innermost of the PROGRAM's
+    spans that covers it on a thread that launches (the thread whose
+    waiting is the device's idling), else the innermost traced host event
+    that covers it on the thread with most events that has one;
+    "untraced" where none does."""
+    for events in launching_lines(reduced):
+        covering = [(hi - lo, name) for name, lo, hi in events
+                    if lo <= at_ns < hi and _spans.is_span(name)]
+        if covering:
+            return min(covering)[1]
     for _line, events in reduced["_host_lines"]:
         covering = [(hi - lo, name) for name, lo, hi in events
                     if lo <= at_ns < hi]
