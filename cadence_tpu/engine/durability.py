@@ -6,10 +6,28 @@ Reference seams:
   event-sourced truth (history batches, branch forks, domain/shard
   metadata, current-run pointers, replication queue items);
 - recovery = stateRebuilder.Rebuild (execution/state_rebuilder.go:102)
-  over every run: mutable states are NOT persisted — they are rebuilt by
-  replaying history through the oracle StateBuilder and bulk-VERIFIED on
-  the TPU (tpu_engine.verify_all), the most TPU-native recovery path
-  available (VERDICT round-1 item 5).
+  over every run: mutable states are NOT persisted — `recover_stores`
+  rebuilds them ON THE DEVICE (engine/rebuild.DeviceRebuilder: every
+  run's current branch encoded into dense lanes, replayed in lockstep,
+  each row hydrated into a MutableState and checked against the
+  device's own payload row; a row the kernel flags or the hydration
+  cannot reproduce goes to the oracle StateBuilder, counted), and then
+  bulk-VERIFIES the rebuilt states on the device a second time
+  (tpu_engine.verify_all: a second whole replay, compared on the device,
+  which also seeds the engine's resident pool).
+
+Who runs which path. The function's defaults are both device passes on:
+the path of a process that owns the log AND the chip (the in-process host
+over a WAL), which is what the benchmark's cell `recover.wal-1chip`
+times and what `walcheck.fsck(path, verify_on_device=True,
+rebuild_on_device=True)` reaches. Every product process that recovers
+today turns both off (`verify_on_device=False, rebuild_on_device=False`:
+the oracle alone): `rpc/storeserver.py` because the store server is
+pinned off the chip by its role, `cli.py` because answering `domain
+list` must not pay backend init and a whole-cluster replay (its commands
+verify explicitly: `admin verify`), `engine/crashsim.py`,
+`gen/interleave.py` and `walcheck.fsck`'s own defaults because they
+recover one cut log after another.
 
 Deliberate deviations (documented, test-asserted):
 - transient activity attempt counters (retry without events) are not in
@@ -28,11 +46,12 @@ config write.
 from __future__ import annotations
 
 import base64
+import collections
 import json
 import os
 import threading
 from dataclasses import dataclass, field
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from ..core.codec import deserialize_history, serialize_history
 from ..core.events import HistoryBatch
@@ -42,6 +61,8 @@ from ..oracle.mutable_state import (
     VersionHistoryItem,
 )
 from ..oracle.state_builder import StateBuilder
+from ..utils import metrics as m
+from ..utils import tracing
 from . import crashpoints
 from .persistence import (
     CurrentExecution,
@@ -558,6 +579,12 @@ class RecoveryReport:
     #: (rebuildable, harmless) but they are not counted open, get no
     #: visibility records, and the task refresher never dispatches them.
     quarantined: List[Tuple[str, str, str]] = field(default_factory=list)
+    #: events of the history batches the log replay put back
+    events: int = 0
+    #: wall seconds of the call and of its legs, the spans' own durations:
+    #: "call", then "log-replay", "rebuild" (with "upsert" inside it),
+    #: "verify" (absent where it did not run) and "reconcile"
+    seconds: Dict[str, float] = field(default_factory=dict)
 
     @property
     def ok(self) -> bool:
@@ -584,23 +611,76 @@ def recover_stores(path: str, verify_on_device: bool = True,
 
     1. replay the log: domains, shard infos, history branches (appends +
        forks in original order), pointers, queue items;
-    2. rebuild every run's mutable state by replaying its CURRENT branch
-       through the oracle StateBuilder (state_rebuilder.go:102), grafting
-       the full branch set back onto the version histories;
-    3. bulk-verify the rebuilt states on the TPU (zero-divergence check).
+    2. rebuild every run's mutable state from its CURRENT branch
+       (state_rebuilder.go:102), grafting the full branch set back onto
+       the version histories. With `rebuild_on_device` (the default) one
+       batched device replay rebuilds every run in lockstep and each row
+       is hydrated into a MutableState (engine/rebuild.py); only a row
+       the kernel flags past the ladder, or one whose hydration does not
+       reproduce the device's payload, goes to the oracle StateBuilder,
+       counted in the report. With it off every run goes through the
+       oracle and nothing imports JAX;
+    3. with `verify_on_device` (the default) replay every run a second
+       time on the device and compare with the rebuilt states there
+       (zero-divergence check; it seeds that engine's resident pool).
+
+    Both switches are on by default; `rpc/storeserver.py`, `cli.py`,
+    `engine/crashsim.py`, `gen/interleave.py` and `walcheck.fsck`'s
+    defaults turn both off (the module's head says why).
+
+    The call is the span `recover.call` and each step a leg under it:
+    `recover.log-replay`, `recover.rebuild` (with `recover.upsert` inside),
+    `recover.verify`, `recover.reconcile`; their seconds are
+    `report.seconds`, and what the call read and handed to the device is
+    counted once under `tpu.recover/*`.
 
     The caller re-acquires shards (bumping range IDs past the dead
     owner's) and runs the task refresher for open workflows.
     """
-    stores = Stores()
-    stores.recovered_config = []
+    with tracing.span("recover.call") as call:
+        with tracing.span("recover.log-replay") as leg:
+            stores = Stores()
+            stores.recovered_config = []
+            referenced_runs, original, events = _replay_log(path, stores)
+        report = _rebuild_executions(stores, verify_on_device, layout,
+                                     referenced_runs, rebuild_on_device)
+        report.events = events
+        report.seconds["log-replay"] = leg.duration_s
+        with tracing.span("recover.reconcile") as leg:
+            _reconcile_current_pointers(stores)
+            # new writes continue the same log (records are idempotent to
+            # replay: recovery takes the last pointer values and appends
+            # are per-branch contiguous, so a recovered process re-logging
+            # is consistent)
+            wal = open_log(path)
+            if original < WAL_VERSION:
+                # records appended from here on are CURRENT-format; stamp
+                # a mid-file version header ("last ver record wins") so
+                # the next recovery doesn't re-run migrations over
+                # already-lifted records — safe today only because
+                # _migrate_1_to_2 is idempotent, required the moment any
+                # migration isn't
+                wal.append(version_record())
+            stores.attach_wal(wal)
+        report.seconds["reconcile"] = leg.duration_s
+    report.seconds["call"] = call.duration_s
+    return stores, report
+
+
+def _replay_log(path: str, stores: Stores) -> Tuple[set, int, int]:
+    """Step 1 of `recover_stores`: the log read, lifted to the current
+    schema and every record put back into `stores`, in file order. Returns
+    the runs a current-run record ever referenced, the log's original
+    schema version and the events of the history batches appended; what it
+    read is counted under `tpu.recover/*`."""
     #: every run a current-run record EVER referenced (not just the final
     #: pointer): a run with history but no reference is an orphan tail of
     #: a start that died before its create_workflow commit point
     referenced_runs = set()
     # schema gate + in-memory migration (the setup/update-schema contract):
     # older logs lift transparently; NEWER logs refuse
-    records, _original = migrate_records(read_log(path))
+    records, original = migrate_records(read_log(path))
+    n_batches = n_events = n_bytes = 0
     for rec in records:
         t = rec["t"]
         if t == "d":
@@ -624,11 +704,14 @@ def recover_stores(path: str, verify_on_device: bool = True,
                 transfer_queue_states=[list(q)
                                        for q in rec.get("qs", [])]))
         elif t == "h":
-            batches = deserialize_history(
-                base64.b64decode(rec["blob"]), rec["d"], rec["w"], rec["r"])
+            blob = base64.b64decode(rec["blob"])
+            batches = deserialize_history(blob, rec["d"], rec["w"], rec["r"])
             for batch in batches:
                 stores.history.append_batch(rec["d"], rec["w"], rec["r"],
                                             batch.events, branch=rec["b"])
+                n_events += len(batch.events)
+            n_batches += len(batches)
+            n_bytes += len(blob)
         elif t == "f":
             stores.history.fork_branch(rec["d"], rec["w"], rec["r"],
                                        source_branch=rec["src"],
@@ -691,23 +774,16 @@ def recover_stores(path: str, verify_on_device: bool = True,
                 stores.queue.enqueue(rec["q"], DLQEntry(
                     task=_repl_task_from(rec["p"]["task"]),
                     error=rec["p"]["err"]))
-
-    report = _rebuild_executions(stores, verify_on_device, layout,
-                                 referenced_runs, rebuild_on_device)
-    _reconcile_current_pointers(stores)
-    # new writes continue the same log (records are idempotent to replay:
-    # recovery takes the last pointer values and appends are per-branch
-    # contiguous, so a recovered process re-logging is consistent)
-    wal = open_log(path)
-    if _original < WAL_VERSION:
-        # records appended from here on are CURRENT-format; stamp a
-        # mid-file version header ("last ver record wins") so the next
-        # recovery doesn't re-run migrations over already-lifted records —
-        # safe today only because _migrate_1_to_2 is idempotent, required
-        # the moment any migration isn't
-        wal.append(version_record())
-    stores.attach_wal(wal)
-    return stores, report
+    scope = m.DEFAULT_REGISTRY.scope(m.SCOPE_TPU_RECOVER)
+    scope.inc(m.M_RECOVER_LOG_RECORDS, len(records))
+    for record_type, n in collections.Counter(
+            rec["t"] for rec in records).items():
+        scope.inc(m.recover_records(record_type), n)
+    scope.inc(m.M_RECOVER_LOG_BYTES, os.path.getsize(path))
+    scope.inc(m.M_RECOVER_HISTORY_BATCHES, n_batches)
+    scope.inc(m.M_RECOVER_HISTORY_EVENTS, n_events)
+    scope.inc(m.M_RECOVER_HISTORY_BYTES, n_bytes)
+    return referenced_runs, original, n_events
 
 
 def _reconcile_current_pointers(stores: Stores) -> None:
@@ -736,103 +812,127 @@ def _reconcile_current_pointers(stores: Stores) -> None:
 def _rebuild_executions(stores: Stores, verify_on_device: bool,
                         layout=None, referenced_runs=frozenset(),
                         rebuild_on_device: bool = True) -> RecoveryReport:
-    from ..core.enums import WorkflowState
-    from ..oracle.mutable_state import DomainEntry
-    from .rebuild import DeviceRebuilder
-
     report = RecoveryReport()
-    keys = stores.history.list_runs()
-    jobs = []
-    for key in keys:
-        domain_id = key[0]
-        try:
-            d = stores.domain.by_id(domain_id)
-            entry = DomainEntry(domain_id=d.domain_id, name=d.name,
-                                is_active=d.is_active,
-                                retention_days=d.retention_days,
-                                failover_version=d.failover_version)
-        except Exception:
-            entry = None
-        current_branch = stores.history.get_current_branch(*key)
-        jobs.append((stores.history.as_history_batches(
-            *key, branch=current_branch), entry))
+    with tracing.span("recover.rebuild") as leg:
+        from ..core.enums import WorkflowState
+        from ..oracle.mutable_state import DomainEntry
+        from .rebuild import DeviceRebuilder
 
-    # one batched device replay rebuilds EVERY run's state in lockstep
-    # (the bulk state_rebuilder); flagged rows fall back to the oracle,
-    # counted in the report
-    from ..core.checksum import DEFAULT_LAYOUT
-    layout = layout if layout is not None else DEFAULT_LAYOUT
-    rebuilder = DeviceRebuilder(layout)
-    # warm restart: the device rebuild consults the recovered snapshot
-    # store — a run with a valid snapshot hydrates the persisted
-    # ReplayState row and replays ONLY the since-snapshot suffix
-    # (engine/snapshot.py), instead of re-encoding + re-scanning its
-    # whole history. Oracle-mode recovery (rebuild_on_device=False)
-    # ignores snapshots entirely: no device state to hydrate into.
-    rebuilder.snapshots = stores.snapshot
-    states = rebuilder.rebuild(jobs, on_device=rebuild_on_device) if jobs else []
-    report.device_rebuilt = rebuilder.stats.device
-    report.rebuild_fallback = rebuilder.stats.oracle_fallback
-    report.snapshot_hydrated = rebuilder.stats.snapshot_seeded
+        keys = stores.history.list_runs()
+        jobs = []
+        for key in keys:
+            domain_id = key[0]
+            try:
+                d = stores.domain.by_id(domain_id)
+                entry = DomainEntry(domain_id=d.domain_id, name=d.name,
+                                    is_active=d.is_active,
+                                    retention_days=d.retention_days,
+                                    failover_version=d.failover_version)
+            except Exception:
+                entry = None
+            current_branch = stores.history.get_current_branch(*key)
+            jobs.append((stores.history.as_history_batches(
+                *key, branch=current_branch), entry))
 
-    for key, ms in zip(keys, states):
-        current_branch = stores.history.get_current_branch(*key)
-        # graft the OTHER branches' version histories (items derived from
-        # their stored events) so NDC state survives recovery
-        n_branches = stores.history.branch_count(*key)
-        if n_branches > 1:
-            histories = []
-            for b in range(n_branches):
-                if b == current_branch:
-                    histories.append(ms.version_histories.current())
-                else:
-                    histories.append(_items_from_events(
-                        stores.history.read_events(*key, branch=b)))
-            ms.version_histories.histories = histories
-            ms.version_histories.current_index = current_branch
-        stores.execution.upsert_workflow(ms, set_current=False)
-        report.executions_rebuilt += 1
-        info = ms.execution_info
-        try:
-            is_current = (stores.execution.get_current_run_id(
-                key[0], key[1]) == key[2])
-        except Exception:
-            is_current = False
-        closed = info.state == WorkflowState.Completed
-        if not closed:
-            # an open run never referenced by ANY current-run record is an
-            # orphan tail of a start that died before its create_workflow
-            # commit point (or an NDC zombie): keep the snapshot but never
-            # surface it as open — the reference treats such history as
-            # garbage nodes, not a live execution
-            if not is_current and key not in referenced_runs:
-                report.quarantined.append(key)
-            else:
-                report.open_workflows += 1
-        # visibility is DERIVED data (the reference reindexes ES from
-        # history); rebuild the records here instead of logging them.
-        # Only runs holding the current pointer (or closed runs) get
-        # records: zombies and orphan history from failed starts must not
-        # surface as phantom open workflows. Close time approximates to
-        # the completion event's timestamp.
-        from .persistence import VisibilityRecord
-        if is_current or closed:
-            stores.visibility.record_started(VisibilityRecord(
-                domain_id=key[0], workflow_id=key[1], run_id=key[2],
-                workflow_type=info.workflow_type_name,
-                start_time=info.start_timestamp))
-        if closed:
-            events = stores.history.read_events(*key)
-            stores.visibility.record_closed(
-                *key, close_time=events[-1].timestamp if events else 0,
-                close_status=info.close_status)
+        # one batched device replay rebuilds EVERY run's state in lockstep
+        # (the bulk state_rebuilder); flagged rows fall back to the oracle,
+        # counted in the report
+        from ..core.checksum import DEFAULT_LAYOUT
+        layout = layout if layout is not None else DEFAULT_LAYOUT
+        rebuilder = DeviceRebuilder(layout)
+        # warm restart: the device rebuild consults the recovered snapshot
+        # store — a run with a valid snapshot hydrates the persisted
+        # ReplayState row and replays ONLY the since-snapshot suffix
+        # (engine/snapshot.py), instead of re-encoding + re-scanning its
+        # whole history. Oracle-mode recovery (rebuild_on_device=False)
+        # ignores snapshots entirely: no device state to hydrate into.
+        rebuilder.snapshots = stores.snapshot
+        states = rebuilder.rebuild(jobs, on_device=rebuild_on_device) \
+            if jobs else []
+        report.device_rebuilt = rebuilder.stats.device
+        report.rebuild_fallback = rebuilder.stats.oracle_fallback
+        report.snapshot_hydrated = rebuilder.stats.snapshot_seeded
+        scope = m.DEFAULT_REGISTRY.scope(m.SCOPE_TPU_RECOVER)
+        scope.inc(m.M_RECOVER_REBUILD_EVENTS, rebuilder.stats.events)
+        scope.inc(m.M_RECOVER_REBUILD_CHUNKS, rebuilder.stats.chunks)
+        scope.inc(m.M_RECOVER_DENSE_BYTES, rebuilder.stats.dense_bytes)
+
+        with tracing.span("recover.upsert") as upsert:
+            for key, ms in zip(keys, states):
+                current_branch = stores.history.get_current_branch(*key)
+                # graft the OTHER branches' version histories (items
+                # derived from their stored events) so NDC state survives
+                # recovery
+                n_branches = stores.history.branch_count(*key)
+                if n_branches > 1:
+                    histories = []
+                    for b in range(n_branches):
+                        if b == current_branch:
+                            histories.append(ms.version_histories.current())
+                        else:
+                            histories.append(_items_from_events(
+                                stores.history.read_events(*key, branch=b)))
+                    ms.version_histories.histories = histories
+                    ms.version_histories.current_index = current_branch
+                stores.execution.upsert_workflow(ms, set_current=False)
+                report.executions_rebuilt += 1
+                info = ms.execution_info
+                try:
+                    is_current = (stores.execution.get_current_run_id(
+                        key[0], key[1]) == key[2])
+                except Exception:
+                    is_current = False
+                closed = info.state == WorkflowState.Completed
+                if not closed:
+                    # an open run never referenced by ANY current-run
+                    # record is an orphan tail of a start that died before
+                    # its create_workflow commit point (or an NDC zombie):
+                    # keep the snapshot but never surface it as open — the
+                    # reference treats such history as garbage nodes, not a
+                    # live execution
+                    if not is_current and key not in referenced_runs:
+                        report.quarantined.append(key)
+                    else:
+                        report.open_workflows += 1
+                # visibility is DERIVED data (the reference reindexes ES
+                # from history); rebuild the records here instead of logging
+                # them. Only runs holding the current pointer (or closed
+                # runs) get records: zombies and orphan history from failed
+                # starts must not surface as phantom open workflows. Close
+                # time approximates to the completion event's timestamp.
+                from .persistence import VisibilityRecord
+                if is_current or closed:
+                    stores.visibility.record_started(VisibilityRecord(
+                        domain_id=key[0], workflow_id=key[1], run_id=key[2],
+                        workflow_type=info.workflow_type_name,
+                        start_time=info.start_timestamp))
+                if closed:
+                    events = stores.history.read_events(*key)
+                    stores.visibility.record_closed(
+                        *key,
+                        close_time=events[-1].timestamp if events else 0,
+                        close_status=info.close_status)
+        scope.inc(m.M_RECOVER_EXECUTIONS, report.executions_rebuilt)
+    report.seconds["rebuild"] = leg.duration_s
+    report.seconds["upsert"] = upsert.duration_s
 
     if verify_on_device and report.executions_rebuilt:
-        from .tpu_engine import TPUReplayEngine
-        result = TPUReplayEngine(stores, layout).verify_all()
+        with tracing.span("recover.verify") as leg:
+            from ..ops.encode import NUM_LANES
+            from .tpu_engine import TPUReplayEngine
+            engine = TPUReplayEngine(stores, layout)
+            result = engine.verify_all()
+            dense_bytes = 8 * NUM_LANES * sum(
+                w * e for w, e in engine.last_run_chunk_shapes)
+            # no caller is handed the engine: the resident pool that
+            # verify_all has just seeded is dropped with it, here
+            del engine
+        report.seconds["verify"] = leg.duration_s
         report.device_verified = result.verified_on_device
         report.oracle_fallback = len(result.fallback)
         report.divergent = result.divergent
+        scope.inc(m.M_RECOVER_ROWS_VERIFIED, result.verified_on_device)
+        scope.inc(m.M_RECOVER_DENSE_BYTES, dense_bytes)
     return report
 
 

@@ -44,6 +44,7 @@ from ..oracle.mutable_state import (
     VersionHistoryItem,
 )
 from ..oracle.state_builder import StateBuilder
+from ..utils import tracing
 
 
 @dataclass
@@ -63,6 +64,11 @@ class RebuildStats:
     #: (engine/snapshot.py) — the warm-restart path: hydrate + replay
     #: only the since-snapshot suffix, never the full history
     snapshot_seeded: int = 0
+    #: what the full-replay path handed to the device: launches, the real
+    #: events in them and the dense int64 bytes shipped (padding included)
+    chunks: int = 0
+    events: int = 0
+    dense_bytes: int = 0
     kernel_errors: Dict[int, int] = field(default_factory=dict)
 
     def merge(self, other: "RebuildStats") -> None:
@@ -71,6 +77,9 @@ class RebuildStats:
         self.ladder += other.ladder
         self.resident += other.resident
         self.snapshot_seeded += other.snapshot_seeded
+        self.chunks += other.chunks
+        self.events += other.events
+        self.dense_bytes += other.dense_bytes
         for code, n in other.kernel_errors.items():
             self.kernel_errors[code] = self.kernel_errors.get(code, 0) + n
 
@@ -161,7 +170,14 @@ class DeviceRebuilder:
         `on_device=False` skips JAX entirely and replays through the
         oracle — for read-only CLI invocations where paying backend init
         plus a whole-cluster device replay to answer `domain list` is
-        wrong (ADVICE r3)."""
+        wrong (ADVICE r3).
+
+        Each leg of a device call is one span, never one a job:
+        `rebuild.snapshot-consult`, `rebuild.resident-prepass`,
+        `rebuild.encode` (a chunk's `encode_corpus`, on a pack thread),
+        `rebuild.replay` (launch to rows on the host), `rebuild.hydrate`
+        (rows to MutableStates) and, where rows were capacity-flagged,
+        `rebuild.ladder`."""
         if not on_device:
             from ..utils import metrics as m
             self.stats.oracle_fallback += len(jobs)
@@ -185,13 +201,15 @@ class DeviceRebuilder:
         # resident pool (seeding the pack cache's interner at the
         # snapshot point), so the resident prepass below serves them as
         # exact/suffix hits — replaying only the since-snapshot suffix
-        self._seed_from_snapshots(jobs)
+        with tracing.span("rebuild.snapshot-consult"):
+            self._seed_from_snapshots(jobs)
         # resident consult: jobs whose key is pinned in the HBM cache
         # rebuild from the resident state — an exact hit hydrates with
         # ZERO replay, a suffix hit replays only the appended batches
         # (lookups are non-authoritative: rebuild may legitimately pass
         # a prefix of the stored history, e.g. a reset point)
-        pre: Dict[int, MutableState] = self._resident_prepass(jobs)
+        with tracing.span("rebuild.resident-prepass"):
+            pre: Dict[int, MutableState] = self._resident_prepass(jobs)
         if pre:
             positions = [i for i in range(len(jobs)) if i not in pre]
             jobs = [jobs[i] for i in positions]
@@ -225,7 +243,8 @@ class DeviceRebuilder:
             lo, hi = spans[ci]
             chunk = jobs[lo:hi]
             max_events = max(history_length(b) for b, _ in chunk)
-            corpus = encode_corpus([b for b, _ in chunk], max_events)
+            with tracing.span("rebuild.encode"):
+                corpus = encode_corpus([b for b, _ in chunk], max_events)
             if corpus.shape[0] % n_dev:
                 # whole slice per device: pad with no-op rows
                 from ..ops.encode import LANE_EVENT_TYPE, NUM_LANES
@@ -241,6 +260,9 @@ class DeviceRebuilder:
             corpus, chunk_events = packed
             scope.inc(m.M_KERNEL_LAUNCHES)
             scope.inc(m.M_EVENTS_REPLAYED, chunk_events)
+            self.stats.chunks += 1
+            self.stats.events += chunk_events
+            self.stats.dense_bytes += corpus.nbytes
             with prof.leg(m.M_PROFILE_H2D):
                 device_corpus = place_corpus(corpus, mesh)
                 prof.h2d(corpus.nbytes)
@@ -255,7 +277,9 @@ class DeviceRebuilder:
             with prof.leg(m.M_PROFILE_READBACK):
                 return np.asarray(rows_dev), jax.device_get(state)
 
-        with scope.timed():
+        # launch to rows on the host; the executor's own legs (h2d,
+        # device-wait, readback on this thread, pack on its pool) lie inside
+        with scope.timed(), tracing.span("rebuild.replay"):
             results, _report = executor.run(len(spans), pack, launch,
                                             consume)
 
@@ -267,55 +291,58 @@ class DeviceRebuilder:
         #: instead of one oracle loop each
         escalate: List[Tuple[int, Sequence[HistoryBatch],
                              Optional[DomainEntry]]] = []
-        for (lo, hi), (rows, arrs) in zip(spans, results):
-            for i, (batches, entry) in enumerate(jobs[lo:hi]):
-                err = int(arrs.error[i])
-                if err != 0:
-                    self.stats.kernel_errors[err] = (
-                        self.stats.kernel_errors.get(err, 0) + 1)
-                    if err in CAPACITY_ERRORS:
-                        escalate.append((len(out), batches, entry))
-                        out.append(None)
+        # rows -> MutableState, each checked against its device row
+        with tracing.span("rebuild.hydrate"):
+            for (lo, hi), (rows, arrs) in zip(spans, results):
+                for i, (batches, entry) in enumerate(jobs[lo:hi]):
+                    err = int(arrs.error[i])
+                    if err != 0:
+                        self.stats.kernel_errors[err] = (
+                            self.stats.kernel_errors.get(err, 0) + 1)
+                        if err in CAPACITY_ERRORS:
+                            escalate.append((len(out), batches, entry))
+                            out.append(None)
+                            continue
+                        self.stats.oracle_fallback += 1
+                        scope.inc(m.M_ORACLE_FALLBACKS)
+                        out.append(self._oracle_rebuild(batches, entry))
                         continue
-                    self.stats.oracle_fallback += 1
-                    scope.inc(m.M_ORACLE_FALLBACKS)
-                    out.append(self._oracle_rebuild(batches, entry))
-                    continue
-                ms = self._hydrate(arrs, i, batches, entry)
-                if ms is None or not (payload_row(ms, self.layout)
-                                      == rows[i]).all():
-                    # hydration must reproduce the device's canonical
-                    # payload exactly; anything else routes through the
-                    # oracle, counted
-                    self.stats.oracle_fallback += 1
-                    scope.inc(m.M_ORACLE_FALLBACKS)
-                    out.append(self._oracle_rebuild(batches, entry))
-                    continue
-                self.stats.device += 1
-                scope.inc(m.M_DEVICE_REBUILDS)
-                out.append(ms)
+                    ms = self._hydrate(arrs, i, batches, entry)
+                    if ms is None or not (payload_row(ms, self.layout)
+                                          == rows[i]).all():
+                        # hydration must reproduce the device's canonical
+                        # payload exactly; anything else routes through the
+                        # oracle, counted
+                        self.stats.oracle_fallback += 1
+                        scope.inc(m.M_ORACLE_FALLBACKS)
+                        out.append(self._oracle_rebuild(batches, entry))
+                        continue
+                    self.stats.device += 1
+                    scope.inc(m.M_DEVICE_REBUILDS)
+                    out.append(ms)
 
         if escalate:
-            corpus = encode_corpus(
-                [b for _, b, _ in escalate],
-                max(history_length(b) for _, b, _ in escalate))
-            outcome, states = self.ladder.escalate_states(corpus)
-            for k, (pos, batches, entry) in enumerate(escalate):
-                ms = None
-                if outcome.resolved[k]:
-                    arrs_k, row_k = states[k]
-                    ms = self._hydrate(arrs_k, row_k, batches, entry)
-                if (ms is not None
-                        and (payload_row(ms, self.layout)
-                             == outcome.rows[k]).all()):
-                    self.stats.device += 1
-                    self.stats.ladder += 1
-                    scope.inc(m.M_DEVICE_REBUILDS)
-                    out[pos] = ms
-                else:
-                    self.stats.oracle_fallback += 1
-                    scope.inc(m.M_ORACLE_FALLBACKS)
-                    out[pos] = self._oracle_rebuild(batches, entry)
+            with tracing.span("rebuild.ladder"):
+                corpus = encode_corpus(
+                    [b for _, b, _ in escalate],
+                    max(history_length(b) for _, b, _ in escalate))
+                outcome, states = self.ladder.escalate_states(corpus)
+                for k, (pos, batches, entry) in enumerate(escalate):
+                    ms = None
+                    if outcome.resolved[k]:
+                        arrs_k, row_k = states[k]
+                        ms = self._hydrate(arrs_k, row_k, batches, entry)
+                    if (ms is not None
+                            and (payload_row(ms, self.layout)
+                                 == outcome.rows[k]).all()):
+                        self.stats.device += 1
+                        self.stats.ladder += 1
+                        scope.inc(m.M_DEVICE_REBUILDS)
+                        out[pos] = ms
+                    else:
+                        self.stats.oracle_fallback += 1
+                        scope.inc(m.M_ORACLE_FALLBACKS)
+                        out[pos] = self._oracle_rebuild(batches, entry)
         done = self.stats.device + self.stats.oracle_fallback
         self.metrics.gauge(m.SCOPE_REBUILD, m.M_FALLBACK_RATE,
                            (self.stats.oracle_fallback / done) if done else 0.0)

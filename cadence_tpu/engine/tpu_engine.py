@@ -60,6 +60,7 @@ from ..ops.encode import (
 from ..ops.payload import payload_rows
 from ..ops.replay import replay_events, verify_rows
 from ..utils import metrics as m
+from ..utils import tracing
 from ..utils.profiler import ReplayProfiler
 from . import resident as resident_mod
 from .cache import PackCache, content_address
@@ -374,6 +375,7 @@ class TPUReplayEngine:
 
         n_dev = int(mesh.devices.size)
 
+        @tracing.spanned("verify.pack")
         def pack(ci):
             plan = plans[ci]
             chunk_keys = [keys[i] for i in plan.idx]
@@ -545,7 +547,13 @@ class TPUReplayEngine:
         ladder resolves verify against the live state at the base payload
         width, byte-identically to the oracle; only the ladder's residue
         (plus non-capacity errors) re-runs through the per-workflow
-        oracle."""
+        oracle.
+
+        Each leg of a call is one span: `verify.partition` (the resident
+        pool consulted), `verify.pack` (a chunk's encode and expected
+        rows, on a pack thread), `verify.replay` (launch to results on
+        the host) with a chunk's `verify.seed-resident` (verified rows
+        pinned into the pool) inside it, and `verify.compare`."""
         if keys is None:
             keys = self.stores.execution.list_executions()
         all_keys = list(keys)
@@ -557,8 +565,9 @@ class TPUReplayEngine:
         self.mesh
         result = BulkVerifyResult(total=len(all_keys), verified_on_device=0)
         if resident_mod.enabled():
-            exact, suffix, keys, addresses, hydrated = \
-                self._partition_resident(all_keys)
+            with tracing.span("verify.partition"):
+                exact, suffix, keys, addresses, hydrated = \
+                    self._partition_resident(all_keys)
             result.snapshot = hydrated
         else:
             exact, suffix, keys, addresses = [], [], all_keys, {}
@@ -649,21 +658,34 @@ class TPUReplayEngine:
             # state-row slice per key and zero extra readback (the cache
             # re-places the row on the key's owning device). The state
             # reference is dropped here (the ring keeps O(depth) alive).
-            for j, i in enumerate(plan.idx):
-                key = keys[i]
-                r = int(plan.rows[j])
-                if (errors[r] == 0 and not mismatch[r]
-                        and key in addresses):
-                    self.resident.admit(
-                        key, addresses[key],
-                        self.resident.extract_row(state, r),
-                        expected[r], int(exp_branch[r]))
+            with tracing.span("verify.seed-resident"):
+                for j, i in enumerate(plan.idx):
+                    key = keys[i]
+                    r = int(plan.rows[j])
+                    if (errors[r] == 0 and not mismatch[r]
+                            and key in addresses):
+                        self.resident.admit(
+                            key, addresses[key],
+                            self.resident.extract_row(state, r),
+                            expected[r], int(exp_branch[r]))
             return mismatch, errors, expected, exp_branch
 
         plans_by_ci = self._plan_chunks(keys)
-        results, plans = self._run_chunks(keys, pack_extra, launch,
-                                          readback, escalate,
-                                          plans=plans_by_ci)
+        # launch to results on the host; the executor's legs and each
+        # chunk's `verify.seed-resident` lie inside
+        with tracing.span("verify.replay"):
+            results, plans = self._run_chunks(keys, pack_extra, launch,
+                                              readback, escalate,
+                                              plans=plans_by_ci)
+        with tracing.span("verify.compare"):
+            self._settle(result, keys, plans, results, pending)
+        return result
+
+    def _settle(self, result: BulkVerifyResult, keys, plans, results,
+                pending: dict) -> None:
+        """The last leg of `verify_all`: the ladder's pending rungs read
+        back, then every row's verdict from its chunk's mismatch bitmap
+        and error lane into `result`."""
         ordered = sorted(pending.items())
         outcomes = self.ladder.finish([p for _, (_, p) in ordered])
         resolved = {}  # (ci, local j) -> (base-width ladder row, branch)
@@ -703,4 +725,3 @@ class TPUReplayEngine:
                     result.verified_on_device += 1
                     if mismatch[r]:
                         result.divergent.append(key)
-        return result

@@ -70,6 +70,10 @@ SCOPE_TPU_MIGRATION = "tpu.migration"
 #: ops/scan.py): List/Scan/Count served as vectorized mask kernels over
 #: device-resident columns; counters below under M_VIS_*
 SCOPE_TPU_VISIBILITY = "tpu.visibility"
+#: crash recovery (engine/durability.recover_stores): what one call read
+#: from the log and what its two device passes were handed; counters
+#: below under M_RECOVER_*
+SCOPE_TPU_RECOVER = "tpu.recover"
 SCOPE_WORKER_RETENTION = "worker.retention"
 SCOPE_WORKER_SCAVENGER = "worker.scavenger"
 SCOPE_WORKER_SCANNER = "worker.scanner"
@@ -370,6 +374,29 @@ M_VIS_SCAN_LATENCY = "scan-latency"
 #: the least-queried resident column and took its slot — queries on it
 #: stop permanently falling back (visibility_device._maybe_replace_attr)
 M_VIS_ATTR_REPLACEMENTS = "attr-column-replacements"
+#: crash recovery (engine/durability.recover_stores, SCOPE_TPU_RECOVER),
+#: added once a call: the log's records (all, and a series a record type:
+#: recover_records("h") = log-records-h) and the bytes of the file read;
+#: the history batches, their events and their serialized bytes (the
+#: blobs before base64: what any recovery must read once); the runs, events
+#: and chunks the device rebuild was handed; the dense int64 bytes both
+#: device passes shipped (rebuild + verify); the rows the verify held
+#: against the rebuilt states on the device
+M_RECOVER_LOG_RECORDS = "log-records"
+M_RECOVER_LOG_BYTES = "log-bytes"
+M_RECOVER_HISTORY_BATCHES = "history-batches"
+M_RECOVER_HISTORY_EVENTS = "history-events"
+M_RECOVER_HISTORY_BYTES = "history-bytes"
+M_RECOVER_EXECUTIONS = "executions-rebuilt"
+M_RECOVER_REBUILD_EVENTS = "events-rebuilt"
+M_RECOVER_REBUILD_CHUNKS = "chunks-rebuilt"
+M_RECOVER_DENSE_BYTES = "dense-bytes"
+M_RECOVER_ROWS_VERIFIED = "rows-verified"
+
+
+def recover_records(record_type: str) -> str:
+    """Per-record-type log counter name: log-records-h, log-records-cur, ..."""
+    return f"{M_RECOVER_LOG_RECORDS}-{record_type}"
 
 
 def ladder_rung_rows(rung: int) -> str:
