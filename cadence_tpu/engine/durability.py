@@ -14,7 +14,8 @@ Reference seams:
   cannot reproduce goes to the oracle StateBuilder, counted), and then
   bulk-VERIFIES the rebuilt states on the device a second time
   (tpu_engine.verify_all: a second whole replay, compared on the device,
-  which also seeds the engine's resident pool).
+  which also seeds the engine's resident pool: each chunk's verified
+  rows pinned as views of the chunk's state, no launch a row).
 
 Who runs which path. The function's defaults are both device passes on:
 the path of a process that owns the log AND the chip (the in-process host
@@ -622,7 +623,8 @@ def recover_stores(path: str, verify_on_device: bool = True,
        oracle and nothing imports JAX;
     3. with `verify_on_device` (the default) replay every run a second
        time on the device and compare with the rebuilt states there
-       (zero-divergence check; it seeds that engine's resident pool).
+       (zero-divergence check; it seeds that engine's resident pool
+       with views of each chunk's state, engine/resident.py).
 
     Both switches are on by default; `rpc/storeserver.py`, `cli.py`,
     `engine/crashsim.py`, `gen/interleave.py` and `walcheck.fsck`'s
@@ -925,7 +927,8 @@ def _rebuild_executions(stores: Stores, verify_on_device: bool,
             dense_bytes = 8 * NUM_LANES * sum(
                 w * e for w, e in engine.last_run_chunk_shapes)
             # no caller is handed the engine: the resident pool that
-            # verify_all has just seeded is dropped with it, here
+            # verify_all has just seeded (views of the chunks' states,
+            # not one row of them materialised) is dropped with it, here
             del engine
         report.seconds["verify"] = leg.duration_s
         report.device_verified = result.verified_on_device

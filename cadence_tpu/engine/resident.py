@@ -12,8 +12,14 @@ loops.
 ResidentStateCache is the device twin of that execution cache:
 
 - per-workflow final `ReplayState` rows stay RESIDENT in HBM between
-  calls (W=1 slices of the batched scan state, one pytree of device
-  arrays per workflow), LRU-bounded by a configurable HBM byte budget;
+  calls, LRU-bounded by a configurable HBM byte budget. A row that
+  arrives alone (a serving flush, an append's re-admit, a hydrated
+  snapshot) is pinned as a W=1 slice of the batched scan state, one
+  pytree of device arrays per workflow (`admit`). The verified rows of
+  a bulk chunk arrive together (`admit_chunk`) and are pinned as VIEWS
+  of the chunk's own state: (chunk state, row index), no program
+  launched and no device buffer made until somebody reads the row's
+  `state`; an exact hit never does;
 - entries are content-addressed by the same (workflow key, batch count,
   last-batch CRC32) scheme the pack cache uses — the shared helper in
   engine/cache.py, so the two caches can never drift on invalidation
@@ -47,7 +53,8 @@ through the pipelined bulk executor (engine/executor.py), so suffix
 packing overlaps device replay exactly like the cold path's chunks.
 
 Counters land under `tpu.resident/*` (hits, suffix-hits, misses,
-invalidations, evictions, events-appended, widened/renarrowed rows) and
+invalidations, evictions, events-appended, widened/renarrowed rows,
+view-rows, views-materialised) and
 the resident-bytes/entries/budget gauges — pre-registered on /metrics
 by ServiceHost so scrapes always expose the names.
 """
@@ -101,17 +108,77 @@ def _bucket(n: int, floor: int) -> int:
     return max(floor, 1 << (max(1, int(n)) - 1).bit_length())
 
 
-@dataclass
+def _tree_nbytes(tree) -> int:
+    """Device bytes of a pytree's leaves."""
+    return int(sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree)))
+
+
+class _ChunkPin:
+    """What the views of one bulk-verified chunk share on ONE device: the
+    chunk's [W, ...] state there (all of it under an unsharded pool, the
+    device's own rows under a sharded one), held whole, padding rows and
+    rows no longer viewed included, until its last view lets go."""
+
+    __slots__ = ("state", "nbytes", "shard", "live", "lock", "pool",
+                 "__weakref__")
+
+    def __init__(self, state, shard: int, pool) -> None:
+        self.state = state
+        self.nbytes = _tree_nbytes(state)
+        self.shard = shard
+        #: views charged to the pool's slice (guarded by the pool's lock)
+        self.live = 0
+        #: one materialisation of this chunk at a time
+        self.lock = threading.Lock()
+        self.pool = weakref.ref(pool)
+
+
+@dataclass(eq=False)
 class ResidentEntry:
     """One workflow's pinned state + the host-side row that serves exact
-    hits without touching the device."""
+    hits without touching the device. The state is either a W=1 row or,
+    for a row admitted out of a chunk, a VIEW (chunk, row index) that
+    the first read of `state` turns into the W=1 row."""
 
-    state: object            # ReplayState, W=1 device arrays
     payload: np.ndarray      # [base_width] canonical payload row
     branch: int              # device-chosen current branch
     address: ContentAddress
     rung: int                # 0 = base layout; r > 0 = widened 2**r
-    nbytes: int
+    nbytes: int              # what the entry itself counts in its slice
+    _state: object = field(default=None, repr=False)  # W=1 device arrays
+    #: a view's chunk and row, until the row is materialised (the pin's
+    #: lock); an evicted view keeps them, so a caller still holding the
+    #: entry can read its state
+    _chunk: Optional[_ChunkPin] = field(default=None, repr=False)
+    _row: int = 0
+    #: the pin this entry is counted a live view of, while it is in the
+    #: pool and not materialised (the pool's lock)
+    _charge: Optional[_ChunkPin] = field(default=None, repr=False)
+
+    @property
+    def state(self):
+        """The W=1 ReplayState. Reading it materialises a view: one
+        `slice_row` launch on the device that holds the chunk, after
+        which the entry keeps the row and lets go of the chunk."""
+        pin = self._chunk
+        if pin is not None:
+            _materialise(self, pin)
+        return self._state
+
+    @property
+    def is_view(self) -> bool:
+        return self._chunk is not None
+
+
+def _materialise(entry: ResidentEntry, pin: _ChunkPin) -> None:
+    with pin.lock:
+        if entry._chunk is None:  # another reader got there first
+            return
+        entry._state = _slice_row(pin.state, entry._row)
+        entry._chunk = None
+    pool = pin.pool()
+    if pool is not None:
+        pool._view_materialised(entry)
 
 
 @dataclass
@@ -143,7 +210,30 @@ class AppendReport:
 
 
 class ResidentStateCache:
-    """Content-addressed LRU of HBM-resident per-workflow ReplayStates."""
+    """Content-addressed LRU of HBM-resident per-workflow ReplayStates.
+
+    What an entry pins, and the budget rule. `resident_bytes` (and each
+    device's slice of it) is what the pool keeps alive, and eviction
+    holds it at or under the budget after every admission and every
+    materialisation:
+
+    - a W=1 row (`admit`, or a view once its `state` was read) counts
+      one row: its device leaves plus the host payload row;
+    - a view (`admit_chunk`) pins the WHOLE of its chunk's state on its
+      device — padding rows, rows that failed the verify, rows whose
+      views were evicted, invalidated or materialised since — so the
+      chunk counts whole, once, in that device's slice for as long as
+      one view of it is live there, and each view adds only its host
+      payload row. When the chunk's last view in the slice is evicted,
+      invalidated, replaced or materialised, the chunk's bytes leave the
+      count and the pool's last reference to the state goes with them.
+
+    Views of a chunk are admitted together, so they sit together in the
+    LRU and eviction takes them in a run; a view that was looked up
+    since sits later, and the chunk stays counted until eviction reaches
+    it too. A chunk that cannot fit its slice of the budget beside its
+    views' payload rows is admitted row by row instead.
+    """
 
     def __init__(self, layout: PayloadLayout = DEFAULT_LAYOUT,
                  budget_bytes: Optional[int] = None,
@@ -199,6 +289,7 @@ class ResidentStateCache:
             self._mesh = mesh
             if n == old_n and new_devs == old_devs:
                 return
+            self._clear_locked()
             # zero the outgoing width's per-device gauges BEFORE the
             # slices shrink: a dashboard keyed on resident-bytes-dev{d}
             # must not keep reporting phantom occupancy
@@ -260,9 +351,7 @@ class ResidentStateCache:
         cached = self._row_bytes_cache.get(layout)
         if cached is None:
             from ..ops.state import init_state
-            row = init_state(1, layout)
-            cached = int(sum(leaf.nbytes
-                             for leaf in jax.tree_util.tree_leaves(row)))
+            cached = _tree_nbytes(init_state(1, layout))
             cached += self.layout.width * 8
             self._row_bytes_cache[layout] = cached
         return cached
@@ -295,10 +384,13 @@ class ResidentStateCache:
             resident = sum(self._slice_bytes)
             widened = sum(1 for s in self._slices
                           for e in s.values() if e.rung > 0)
+            views = sum(1 for s in self._slices
+                        for e in s.values() if e._charge is not None)
             per_device = list(self._slice_bytes)
         return {
             "entries": entries,
             "widened_entries": widened,
+            "view_entries": views,
             "resident_bytes": resident,
             "mesh_shards": len(per_device),
             "per_device_bytes": per_device,
@@ -315,6 +407,10 @@ class ResidentStateCache:
                                      m.M_CACHE_EVICTIONS),
             "events_appended": reg.counter(m.SCOPE_TPU_RESIDENT,
                                            m.M_RESIDENT_EVENTS_APPENDED),
+            "view_rows": reg.counter(m.SCOPE_TPU_RESIDENT,
+                                     m.M_RESIDENT_VIEW_ROWS),
+            "views_materialised": reg.counter(
+                m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_VIEWS_MATERIALISED),
         }
 
     # -- lookup / admit / invalidate ----------------------------------------
@@ -370,7 +466,7 @@ class ResidentStateCache:
             shard = self.shard_of(key)
             entry = self._slices[shard].pop(key, None)
             if entry is not None:
-                self._slice_bytes[shard] -= entry.nbytes
+                self._uncount_locked(shard, entry)
             self._gauges_locked()
         if entry is not None:
             self._scope().inc(m.M_CACHE_INVALIDATIONS)
@@ -378,10 +474,51 @@ class ResidentStateCache:
 
     def clear(self) -> None:
         with self._lock:
-            for sl in self._slices:
-                sl.clear()
-            self._slice_bytes = [0] * len(self._slices)
+            self._clear_locked()
             self._gauges_locked()
+
+    def _clear_locked(self) -> None:
+        for sl in self._slices:
+            for entry in sl.values():
+                entry._charge = None  # a held view must not count later
+            sl.clear()
+        self._slice_bytes = [0] * len(self._slices)
+
+    def _count_locked(self, shard: int, key: tuple,
+                      entry: ResidentEntry) -> None:
+        """Put `entry` at the recent end of its slice and count it; a
+        view's chunk is counted with its first live view."""
+        sl = self._slices[shard]
+        old = sl.pop(key, None)
+        if old is not None:
+            self._uncount_locked(shard, old)
+        sl[key] = entry
+        self._slice_bytes[shard] += entry.nbytes
+        pin = entry._charge
+        if pin is not None:
+            if pin.live == 0:
+                self._slice_bytes[shard] += pin.nbytes
+            pin.live += 1
+
+    def _uncount_locked(self, shard: int, entry: ResidentEntry) -> None:
+        """Take an entry that left its slice out of the count; the chunk
+        of a view goes with its last live view."""
+        self._slice_bytes[shard] -= entry.nbytes
+        pin, entry._charge = entry._charge, None
+        if pin is not None:
+            pin.live -= 1
+            if pin.live == 0:
+                self._slice_bytes[shard] -= pin.nbytes
+
+    def _evict_locked(self, shard: int) -> int:
+        """LRU-evict the slice back under its budget; the count evicted."""
+        sl = self._slices[shard]
+        evicted = 0
+        while self._slice_bytes[shard] > self.slice_budget and len(sl) > 1:
+            _, dropped = sl.popitem(last=False)
+            self._uncount_locked(shard, dropped)
+            evicted += 1
+        return evicted
 
     def admit(self, key: tuple, address: ContentAddress, state_row,
               payload: np.ndarray, branch: int, rung: int = 0) -> bool:
@@ -400,29 +537,106 @@ class ResidentStateCache:
         device = self.device_of(key)
         if device is not None:
             state_row = jax.device_put(state_row, device)
-        entry = ResidentEntry(state=state_row,
-                              payload=np.asarray(payload, dtype=np.int64),
+        entry = ResidentEntry(payload=np.asarray(payload, dtype=np.int64),
                               branch=int(branch), address=address,
-                              rung=int(rung), nbytes=nbytes)
-        evicted = 0
+                              rung=int(rung), nbytes=nbytes,
+                              _state=state_row)
         with self._lock:
             shard = self.shard_of(key)
-            sl = self._slices[shard]
-            old = sl.pop(key, None)
-            if old is not None:
-                self._slice_bytes[shard] -= old.nbytes
-            sl[key] = entry
-            self._slice_bytes[shard] += nbytes
-            while self._slice_bytes[shard] > self.slice_budget \
-                    and len(sl) > 1:
-                _, dropped = sl.popitem(last=False)
-                self._slice_bytes[shard] -= dropped.nbytes
-                evicted += 1
+            self._count_locked(shard, key, entry)
+            evicted = self._evict_locked(shard)
             self._gauges_locked()
         if evicted:
             self.metrics.inc(m.SCOPE_TPU_RESIDENT, m.M_CACHE_EVICTIONS,
                              evicted)
         return True
+
+    def admit_chunk(self, state,
+                    rows: Sequence[Tuple[tuple, ContentAddress, int,
+                                         np.ndarray, int]]) -> int:
+        """Pin the verified rows of one bulk chunk as VIEWS of `state`,
+        the chunk's own [W, ...] device state: `rows` holds (key,
+        address, row index in `state`, canonical payload row, branch) of
+        each. No program is launched and no buffer made a row: a view's
+        W=1 row comes into being when its `state` is first read (a
+        suffix append, a snapshot write, a rebuild from it), and an
+        exact hit never reads it. One lock, one gauge update a chunk.
+
+        Under a sharded pool `state` must be laid out as the engine's
+        chunks are (parallel/mesh: W = n x P rows, rows [s P, (s+1) P)
+        on mesh position s) with each key's row on its owning device:
+        a view then pins, and later slices, its own device's rows alone.
+
+        What the views pin and count is the class's budget rule; a
+        chunk too large for it is admitted row by row, as `admit` would.
+        Returns the number of rows now resident."""
+        rows = list(rows)
+        if not rows:
+            return 0
+        n = len(self._slices)
+        parts = _device_parts(state, self._mesh) if n > 1 else [state]
+        per = jax.tree_util.tree_leaves(state)[0].shape[0] // n
+        payload_nbytes = self.layout.width * 8
+        by_shard: Dict[int, list] = {}
+        for item in rows:
+            shard = self.shard_of(item[0])
+            if not shard * per <= item[2] < (shard + 1) * per:
+                raise ValueError(
+                    f"row {item[2]} of a chunk of {n} x {per} rows does "
+                    f"not lie on shard {shard}, which owns {item[0]}")
+            by_shard.setdefault(shard, []).append(item)
+        pins = {shard: _ChunkPin(parts[shard], shard, self)
+                for shard in by_shard}
+        viewed = evicted = 0
+        alone = []
+        with self._lock:
+            for shard, group in sorted(by_shard.items()):
+                pin = pins[shard]
+                if (pin.nbytes + len(group) * payload_nbytes
+                        > min(self.slice_budget, self.budget_bytes)):
+                    alone += [(pin, item) for item in group]
+                    continue
+                for key, address, row, payload, branch in group:
+                    self._count_locked(shard, key, ResidentEntry(
+                        payload=np.asarray(payload, dtype=np.int64),
+                        branch=int(branch), address=address, rung=0,
+                        nbytes=payload_nbytes, _chunk=pin,
+                        _row=int(row) - shard * per, _charge=pin))
+                viewed += len(group)
+                evicted += self._evict_locked(shard)
+            self._gauges_locked()
+        scope = self._scope()
+        if viewed:
+            scope.inc(m.M_RESIDENT_VIEW_ROWS, viewed)
+        if evicted:
+            scope.inc(m.M_CACHE_EVICTIONS, evicted)
+        return viewed + sum(
+            self.admit(key, address,
+                       _slice_row(pin.state, row - pin.shard * per),
+                       payload, branch)
+            for pin, (key, address, row, payload, branch) in alone)
+
+    def _view_materialised(self, entry: ResidentEntry) -> None:
+        """A view's `state` was read: it is a W=1 row from here on.
+        Still in the pool, it now counts as a row and no longer as a
+        live view of its chunk; an entry evicted meanwhile counts
+        nothing."""
+        from ..ops.state import layout_of
+
+        self._scope().inc(m.M_RESIDENT_VIEWS_MATERIALISED)
+        nbytes = self._row_nbytes(layout_of(entry._state))
+        with self._lock:
+            pin = entry._charge
+            if pin is None:
+                return
+            shard = pin.shard
+            self._uncount_locked(shard, entry)
+            entry.nbytes = nbytes
+            self._slice_bytes[shard] += nbytes
+            evicted = self._evict_locked(shard)
+            self._gauges_locked()
+        if evicted:
+            self._scope().inc(m.M_CACHE_EVICTIONS, evicted)
 
     # -- device helpers -----------------------------------------------------
 
@@ -706,6 +920,25 @@ def _stack_padded(rows, width: int, device=None):
             filler = jax.device_put(filler, device)
         rows += [filler] * (width - len(rows))
     return _stack_states(rows)
+
+
+def _device_parts(state, mesh) -> list:
+    """A chunk's [W, ...] state as one local state a mesh position: each
+    leaf's own buffer on that device, rows [s W/n, (s+1) W/n). A leaf
+    the compiler laid out otherwise is re-placed first; the others are
+    shared, not copied, so dropping one part frees that device's rows
+    whatever becomes of the rest."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from ..parallel.mesh import SHARD_AXIS
+
+    state = jax.device_put(
+        state, NamedSharding(mesh, PartitionSpec(SHARD_AXIS)))
+    leaves, treedef = jax.tree_util.tree_flatten(state)
+    local = [{s.device: s.data for s in leaf.addressable_shards}
+             for leaf in leaves]
+    return [treedef.unflatten([by_dev[device] for by_dev in local])
+            for device in mesh.devices.flat]
 
 
 _SLICE_FN = None

@@ -537,7 +537,9 @@ class TPUReplayEngine:
         zero device work, an appended history replays ONLY the new
         batches against the resident state (O(new events) per
         transaction). Cold misses run the full chunked path below and
-        seed the cache from their verified final states.
+        seed the cache from their verified final states, a chunk at a
+        time and as views of the chunk's state (resident.admit_chunk): a
+        row costs a launch only when its state is first read.
 
         Capacity-flagged rows (pending-table / version-history / branch
         overflow) escalate through the widened-K ladder: their rung-1
@@ -553,7 +555,8 @@ class TPUReplayEngine:
         pool consulted), `verify.pack` (a chunk's encode and expected
         rows, on a pack thread), `verify.replay` (launch to results on
         the host) with a chunk's `verify.seed-resident` (verified rows
-        pinned into the pool) inside it, and `verify.compare`."""
+        pinned into the pool, one call a chunk) inside it, and
+        `verify.compare`."""
         if keys is None:
             keys = self.stores.execution.list_executions()
         all_keys = list(keys)
@@ -654,20 +657,19 @@ class TPUReplayEngine:
                     gather_subcorpus(corpus, cap_rows)))
             # seed the resident cache from this chunk's verified-clean
             # rows: the device row equals the shipped expected row
-            # whenever the mismatch bit is clear, so admission costs one
-            # state-row slice per key and zero extra readback (the cache
-            # re-places the row on the key's owning device). The state
-            # reference is dropped here (the ring keeps O(depth) alive).
+            # whenever the mismatch bit is clear, so the pool is handed
+            # the chunk's state ONCE and pins the rows as views of it
+            # (resident.admit_chunk): no launch, no readback and no
+            # device buffer a row until somebody reads that row's state.
+            # The state reference is dropped here (the ring keeps
+            # O(depth) alive); the views keep the state.
             with tracing.span("verify.seed-resident"):
-                for j, i in enumerate(plan.idx):
-                    key = keys[i]
-                    r = int(plan.rows[j])
-                    if (errors[r] == 0 and not mismatch[r]
-                            and key in addresses):
-                        self.resident.admit(
-                            key, addresses[key],
-                            self.resident.extract_row(state, r),
-                            expected[r], int(exp_branch[r]))
+                self.resident.admit_chunk(state, [
+                    (keys[i], addresses[keys[i]], r, expected[r],
+                     int(exp_branch[r]))
+                    for i, r in zip(plan.idx, plan.rows.tolist())
+                    if errors[r] == 0 and not mismatch[r]
+                    and keys[i] in addresses])
             return mismatch, errors, expected, exp_branch
 
         plans_by_ci = self._plan_chunks(keys)

@@ -226,7 +226,9 @@ class ServiceHost(socketserver.ThreadingTCPServer):
                        cm.M_CACHE_MISSES, cm.M_CACHE_EVICTIONS,
                        cm.M_CACHE_INVALIDATIONS,
                        cm.M_RESIDENT_EVENTS_APPENDED,
-                       cm.M_RESIDENT_WIDENED, cm.M_RESIDENT_NARROWED):
+                       cm.M_RESIDENT_WIDENED, cm.M_RESIDENT_NARROWED,
+                       cm.M_RESIDENT_VIEW_ROWS,
+                       cm.M_RESIDENT_VIEWS_MATERIALISED):
             self.metrics.inc(cm.SCOPE_TPU_RESIDENT, metric, 0)
         for gauge in (cm.M_RESIDENT_BYTES, cm.M_RESIDENT_ENTRIES,
                       cm.M_RESIDENT_BUDGET_BYTES):

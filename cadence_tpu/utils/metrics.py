@@ -220,7 +220,9 @@ M_CACHE_SUFFIX_PACKS = "suffix-packs"
 #: device work, suffix hits replay only appended batches against the
 #: HBM-resident state, invalidations count stale entries dropped on tail
 #: overwrite / reset / NDC branch switch; the resident-bytes gauge is
-#: the cache's HBM footprint against its configured budget
+#: the cache's HBM footprint against its configured budget; view-rows
+#: counts rows a bulk chunk seeded as views of its own state (no launch,
+#: no buffer), views-materialised the views whose W=1 row was then read
 M_CACHE_INVALIDATIONS = "invalidations"
 M_RESIDENT_SUFFIX_HITS = "suffix-hits"
 M_RESIDENT_BYTES = "resident-bytes"
@@ -229,6 +231,8 @@ M_RESIDENT_BUDGET_BYTES = "budget-bytes"
 M_RESIDENT_EVENTS_APPENDED = "events-appended"
 M_RESIDENT_WIDENED = "widened-rows"
 M_RESIDENT_NARROWED = "renarrowed-rows"
+M_RESIDENT_VIEW_ROWS = "view-rows"
+M_RESIDENT_VIEWS_MATERIALISED = "views-materialised"
 #: capacity-escalation ladder counters (engine/ladder.py,
 #: SCOPE_TPU_FALLBACK): rows entering the ladder, rows re-replayed at
 #: each rung (metric name ladder_rung_rows(r)), rows resolved on device,

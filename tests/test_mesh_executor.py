@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from cadence_tpu.core.checksum import STICKY_ROW_INDEX, payload_row
 from cadence_tpu.engine.executor import replay_corpus_mesh, stream_wirec_mesh
 from cadence_tpu.engine.persistence import Stores
 from cadence_tpu.engine.tpu_engine import TPUReplayEngine
@@ -281,6 +282,154 @@ class TestEngineMeshVerify:
         # rebinding to a different width drops entries (placement moved)
         cache.set_mesh(make_mesh(jax.devices()[:2]))
         assert cache.n_shards == 2 and len(cache) == 0
+
+
+def _sharded_chunk(mesh, hists):
+    """One verify chunk as the engine lays it out over `mesh`: P rows a
+    device, each key's row in its owning device's slice, the rest
+    padding. Returns (state, payload rows, branches, keys, row of key)."""
+    from cadence_tpu.ops.encode import (
+        LANE_EVENT_TYPE,
+        NUM_LANES,
+        assemble_corpus,
+        encode_batches_resumable,
+    )
+    from cadence_tpu.ops.payload import payload_rows
+    from cadence_tpu.ops.replay import replay_events
+    from cadence_tpu.parallel.mesh import place_corpus
+
+    n = int(mesh.devices.size)
+    keys = [("d", f"w{i}", "r") for i in range(len(hists))]
+    buckets = [[i for i, k in enumerate(keys) if workflow_shard(k, n) == s]
+               for s in range(n)]
+    per = max(len(b) for b in buckets) + 1      # a padding row a device
+    row_of = {i: s * per + j for s, b in enumerate(buckets)
+              for j, i in enumerate(b)}
+    rows_list = [encode_batches_resumable(h)[0] for h in hists]
+    E = max(r.shape[0] for r in rows_list)
+    corpus = np.zeros((n * per, E, NUM_LANES), dtype=np.int64)
+    corpus[:, :, LANE_EVENT_TYPE] = -1
+    corpus[[row_of[i] for i in range(len(hists))]] = \
+        assemble_corpus(rows_list, E)
+    state = replay_events(place_corpus(corpus, mesh))
+    assert (np.asarray(state.error) == 0).all()
+    return (state, np.asarray(payload_rows(state)),
+            np.asarray(state.current_branch), keys, row_of)
+
+
+@pytest.mark.parametrize("how", ["views", "rows"])
+def test_sharded_pool_seeded_by_a_chunk_of_views(how):
+    """The sharded pool's case of tests/test_resident.py TestChunkViews:
+    a view pins its OWN device's rows of the chunk, that part counts
+    whole in the device's slice while a view of it is live there, and
+    the row it materialises lies on the key's owning device."""
+    import gc
+    import weakref
+
+    from cadence_tpu.core.checksum import DEFAULT_LAYOUT
+    from cadence_tpu.engine.cache import content_address
+    from cadence_tpu.engine.resident import ResidentStateCache
+
+    mesh = make_mesh(jax.devices()[:2])
+    hists = generate_corpus("basic", num_workflows=10, seed=11,
+                            target_events=24)
+    prefix = [h[:-1] for h in hists]
+    state, rows, branch, keys, row_of = _sharded_chunk(mesh, prefix)
+    cache = ResidentStateCache(mesh=mesh)
+    items = [(keys[i], content_address(prefix[i]), row_of[i],
+              rows[row_of[i]], int(branch[row_of[i]]))
+             for i in range(len(keys))]
+    if how == "views":
+        assert cache.admit_chunk(state, items) == len(keys)
+    else:
+        for key, address, r, payload, br in items:
+            assert cache.admit(key, address, cache.extract_row(state, r),
+                               payload, br)
+    owner = {k: workflow_shard(k, 2) for k in keys}
+    count = [sum(1 for k in keys if owner[k] == s) for s in range(2)]
+    assert min(count) >= 1
+    row_nbytes = cache._row_nbytes(DEFAULT_LAYOUT)
+    payload_nbytes = DEFAULT_LAYOUT.width * 8
+    part_nbytes = sum(leaf.nbytes for leaf in
+                      jax.tree_util.tree_leaves(state)) // 2
+
+    def per_device():
+        return cache.stats()["per_device_bytes"]
+
+    if how == "views":
+        assert per_device() == [part_nbytes + c * payload_nbytes
+                                for c in count]
+    else:
+        assert per_device() == [c * row_nbytes for c in count]
+    reg = cache.metrics
+    for s in range(2):
+        assert reg.gauge_value(
+            m.SCOPE_TPU_RESIDENT,
+            m.device_metric(m.M_RESIDENT_BYTES, s)) == per_device()[s]
+
+    # the same lookups, and no state read by any of them
+    for k, h in zip(keys, hists):
+        assert cache.lookup(k, h[:-1])[0] == "exact"
+        assert cache.lookup(k, h)[0] == "suffix"
+    assert reg.counter(m.SCOPE_TPU_RESIDENT,
+                       m.M_RESIDENT_VIEWS_MATERIALISED) == 0
+
+    # device 0's part goes with device 0's last view, whatever device 1
+    # still views
+    part0 = weakref.ref(next(
+        sh.data for sh in jax.tree_util.tree_leaves(state)[0]
+        .addressable_shards if sh.device == mesh.devices.flat[0]))
+    del state
+    gc.collect()
+    if how == "views":
+        assert part0() is not None
+    on0 = [k for k in keys if owner[k] == 0]
+    for k in on0:
+        leaf = jax.tree_util.tree_leaves(cache.entry_for(k).state)[0]
+        assert leaf.devices() == {mesh.devices.flat[0]}
+    gc.collect()
+    assert part0() is None
+    assert per_device()[0] == count[0] * row_nbytes
+    if how == "views":
+        assert per_device()[1] == part_nbytes + count[1] * payload_nbytes
+        assert cache.stats()["view_entries"] == count[1]
+        assert reg.counter(m.SCOPE_TPU_RESIDENT,
+                           m.M_RESIDENT_VIEWS_MATERIALISED) == count[0]
+
+    # a suffix append from the views left gives the row's append, on
+    # the owning device
+    on1 = [i for i, k in enumerate(keys) if owner[k] == 1]
+    results = cache.replay_append(
+        [(keys[i], cache.lookup(keys[i], hists[i])[1], hists[i])
+         for i in on1])
+    for i, res in zip(on1, results):
+        assert res.ok
+        oracle = payload_row(StateBuilder().replay_history(hists[i]))
+        oracle[STICKY_ROW_INDEX] = 0
+        assert (res.payload == oracle).all()
+        kind, entry = cache.lookup(keys[i], hists[i])
+        assert kind == "exact" and not entry.is_view
+        leaf = jax.tree_util.tree_leaves(entry.state)[0]
+        assert leaf.devices() == {mesh.devices.flat[1]}
+    assert per_device() == [c * row_nbytes for c in count]
+    assert cache.resident_bytes <= cache.budget_bytes
+
+
+def test_a_view_must_lie_on_its_keys_owning_device():
+    from cadence_tpu.engine.cache import content_address
+    from cadence_tpu.engine.resident import ResidentStateCache
+
+    mesh = make_mesh(jax.devices()[:2])
+    hists = generate_corpus("basic", num_workflows=4, seed=13,
+                            target_events=20)
+    state, rows, branch, keys, row_of = _sharded_chunk(mesh, hists)
+    cache = ResidentStateCache(mesh=mesh)
+    per = rows.shape[0] // 2
+    wrong = (row_of[0] + per) % (2 * per)    # the other device's slice
+    with pytest.raises(ValueError, match="does not lie on shard"):
+        cache.admit_chunk(state, [(keys[0], content_address(hists[0]),
+                                   wrong, rows[wrong], 0)])
+    assert len(cache) == 0 and cache.resident_bytes == 0
 
 
 def _feeder_case():

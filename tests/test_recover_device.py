@@ -10,7 +10,8 @@ backend at a small size.
 - the counters under `tpu.recover/*` and the report's `events` and
   `seconds` against what the log holds;
 - a recovery leaves the log's bytes alone, and a second cold one gives the
-  same states.
+  same states;
+- the verify pins every run as a view of its chunk's state and slices none.
 
 The cell `recover.wal-1chip` times this path on the chip.
 """
@@ -180,6 +181,39 @@ def test_a_call_lays_one_span_a_leg_and_the_legs_cover_it(wal):
     legs = sum(report.seconds[leg] for leg in TOP_LEGS)
     assert 0.98 * report.seconds["call"] <= legs <= report.seconds["call"]
     assert report.seconds["upsert"] < report.seconds["rebuild"]
+
+
+def test_the_verify_seeds_its_pool_with_views_and_slices_no_row(
+        wal, monkeypatch):
+    """`verify_all` hands the pool each chunk once: every run is pinned
+    as a view of the chunk's state, and a recovery, which reads none of
+    them, launches no `slice_row` program at all."""
+    from cadence_tpu.engine import resident
+
+    path, histories = wal
+    sliced = []
+    slice_row = resident._slice_row
+    monkeypatch.setattr(
+        resident, "_slice_row",
+        lambda state, index: sliced.append(index) or slice_row(state, index))
+    stores, report = _recover(path)
+    assert report.device_verified == RUNS and report.ok
+
+    def counter(name):
+        return m.DEFAULT_REGISTRY.counter(m.SCOPE_TPU_RESIDENT, name)
+
+    assert counter(m.M_CACHE_MISSES) == RUNS
+    assert counter(m.M_RESIDENT_VIEW_ROWS) == RUNS
+    assert counter(m.M_RESIDENT_VIEWS_MATERIALISED) == 0
+    assert sliced == []
+    assert m.DEFAULT_REGISTRY.gauge_value(
+        m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_ENTRIES) == RUNS
+    # the states are what the oracle alone recovers from the same log
+    oracle, _report = recover_stores(path, verify_on_device=False,
+                                     rebuild_on_device=False)
+    oracle.wal.close()
+    for one, two in zip(_rows(stores, histories), _rows(oracle, histories)):
+        assert (one == two).all()
 
 
 def test_counters_and_the_report_agree_with_the_log(wal):

@@ -340,6 +340,359 @@ class TestResidentCacheUnit:
 
 
 # ---------------------------------------------------------------------------
+# a bulk chunk's verified rows pinned as VIEWS of the chunk's state
+# (admit_chunk) against the same rows pinned one by one (admit)
+# ---------------------------------------------------------------------------
+
+
+def _seed_from_chunk(cache, keys, prefix_hists, how, rows_of=None):
+    """Pin rows `rows_of` (default: all) of ONE replayed chunk: as views
+    of its state or, the reference, sliced and admitted row by row.
+    Returns the chunk's state."""
+    s, rows = _replay_full(prefix_hists)
+    branch = np.asarray(s.current_branch)
+    assert (np.asarray(s.error) == 0).all()
+    picked = list(range(len(keys))) if rows_of is None else list(rows_of)
+    if how == "views":
+        assert cache.admit_chunk(s, [
+            (keys[i], content_address(prefix_hists[i]), i, rows[i],
+             int(branch[i])) for i in picked]) == len(picked)
+    else:
+        for i in picked:
+            assert cache.admit(keys[i], content_address(prefix_hists[i]),
+                               cache.extract_row(s, i), rows[i],
+                               int(branch[i]))
+    return s
+
+
+def _view_counters(cache):
+    reg = cache.metrics
+    return (reg.counter(m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_VIEW_ROWS),
+            reg.counter(m.SCOPE_TPU_RESIDENT,
+                        m.M_RESIDENT_VIEWS_MATERIALISED))
+
+
+def _state_nbytes(state):
+    import jax
+
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(state))
+
+
+def _counted(cache):
+    """The pool's byte count made again from its entries and the chunks
+    their live views pin: the stated budget rule."""
+    total = 0
+    for sl in cache._slices:
+        pins = {id(e._charge): e._charge for e in sl.values()
+                if e._charge is not None}
+        total += sum(e.nbytes for e in sl.values())
+        total += sum(pin.nbytes for pin in pins.values())
+    return total
+
+
+@pytest.mark.parametrize("how", ["views", "rows"])
+class TestChunkViews:
+    N = 6
+
+    def _case(self, suite="basic", seed=29, **kw):
+        kw.setdefault("ladder", EscalationLadder(DEFAULT_LAYOUT))
+        cache = ResidentStateCache(DEFAULT_LAYOUT, **kw)
+        hists = generate_corpus(suite, num_workflows=self.N, seed=seed,
+                                target_events=24)
+        keys = [("d", f"w{i}", "r") for i in range(self.N)]
+        return cache, keys, hists
+
+    def test_lookups_serve_what_rows_serve(self, how):
+        cache, keys, hists = self._case()
+        _seed_from_chunk(cache, keys, [h[:-1] for h in hists], how)
+        assert len(cache) == self.N
+        for k, h in zip(keys[:3], hists):
+            kind, entry = cache.lookup(k, h[:-1])
+            assert kind == "exact"
+            assert (entry.payload == _oracle_row(h[:-1])).all()
+            assert entry.address == content_address(h[:-1])
+            assert entry.rung == 0
+        assert cache.lookup(keys[3], hists[3])[0] == "suffix"
+        mutated = list(hists[4][:-2]) + [hists[4][-1]]
+        assert cache.lookup(keys[4], mutated) is None      # stale: dropped
+        assert cache.lookup(keys[4], hists[4][:-1]) is None
+        assert cache.lookup(keys[5], hists[5][:1],
+                            authoritative=False) is None   # prefix: kept
+        assert cache.lookup(keys[5], hists[5][:-1])[0] == "exact"
+        reg = cache.metrics
+        assert reg.counter(m.SCOPE_TPU_RESIDENT, m.M_CACHE_HITS) == 4
+        assert reg.counter(m.SCOPE_TPU_RESIDENT,
+                           m.M_CACHE_INVALIDATIONS) == 1
+        # an exact hit, a suffix LOOKUP and an invalidation read no state
+        assert _view_counters(cache) == (
+            (self.N, 0) if how == "views" else (0, 0))
+        stats = cache.stats()
+        assert stats["view_rows"] == (self.N if how == "views" else 0)
+        assert stats["view_entries"] == (self.N - 1 if how == "views" else 0)
+        assert stats["views_materialised"] == 0
+
+    def test_suffix_append_from_a_view_is_a_rows_append(self, how):
+        cache, keys, hists = self._case("concurrent_child", seed=31)
+        _seed_from_chunk(cache, keys, [h[:-1] for h in hists], how)
+        appended = [0, 2, 3]
+        items = [(keys[i], cache.lookup(keys[i], hists[i])[1], hists[i])
+                 for i in appended]
+        results = cache.replay_append(items)
+        row_nbytes = cache._row_nbytes(DEFAULT_LAYOUT)
+        for i, res in zip(appended, results):
+            assert res.ok and not res.escalated and res.rung == 0
+            assert (res.payload == _oracle_row(hists[i])).all()
+            oracle = StateBuilder().replay_history(hists[i])
+            assert res.branch == oracle.version_histories.current_index
+            kind, entry = cache.lookup(keys[i], hists[i])
+            assert kind == "exact"          # re-admitted at the new address
+            assert not entry.is_view and entry.nbytes == row_nbytes
+            assert (entry.payload == res.payload).all()
+        # only the rows appended to were materialised; the rest still view
+        assert _view_counters(cache) == (
+            (self.N, len(appended)) if how == "views" else (0, 0))
+        assert cache.stats()["view_entries"] == (
+            self.N - len(appended) if how == "views" else 0)
+        assert cache.resident_bytes == _counted(cache)
+
+    def test_bytes_follow_the_stated_rule(self, how):
+        """A chunk counts whole, once, while a view of it is live (the
+        rows nobody admitted included); a view adds its payload row; a
+        materialised row counts as a row."""
+        cache, keys, hists = self._case()
+        admitted = [0, 1, 2, 4]   # rows 3 and 5 are pinned but not viewed
+        state = _seed_from_chunk(cache, keys, [h[:-1] for h in hists], how,
+                                 rows_of=admitted)
+        row_nbytes = cache._row_nbytes(DEFAULT_LAYOUT)
+        payload_nbytes = DEFAULT_LAYOUT.width * 8
+        chunk_nbytes = _state_nbytes(state)
+        assert chunk_nbytes == self.N * (row_nbytes - payload_nbytes)
+        k = len(admitted)
+
+        def holds(expected):
+            assert cache.resident_bytes == expected == _counted(cache)
+            assert cache.stats()["resident_bytes"] == expected
+            assert cache.metrics.gauge_value(
+                m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_BYTES) == expected
+            assert expected <= cache.budget_bytes
+
+        if how == "rows":
+            holds(k * row_nbytes)
+            return
+        holds(chunk_nbytes + k * payload_nbytes)
+        # one row read: it counts as a row, the chunk still whole
+        first = cache.entry_for(keys[0])
+        assert first.is_view
+        assert _state_nbytes(first.state) == row_nbytes - payload_nbytes
+        assert not first.is_view and first.nbytes == row_nbytes
+        holds(chunk_nbytes + (k - 1) * payload_nbytes + row_nbytes)
+        # one view invalidated: its payload row leaves, the chunk stays
+        assert cache.invalidate(keys[1])
+        holds(chunk_nbytes + (k - 2) * payload_nbytes + row_nbytes)
+        # the last views read: the chunk leaves the count
+        for i in (2, 4):
+            cache.entry_for(keys[i]).state
+        holds((k - 1) * row_nbytes)
+        assert _view_counters(cache) == (k, 3)
+
+    def test_a_chunk_over_its_budget_is_admitted_row_by_row(self, how):
+        probe = ResidentStateCache(DEFAULT_LAYOUT)
+        row_nbytes = probe._row_nbytes(DEFAULT_LAYOUT)
+        cache, keys, hists = self._case(budget_bytes=3 * row_nbytes + 1)
+        _seed_from_chunk(cache, keys, [h[:-1] for h in hists], how)
+        # six rows of state do not fit three rows of budget: no view
+        assert len(cache) == 3
+        assert cache.resident_bytes == 3 * row_nbytes <= cache.budget_bytes
+        assert _view_counters(cache) == (0, 0)
+        assert cache.metrics.counter(m.SCOPE_TPU_RESIDENT,
+                                     m.M_CACHE_EVICTIONS) == 3
+        assert cache.lookup(keys[0], hists[0][:-1]) is None
+        assert cache.lookup(keys[5], hists[5][:-1])[0] == "exact"
+
+    def test_materialising_evicts_rather_than_pass_the_budget(self, how):
+        probe = ResidentStateCache(DEFAULT_LAYOUT)
+        row_nbytes = probe._row_nbytes(DEFAULT_LAYOUT)
+        payload_nbytes = DEFAULT_LAYOUT.width * 8
+        # the chunk and its six payload rows fit with one row to spare
+        budget = self.N * row_nbytes + (row_nbytes - payload_nbytes)
+        cache, keys, hists = self._case(budget_bytes=budget)
+        _seed_from_chunk(cache, keys, [h[:-1] for h in hists], how)
+        assert len(cache) == self.N
+        for i in (5, 4):
+            entry = cache.entry_for(keys[i])
+            assert (np.asarray(entry.state.error) == 0).all()
+            assert cache.resident_bytes <= budget
+            assert cache.resident_bytes == _counted(cache)
+        if how == "views":
+            # the first row read filled the budget; the second pushed the
+            # four cold views out in a run, and the chunk went with the
+            # last of them
+            assert sorted(cache.keys()) == sorted([keys[4], keys[5]])
+            assert cache.resident_bytes == 2 * row_nbytes
+            assert cache.metrics.counter(m.SCOPE_TPU_RESIDENT,
+                                         m.M_CACHE_EVICTIONS) == 4
+        else:
+            assert len(cache) == self.N
+
+
+@pytest.mark.parametrize("how", ["views", "rows"])
+def test_a_sweep_over_views_writes_the_blobs_rows_write(how):
+    """The snapshot writer reads `entry.state`: over a pool of views it
+    persists, key for key, the packed W=1 slice it persisted before."""
+    from cadence_tpu.engine.persistence import Stores
+    from cadence_tpu.engine.snapshot import pack_state_row
+    from cadence_tpu.engine.tpu_engine import TPUReplayEngine
+
+    hists = generate_corpus("timer_retry", num_workflows=4, seed=47,
+                            target_events=24)
+    stores = Stores()
+    keys = []
+    for h in hists:
+        key = (h[0].domain_id, h[0].workflow_id, h[0].run_id)
+        for b in h:
+            stores.history.append_batch(*key, list(b.events))
+        stores.execution.upsert_workflow(StateBuilder().replay_history(h))
+        keys.append(key)
+    tpu = TPUReplayEngine(stores)
+    if how == "views":
+        assert tpu.verify_all().ok      # seeds the pool a chunk at a time
+        assert tpu.resident.stats()["view_entries"] == len(keys)
+    else:
+        _seed_from_chunk(tpu.resident, keys, hists, "rows")
+    full, _rows = _replay_full(hists)
+    assert tpu.snapshot_sweep(force=True).written == len(keys)
+    for i, key in enumerate(keys):
+        assert stores.snapshot.get(key).state_blob == pack_state_row(
+            tpu.resident.extract_row(full, i)), key
+    assert _view_counters(tpu.resident) == (
+        (len(keys), len(keys)) if how == "views" else (0, 0))
+    assert tpu.resident.stats()["view_entries"] == 0
+
+
+@pytest.mark.parametrize("gone", ["evicted", "invalidated", "materialised",
+                                  "cleared", "replaced"])
+def test_a_chunk_is_collectable_once_its_last_view_is_gone(gone):
+    import gc
+    import weakref
+
+    import jax
+
+    cache = ResidentStateCache(DEFAULT_LAYOUT,
+                               ladder=EscalationLadder(DEFAULT_LAYOUT))
+    hists = generate_corpus("basic", num_workflows=4, seed=37,
+                            target_events=20)
+    keys = [("d", f"w{i}", "r") for i in range(4)]
+    prefix = [h[:-1] for h in hists]
+    state = _seed_from_chunk(cache, keys, prefix, "views")
+    chunk_nbytes = _state_nbytes(state)
+    leaf = weakref.ref(jax.tree_util.tree_leaves(state)[0])
+    del state
+    gc.collect()
+    assert leaf() is not None            # the views hold the chunk
+    if gone == "evicted":
+        # a second chunk that fits only alone: the first one's views leave
+        # the LRU in a run
+        cache.budget_bytes = chunk_nbytes + 4 * DEFAULT_LAYOUT.width * 8 + 8
+        other = [("d", f"x{i}", "r") for i in range(4)]
+        _seed_from_chunk(cache, other, prefix, "views")
+        assert sorted(cache.keys()) == sorted(other)
+        assert cache.metrics.counter(m.SCOPE_TPU_RESIDENT,
+                                     m.M_CACHE_EVICTIONS) == 4
+        assert cache.resident_bytes <= cache.budget_bytes
+    for n, key in enumerate(keys):
+        if gone != "evicted":
+            assert leaf() is not None, n  # one live view is enough
+        if gone == "invalidated":
+            assert cache.invalidate(key)
+        elif gone == "materialised":
+            cache.entry_for(key).state
+        elif gone == "replaced":
+            s1, rows1 = _replay_full([prefix[n]])
+            cache.admit(key, content_address(prefix[n]),
+                        cache.extract_row(s1, 0), rows1[0], 0)
+    if gone == "cleared":
+        cache.clear()
+    gc.collect()
+    assert leaf() is None
+    assert cache.resident_bytes == _counted(cache)
+    assert cache.stats()["view_entries"] == (4 if gone == "evicted" else 0)
+
+
+def test_an_evicted_view_still_held_by_a_caller_reads_its_state():
+    """A suffix item looked up before a concurrent admission evicted it
+    keeps its chunk: the state is there, and the pool counts nothing."""
+    cache = ResidentStateCache(DEFAULT_LAYOUT)
+    hists = generate_corpus("basic", num_workflows=2, seed=41,
+                            target_events=20)
+    keys = [("d", f"w{i}", "r") for i in range(2)]
+    _seed_from_chunk(cache, keys, hists, "views")
+    held = cache.entry_for(keys[0])
+    cache.clear()
+    assert held.is_view and cache.resident_bytes == 0
+    assert (np.asarray(held.state.error) == 0).all()
+    assert cache.resident_bytes == 0 and len(cache) == 0
+    assert _view_counters(cache) == (2, 1)
+
+
+def test_views_under_readers_and_invalidations_at_once():
+    """Eight threads read every view's state while one invalidates and
+    re-admits: each view is sliced once, every reader sees the row the
+    eager slice gives, and the count is the rule's at the end."""
+    import sys
+    import threading
+
+    n = 12
+    cache = ResidentStateCache(DEFAULT_LAYOUT)
+    hists = generate_corpus("basic", num_workflows=n, seed=43,
+                            target_events=20)
+    keys = [("d", f"w{i}", "r") for i in range(n)]
+    state = _seed_from_chunk(cache, keys, hists, "views")
+    expected = [np.asarray(cache.extract_row(state, i).next_event_id)
+                for i in range(n)]
+    entries = [cache.entry_for(k) for k in keys]
+    failures = []
+
+    def read(order):
+        try:
+            for i in order:
+                got = np.asarray(entries[i].state.next_event_id)
+                if not (got == expected[i]).all():
+                    failures.append(("row", i))
+        except Exception as exc:  # a reader must never raise
+            failures.append(("raised", repr(exc)))
+
+    def churn():
+        try:
+            for i in range(0, n, 3):
+                cache.invalidate(keys[i])
+                s1, rows1 = _replay_full([hists[i]])
+                cache.admit(keys[i], content_address(hists[i]),
+                            cache.extract_row(s1, 0), rows1[0], 0)
+        except Exception as exc:
+            failures.append(("churn", repr(exc)))
+
+    rng = random.Random(5)
+    threads = [threading.Thread(target=read,
+                                args=(rng.sample(range(n), n),))
+               for _ in range(8)] + [threading.Thread(target=churn)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert failures == []
+    assert _view_counters(cache) == (n, n)      # once each, never twice
+    assert len(cache) == n and cache.stats()["view_entries"] == 0
+    assert cache.resident_bytes == _counted(cache) \
+        == n * cache._row_nbytes(DEFAULT_LAYOUT)
+
+
+# ---------------------------------------------------------------------------
 # capacity escalation: widen on overflowing append, stay resident,
 # re-narrow once the load drains
 # ---------------------------------------------------------------------------
@@ -705,6 +1058,9 @@ class TestMetricsSurface:
             cluster.stop()
         assert 'cadence_invalidations_total{scope="tpu.resident"} 0' in text
         assert 'cadence_suffix_hits_total{scope="tpu.resident"} 0' in text
+        assert 'cadence_view_rows_total{scope="tpu.resident"} 0' in text
+        assert 'cadence_views_materialised_total{scope="tpu.resident"} 0' \
+            in text
         assert 'cadence_resident_bytes{scope="tpu.resident"} 0' in text
         assert 'cadence_budget_bytes{scope="tpu.resident"} 0' in text
 
