@@ -571,6 +571,16 @@ class RecoveryReport:
     #: runs whose rebuild hydrated a persisted snapshot and replayed
     #: only the since-snapshot suffix (the warm-restart counter)
     snapshot_hydrated: int = 0
+    #: `snap` records the log replay installed (the latest a run wins),
+    #: and runs whose VERIFY hydrated one into its own pool
+    snapshot_records: int = 0
+    verify_hydrated: int = 0
+    #: how each device pass ("rebuild", "verify") served the runs a
+    #: resident pool held: exact hits (no replay), suffix hits (only the
+    #: since-snapshot batches replayed) and the events of those suffixes
+    exact_rows: Dict[str, int] = field(default_factory=dict)
+    suffix_rows: Dict[str, int] = field(default_factory=dict)
+    suffix_events: Dict[str, int] = field(default_factory=dict)
     device_verified: int = 0
     oracle_fallback: int = 0
     divergent: List[Tuple[str, str, str]] = field(default_factory=list)
@@ -643,10 +653,12 @@ def recover_stores(path: str, verify_on_device: bool = True,
         with tracing.span("recover.log-replay") as leg:
             stores = Stores()
             stores.recovered_config = []
-            referenced_runs, original, events = _replay_log(path, stores)
+            referenced_runs, original, events, snaps = _replay_log(
+                path, stores)
         report = _rebuild_executions(stores, verify_on_device, layout,
                                      referenced_runs, rebuild_on_device)
         report.events = events
+        report.snapshot_records = snaps
         report.seconds["log-replay"] = leg.duration_s
         with tracing.span("recover.reconcile") as leg:
             _reconcile_current_pointers(stores)
@@ -669,12 +681,13 @@ def recover_stores(path: str, verify_on_device: bool = True,
     return stores, report
 
 
-def _replay_log(path: str, stores: Stores) -> Tuple[set, int, int]:
+def _replay_log(path: str, stores: Stores) -> Tuple[set, int, int, int]:
     """Step 1 of `recover_stores`: the log read, lifted to the current
     schema and every record put back into `stores`, in file order. Returns
     the runs a current-run record ever referenced, the log's original
-    schema version and the events of the history batches appended; what it
-    read is counted under `tpu.recover/*`."""
+    schema version, the events of the history batches appended and the
+    `snap` records installed; what it read is counted under
+    `tpu.recover/*`."""
     #: every run a current-run record EVER referenced (not just the final
     #: pointer): a run with history but no reference is an orphan tail of
     #: a start that died before its create_workflow commit point
@@ -682,7 +695,7 @@ def _replay_log(path: str, stores: Stores) -> Tuple[set, int, int]:
     # schema gate + in-memory migration (the setup/update-schema contract):
     # older logs lift transparently; NEWER logs refuse
     records, original = migrate_records(read_log(path))
-    n_batches = n_events = n_bytes = 0
+    n_batches = n_events = n_bytes = n_snaps = n_snap_bytes = 0
     for rec in records:
         t = rec["t"]
         if t == "d":
@@ -735,9 +748,12 @@ def _replay_log(path: str, stores: Stores) -> Tuple[set, int, int]:
             # uses. A malformed body is ignored (that run simply cold
             # starts); hydration re-validates blob CRC + layout anyway.
             try:
-                stores.snapshot.restore(snapshot_from_record(rec))
+                snap = snapshot_from_record(rec)
             except Exception:
-                pass
+                continue
+            stores.snapshot.restore(snap)
+            n_snaps += 1
+            n_snap_bytes += snap.nbytes
         elif t == "cfg":
             stores.recovered_config.append(
                 (rec["k"], rec["v"], rec.get("dom")))
@@ -785,7 +801,9 @@ def _replay_log(path: str, stores: Stores) -> Tuple[set, int, int]:
     scope.inc(m.M_RECOVER_HISTORY_BATCHES, n_batches)
     scope.inc(m.M_RECOVER_HISTORY_EVENTS, n_events)
     scope.inc(m.M_RECOVER_HISTORY_BYTES, n_bytes)
-    return referenced_runs, original, n_events
+    scope.inc(m.M_RECOVER_SNAPSHOT_RECORDS, n_snaps)
+    scope.inc(m.M_RECOVER_SNAPSHOT_BYTES, n_snap_bytes)
+    return referenced_runs, original, n_events, n_snaps
 
 
 def _reconcile_current_pointers(stores: Stores) -> None:
@@ -854,10 +872,17 @@ def _rebuild_executions(stores: Stores, verify_on_device: bool,
         report.device_rebuilt = rebuilder.stats.device
         report.rebuild_fallback = rebuilder.stats.oracle_fallback
         report.snapshot_hydrated = rebuilder.stats.snapshot_seeded
+        report.exact_rows["rebuild"] = rebuilder.stats.exact_rows
+        report.suffix_rows["rebuild"] = rebuilder.stats.suffix_rows
+        report.suffix_events["rebuild"] = rebuilder.stats.suffix_events
         scope = m.DEFAULT_REGISTRY.scope(m.SCOPE_TPU_RECOVER)
         scope.inc(m.M_RECOVER_REBUILD_EVENTS, rebuilder.stats.events)
         scope.inc(m.M_RECOVER_REBUILD_CHUNKS, rebuilder.stats.chunks)
         scope.inc(m.M_RECOVER_DENSE_BYTES, rebuilder.stats.dense_bytes)
+        # the rebuilder's own pool (a warm restart's hydrated rows) is of
+        # no use past the states it returned: dropped here, not held
+        # through the verify's second pool
+        del rebuilder
 
         with tracing.span("recover.upsert") as upsert:
             for key, ms in zip(keys, states):
@@ -934,8 +959,19 @@ def _rebuild_executions(stores: Stores, verify_on_device: bool,
         report.device_verified = result.verified_on_device
         report.oracle_fallback = len(result.fallback)
         report.divergent = result.divergent
+        report.verify_hydrated = len(result.snapshot)
+        report.exact_rows["verify"] = result.exact_rows
+        report.suffix_rows["verify"] = result.suffix_rows
+        report.suffix_events["verify"] = result.suffix_events
         scope.inc(m.M_RECOVER_ROWS_VERIFIED, result.verified_on_device)
+        scope.inc(m.M_RECOVER_VERIFY_EVENTS, result.replayed_events)
         scope.inc(m.M_RECOVER_DENSE_BYTES, dense_bytes)
+    # the warm restart, both device passes together
+    scope.inc(m.M_RECOVER_RUNS_HYDRATED,
+              report.snapshot_hydrated + report.verify_hydrated)
+    scope.inc(m.M_RECOVER_EXACT_ROWS, sum(report.exact_rows.values()))
+    scope.inc(m.M_RECOVER_SUFFIX_ROWS, sum(report.suffix_rows.values()))
+    scope.inc(m.M_RECOVER_SUFFIX_EVENTS, sum(report.suffix_events.values()))
     return report
 
 

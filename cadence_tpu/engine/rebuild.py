@@ -64,6 +64,12 @@ class RebuildStats:
     #: (engine/snapshot.py) — the warm-restart path: hydrate + replay
     #: only the since-snapshot suffix, never the full history
     snapshot_seeded: int = 0
+    #: how the resident prepass served its jobs: exact hits (no replay),
+    #: suffix hits (only the appended batches replayed) and the events
+    #: those appends replayed
+    exact_rows: int = 0
+    suffix_rows: int = 0
+    suffix_events: int = 0
     #: what the full-replay path handed to the device: launches, the real
     #: events in them and the dense int64 bytes shipped (padding included)
     chunks: int = 0
@@ -77,6 +83,9 @@ class RebuildStats:
         self.ladder += other.ladder
         self.resident += other.resident
         self.snapshot_seeded += other.snapshot_seeded
+        self.exact_rows += other.exact_rows
+        self.suffix_rows += other.suffix_rows
+        self.suffix_events += other.suffix_events
         self.chunks += other.chunks
         self.events += other.events
         self.dense_bytes += other.dense_bytes
@@ -173,11 +182,13 @@ class DeviceRebuilder:
         wrong (ADVICE r3).
 
         Each leg of a device call is one span, never one a job:
-        `rebuild.snapshot-consult`, `rebuild.resident-prepass`,
-        `rebuild.encode` (a chunk's `encode_corpus`, on a pack thread),
-        `rebuild.replay` (launch to rows on the host), `rebuild.hydrate`
-        (rows to MutableStates) and, where rows were capacity-flagged,
-        `rebuild.ladder`."""
+        `rebuild.snapshot-consult`, `rebuild.resident-prepass` (inside it,
+        where the pool serves jobs, `rebuild.suffix-replay`: the appended
+        batches of its suffix hits, and a `rebuild.hydrate` of the rows
+        it resolved), `rebuild.encode` (a chunk's `encode_corpus`, on a
+        pack thread), `rebuild.replay` (launch to rows on the host),
+        `rebuild.hydrate` (rows to MutableStates) and, where rows were
+        capacity-flagged, `rebuild.ladder`."""
         if not on_device:
             from ..utils import metrics as m
             self.stats.oracle_fallback += len(jobs)
@@ -423,11 +434,15 @@ class DeviceRebuilder:
             else:
                 suffix_items.append((key, rentry, batches))
                 suffix_jobs.append((pos, batches, entry))
+        self.stats.exact_rows += len(resolved)
         if suffix_items:
-            outcomes = cache.replay_append(
-                suffix_items,
-                encode_suffix=(self.pack_cache.encode_suffix
-                               if self.pack_cache is not None else None))
+            with tracing.span("rebuild.suffix-replay"):
+                outcomes, appended = cache.replay_append_report(
+                    suffix_items,
+                    encode_suffix=(self.pack_cache.encode_suffix
+                                   if self.pack_cache is not None else None))
+            self.stats.suffix_rows += len(suffix_items)
+            self.stats.suffix_events += appended.events_appended
             for (pos, batches, entry), (key, _r, _b), res in zip(
                     suffix_jobs, suffix_items, outcomes):
                 if not res.ok:
@@ -435,7 +450,11 @@ class DeviceRebuilder:
                 hit2 = cache.lookup(key, batches, authoritative=False)
                 if hit2 is not None and hit2[0] == "exact":
                     resolved.append((pos, key, batches, entry, hit2[1]))
-        pre = self._hydrate_resolved(resolved)
+        if not resolved:
+            return {}
+        # rows to MutableStates: the name the full-replay path gives it
+        with tracing.span("rebuild.hydrate"):
+            pre = self._hydrate_resolved(resolved)
         if pre:
             self.stats.device += len(pre)
             self.stats.resident += len(pre)

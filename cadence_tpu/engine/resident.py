@@ -903,6 +903,14 @@ def _stack_states(states):
     return _STACK_FN(states)
 
 
+#: the widest launch state ONE `stack` program builds: a serving flush at
+#: its default `max_batch`. The program takes rows x 66 leaves as
+#: operands and the TPU's compiler pays for them super-linearly (AOT for
+#: a v5e: 28 s at 64 rows, 90 s at 128, 308 s at 256, so hours at the
+#: 2,048 rows of an append chunk: PERF.md, PR 36)
+STACK_BLOCK = 64
+
+
 def _stack_padded(rows, width: int, device=None):
     """Stack k W=1 state rows into one [width, ...] launch state, the
     tail filled with initial-state rows (their corpus rows carry no
@@ -910,7 +918,11 @@ def _stack_padded(rows, width: int, device=None):
     the jitted stack then takes `width` operands whatever k is — one
     program per flush width, not one per ROW COUNT, and the TPU's
     compiler pays for each super-linearly in its operand count (a cold
-    host's boot warm-up; PERF.md, PR 22)."""
+    host's boot warm-up; PERF.md, PR 22). A launch wider than
+    STACK_BLOCK (a warm restart's append chunk: the power of two over
+    its rows, up to `chunk_workflows`) is stacked STACK_BLOCK rows at a
+    time by that one program and the blocks joined by a second of
+    width / STACK_BLOCK operands a leaf."""
     from ..ops.state import init_state, layout_of
 
     rows = list(rows)
@@ -919,7 +931,10 @@ def _stack_padded(rows, width: int, device=None):
         if device is not None:
             filler = jax.device_put(filler, device)
         rows += [filler] * (width - len(rows))
-    return _stack_states(rows)
+    if len(rows) <= STACK_BLOCK:
+        return _stack_states(rows)
+    return _stack_states([_stack_states(rows[lo:lo + STACK_BLOCK])
+                          for lo in range(0, len(rows), STACK_BLOCK)])
 
 
 def _device_parts(state, mesh) -> list:

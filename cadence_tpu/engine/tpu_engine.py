@@ -102,6 +102,13 @@ class BulkVerifyResult:
     #: snapshot during this verify (engine/snapshot.py): the cold
     #: partition became a suffix partition for these keys
     snapshot: List[Tuple[str, str, str]] = field(default_factory=list)
+    #: how `resident` splits: exact hits, suffix hits and the events the
+    #: suffix hits' appends replayed; beside them the events the
+    #: full-replay path replayed for the cold keys
+    exact_rows: int = 0
+    suffix_rows: int = 0
+    suffix_events: int = 0
+    replayed_events: int = 0
 
     @property
     def ok(self) -> bool:
@@ -158,6 +165,8 @@ class TPUReplayEngine:
         #: the bounded-footprint contract (a long-tail history inflates
         #: only its own chunk's E)
         self.last_run_chunk_shapes: List[Tuple[int, int]] = []
+        #: the real events those chunks held
+        self.last_run_events = 0
         #: lazy device-serving scheduler (engine/serving.py); created on
         #: first request so engines that never serve pay nothing
         self._serving = None
@@ -423,6 +432,7 @@ class TPUReplayEngine:
                 len(plans), pack, launch, consume,
                 escalate if escalate_fn is not None else None)
         self.last_run_chunk_shapes = [s for s in shapes if s is not None]
+        self.last_run_events = sum(events)
         t = self.metrics.timer(m.SCOPE_TPU_REPLAY, m.M_LATENCY)
         if t.total_s > 0:
             self.metrics.gauge(
@@ -500,22 +510,32 @@ class TPUReplayEngine:
         hydrated: List[Tuple[str, str, str]] = []
         snapshots = getattr(self.stores, "snapshot", None)
         hs = self.stores.history
+        looked: list = []  # (key, batches, hit), in key order
         for key in keys:
             if (hs.branch_count(*key) > 1
                     or hs.get_current_branch(*key) != 0):
                 self.resident.invalidate(key)  # NDC branch switch
-                cold.append(key)
+                looked.append((key, None, None))
                 continue
             batches = hs.as_history_batches(*key)
-            hit = self.resident.lookup(key, batches)
-            if hit is None and snapshot_mod.seed_from_batches(
-                    snapshots, self.resident, self.pack_cache, key,
-                    batches, self.layout, self.metrics):
+            looked.append((key, batches, self.resident.lookup(key, batches)))
+        # one span a call, not one a key: every miss with a valid record
+        # hydrates it into the pool and is looked up again
+        with tracing.span("verify.snapshot-consult"):
+            for n, (key, batches, hit) in enumerate(looked):
+                if batches is None or hit is not None \
+                        or not snapshot_mod.seed_from_batches(
+                            snapshots, self.resident, self.pack_cache, key,
+                            batches, self.layout, self.metrics):
+                    continue
                 hit = self.resident.lookup(key, batches)
                 if hit is not None:
                     hydrated.append(key)
+                    looked[n] = (key, batches, hit)
+        for key, batches, hit in looked:
             if hit is None:
-                addresses[key] = content_address(batches)
+                if batches is not None:
+                    addresses[key] = content_address(batches)
                 cold.append(key)
             elif hit[0] == "exact":
                 exact.append((key, hit[1]))
@@ -552,7 +572,10 @@ class TPUReplayEngine:
         oracle.
 
         Each leg of a call is one span: `verify.partition` (the resident
-        pool consulted), `verify.pack` (a chunk's encode and expected
+        pool consulted; inside it `verify.snapshot-consult`, the misses'
+        persisted records hydrated into the pool), `verify.suffix-replay`
+        (the suffix hits' appended batches, where there are any),
+        `verify.pack` (a chunk's encode and expected
         rows, on a pack thread), `verify.replay` (launch to results on
         the host) with a chunk's `verify.seed-resident` (verified rows
         pinned into the pool, one call a chunk) inside it, and
@@ -575,6 +598,7 @@ class TPUReplayEngine:
         else:
             exact, suffix, keys, addresses = [], [], all_keys, {}
 
+        result.exact_rows = len(exact)
         for key, entry in exact:
             row, br = self._expected_row(key)
             result.verified_on_device += 1
@@ -583,8 +607,11 @@ class TPUReplayEngine:
                 result.divergent.append(key)
 
         if suffix:
-            outcomes = self.resident.replay_append(
-                suffix, encode_suffix=self.pack_cache.encode_suffix)
+            with tracing.span("verify.suffix-replay"):
+                outcomes, appended = self.resident.replay_append_report(
+                    suffix, encode_suffix=self.pack_cache.encode_suffix)
+            result.suffix_rows = len(suffix)
+            result.suffix_events = appended.events_appended
             for (key, _entry, batches), res in zip(suffix, outcomes):
                 row, br = self._expected_row(key)
                 if not res.ok:
@@ -679,6 +706,7 @@ class TPUReplayEngine:
             results, plans = self._run_chunks(keys, pack_extra, launch,
                                               readback, escalate,
                                               plans=plans_by_ci)
+        result.replayed_events = self.last_run_events
         with tracing.span("verify.compare"):
             self._settle(result, keys, plans, results, pending)
         return result

@@ -385,7 +385,12 @@ M_VIS_ATTR_REPLACEMENTS = "attr-column-replacements"
 #: blobs before base64: what any recovery must read once); the runs, events
 #: and chunks the device rebuild was handed; the dense int64 bytes both
 #: device passes shipped (rebuild + verify); the rows the verify held
-#: against the rebuilt states on the device
+#: against the rebuilt states on the device and the events its full
+#: replay took for them. A warm restart (a log with `snap` records): the
+#: records the log replay installed and their decoded bytes (state blob +
+#: payload row); then, summed over both device passes, the runs hydrated
+#: from a record, the rows served as exact and as suffix hits of a
+#: resident pool, and the events those suffixes replayed
 M_RECOVER_LOG_RECORDS = "log-records"
 M_RECOVER_LOG_BYTES = "log-bytes"
 M_RECOVER_HISTORY_BATCHES = "history-batches"
@@ -396,6 +401,13 @@ M_RECOVER_REBUILD_EVENTS = "events-rebuilt"
 M_RECOVER_REBUILD_CHUNKS = "chunks-rebuilt"
 M_RECOVER_DENSE_BYTES = "dense-bytes"
 M_RECOVER_ROWS_VERIFIED = "rows-verified"
+M_RECOVER_VERIFY_EVENTS = "events-verified"
+M_RECOVER_SNAPSHOT_RECORDS = "snapshot-records"
+M_RECOVER_SNAPSHOT_BYTES = "snapshot-bytes"
+M_RECOVER_RUNS_HYDRATED = "runs-hydrated"
+M_RECOVER_EXACT_ROWS = "exact-rows"
+M_RECOVER_SUFFIX_ROWS = "suffix-rows"
+M_RECOVER_SUFFIX_EVENTS = "suffix-events"
 
 
 def recover_records(record_type: str) -> str:

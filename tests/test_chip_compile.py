@@ -236,6 +236,24 @@ def test_serving_stack_at_max_batch(one_chip):
         [_shapes(row, one_chip)] * DEFAULT_BATCH).compile())
 
 
+def test_warm_restart_stack_at_an_append_chunk(one_chip):
+    """A warm restart's append launch: `chunk_workflows` W=1 rows, 2,048
+    at the default, stacked STACK_BLOCK at a time by the serving flush's
+    program (the test above compiles it) and the blocks joined by one more
+    program of 32 operands a leaf. One program over all 2,048 rows x 66
+    leaves takes this compiler hours (28 s at 64 rows, 308 s at 256,
+    1,061 s at 512: PERF.md, PR 36)."""
+    from cadence_tpu.engine import resident
+    from cadence_tpu.ops.state import init_state
+
+    blocks = resident.DEFAULT_CHUNK // resident.STACK_BLOCK
+    assert resident.STACK_BLOCK == 64 and blocks == 32
+    block = _shapes(init_state(resident.STACK_BLOCK, DEFAULT_LAYOUT),
+                    one_chip)
+    resident._stack_padded([init_state(1, DEFAULT_LAYOUT)], 8)  # builds it
+    _fits(resident._STACK_FN.lower([block] * blocks).compile())
+
+
 @pytest.mark.parametrize("n", [1, 4])
 def test_fused_generator_kernel(topo, n):
     """ops/genkernel's shard_map kernel (generate + replay + CRC in one

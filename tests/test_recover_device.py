@@ -55,6 +55,7 @@ PARENT = {
     "rebuild.replay": "recover.rebuild",
     "rebuild.hydrate": "recover.rebuild",
     "verify.partition": "recover.verify",
+    "verify.snapshot-consult": "verify.partition",
     "verify.replay": "recover.verify",
     "verify.seed-resident": "verify.replay",
     "verify.compare": "recover.verify",
@@ -245,6 +246,15 @@ def test_counters_and_the_report_agree_with_the_log(wal):
     assert counter(m.M_RECOVER_REBUILD_EVENTS) == events
     assert counter(m.M_RECOVER_REBUILD_CHUNKS) == 1
     assert counter(m.M_RECOVER_ROWS_VERIFIED) == RUNS
+    assert counter(m.M_RECOVER_VERIFY_EVENTS) == events
+    # a log with no `snap` record: nothing of the warm restart counts
+    for name in (m.M_RECOVER_SNAPSHOT_RECORDS, m.M_RECOVER_SNAPSHOT_BYTES,
+                 m.M_RECOVER_RUNS_HYDRATED, m.M_RECOVER_EXACT_ROWS,
+                 m.M_RECOVER_SUFFIX_ROWS, m.M_RECOVER_SUFFIX_EVENTS):
+        assert counter(name) == 0, name
+    assert report.snapshot_records == report.verify_hydrated == 0
+    assert report.exact_rows == report.suffix_rows == \
+        report.suffix_events == {"rebuild": 0, "verify": 0}
     # dense int64 lanes: the rebuild's chunk at its longest history, the
     # verify's at the power of two above it (both longer than 16 events)
     longest = max(sum(len(b.events) for b in h) for h in histories)
