@@ -54,7 +54,7 @@ packing overlaps device replay exactly like the cold path's chunks.
 
 Counters land under `tpu.resident/*` (hits, suffix-hits, misses,
 invalidations, evictions, events-appended, widened/renarrowed rows,
-view-rows, views-materialised) and
+view-rows, views-materialised, host-stacked-rows) and
 the resident-bytes/entries/budget gauges — pre-registered on /metrics
 by ServiceHost so scrapes always expose the names.
 """
@@ -411,6 +411,8 @@ class ResidentStateCache:
                                      m.M_RESIDENT_VIEW_ROWS),
             "views_materialised": reg.counter(
                 m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_VIEWS_MATERIALISED),
+            "host_stacked_rows": reg.counter(
+                m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_HOST_STACKED_ROWS),
         }
 
     # -- lookup / admit / invalidate ----------------------------------------
@@ -748,7 +750,7 @@ class ResidentStateCache:
         def launch(ci, corpus):
             lo, hi = spans[ci]
             s0 = _stack_padded([items[i][1].state for i in idxs[lo:hi]],
-                               corpus.shape[0], device)
+                               corpus.shape[0], device, scope)
             report.chunk_shapes.append(
                 (corpus.shape[0], corpus.shape[1]))
             events = int((corpus[:, :, 0] > 0).sum())  # LANE_EVENT_ID
@@ -911,7 +913,20 @@ def _stack_states(states):
 STACK_BLOCK = 64
 
 
-def _stack_padded(rows, width: int, device=None):
+def _host_leaves(rows) -> Optional[List[list]]:
+    """Each row's leaves where every leaf of every row is a numpy array
+    (rows hydrated from snapshot records), else None: stops at the first
+    leaf on a device."""
+    out = []
+    for row in rows:
+        leaves = jax.tree_util.tree_leaves(row)
+        if not all(isinstance(leaf, np.ndarray) for leaf in leaves):
+            return None
+        out.append(leaves)
+    return out
+
+
+def _stack_padded(rows, width: int, device=None, scope=None):
     """Stack k W=1 state rows into one [width, ...] launch state, the
     tail filled with initial-state rows (their corpus rows carry no
     events). The filler must be W=1 rows, not one [width - k] block:
@@ -922,10 +937,30 @@ def _stack_padded(rows, width: int, device=None):
     STACK_BLOCK (a warm restart's append chunk: the power of two over
     its rows, up to `chunk_workflows`) is stacked STACK_BLOCK rows at a
     time by that one program and the blocks joined by a second of
-    width / STACK_BLOCK operands a leaf."""
+    width / STACK_BLOCK operands a leaf.
+
+    Rows whose leaves all live on the host (hydrated from snapshot
+    records) are stacked there instead and the launch state put on the
+    device once, one transfer a leaf: handed to the jitted stack, each of
+    their width x 66 leaves would be a host-to-device copy of its own
+    (84 us apiece on a v5e: PERF.md, PR 36). `scope` counts their rows."""
     from ..ops.state import init_state, layout_of
+    from .snapshot import _row_template
 
     rows = list(rows)
+    host = _host_leaves(rows) if rows else None
+    if host is not None:
+        treedef, _fields, _total, fill = _row_template(layout_of(rows[0]))
+        k = len(rows)
+        leaves = []
+        for i, pad in enumerate(fill):
+            leaf = np.empty((width,) + pad.shape[1:], pad.dtype)
+            np.concatenate([r[i] for r in host], axis=0, out=leaf[:k])
+            leaf[k:] = pad
+            leaves.append(leaf)
+        if scope is not None:
+            scope.inc(m.M_RESIDENT_HOST_STACKED_ROWS, k)
+        return jax.device_put(treedef.unflatten(leaves), device)
     if len(rows) < width:
         filler = init_state(1, layout_of(rows[0]))
         if device is not None:

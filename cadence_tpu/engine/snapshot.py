@@ -110,7 +110,7 @@ def pack_state_row(state_row) -> bytes:
     import jax
 
     from ..ops.state import layout_of
-    _treedef, fields, _total = _row_template(layout_of(state_row))
+    _treedef, fields, _total, _leaves = _row_template(layout_of(state_row))
     leaves = [np.asarray(l) for l in
               jax.tree_util.tree_leaves(jax.device_get(state_row))]
     parts = [_BLOB_MAGIC]
@@ -125,10 +125,11 @@ class SnapshotFormatError(Exception):
 
 
 #: layout signature -> (treedef, [(shape, dtype, count, offset) per
-#: leaf], total blob bytes) — the W=1 ReplayState template spec, built
-#: ONCE per layout: constructing a fresh init_state (or recomputing
-#: per-leaf sizes) per unpack would cost per-key overhead exactly where
-#: a warm restart earns its keep
+#: leaf], total blob bytes, the initial state's leaves on the host) — the
+#: W=1 ReplayState template spec, built ONCE per layout: constructing a
+#: fresh init_state (or recomputing per-leaf sizes) per unpack would cost
+#: per-key overhead exactly where a warm restart earns its keep; the
+#: leaves are the filler rows of a launch stacked on the host
 _TEMPLATE_SPECS: Dict[tuple, tuple] = {}
 _TEMPLATE_LOCK = threading.Lock()
 
@@ -140,14 +141,14 @@ def _row_template(layout: PayloadLayout):
         import jax
 
         from ..ops.state import init_state
-        leaves, treedef = jax.tree_util.tree_flatten(init_state(1, layout))
+        leaves, treedef = jax.tree_util.tree_flatten(
+            jax.device_get(init_state(1, layout)))
         fields = []
         off = len(_BLOB_MAGIC)
-        for l in leaves:
-            a = np.asarray(l)
+        for a in leaves:
             fields.append((a.shape, a.dtype, int(a.size), off))
             off += a.nbytes
-        spec = (treedef, fields, off)
+        spec = (treedef, fields, off, leaves)
         with _TEMPLATE_LOCK:
             _TEMPLATE_SPECS[key] = spec
     return spec
@@ -158,13 +159,13 @@ def unpack_state_row(blob: bytes, layout: PayloadLayout):
     byte length are validated against the layout's template spec, so a
     truncated, doctored, or foreign-layout blob raises
     SnapshotFormatError instead of producing a silently-wrong state.
-    Leaves are zero-copy frombuffer views that stay host-side — the
-    resident pool's stack/replay launches move them to the device
-    lazily, in one batched transfer instead of ~60 per-leaf puts per
-    workflow."""
+    Leaves are zero-copy frombuffer views that stay host-side: an
+    append launch over such rows stacks them on the host and puts the
+    launch state on the device once a leaf (`resident._stack_padded`),
+    not ~66 puts a workflow."""
     import jax
 
-    treedef, fields, total = _row_template(layout)
+    treedef, fields, total, _leaves = _row_template(layout)
     if not blob.startswith(_BLOB_MAGIC):
         raise SnapshotFormatError("bad state-blob magic")
     if len(blob) != total:

@@ -222,7 +222,10 @@ M_CACHE_SUFFIX_PACKS = "suffix-packs"
 #: overwrite / reset / NDC branch switch; the resident-bytes gauge is
 #: the cache's HBM footprint against its configured budget; view-rows
 #: counts rows a bulk chunk seeded as views of its own state (no launch,
-#: no buffer), views-materialised the views whose W=1 row was then read
+#: no buffer), views-materialised the views whose W=1 row was then read;
+#: host-stacked-rows the real rows of each append launch state built on
+#: the host (rows hydrated from snapshot records) and put on the device
+#: once a leaf
 M_CACHE_INVALIDATIONS = "invalidations"
 M_RESIDENT_SUFFIX_HITS = "suffix-hits"
 M_RESIDENT_BYTES = "resident-bytes"
@@ -232,6 +235,7 @@ M_RESIDENT_EVENTS_APPENDED = "events-appended"
 M_RESIDENT_WIDENED = "widened-rows"
 M_RESIDENT_NARROWED = "renarrowed-rows"
 M_RESIDENT_VIEW_ROWS = "view-rows"
+M_RESIDENT_HOST_STACKED_ROWS = "host-stacked-rows"
 M_RESIDENT_VIEWS_MATERIALISED = "views-materialised"
 #: capacity-escalation ladder counters (engine/ladder.py,
 #: SCOPE_TPU_FALLBACK): rows entering the ladder, rows re-replayed at

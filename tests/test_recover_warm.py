@@ -20,7 +20,9 @@ in 0..31, seeded); one run in six starts after the sweep and has no record.
 - a record that is stale (its run's tail rewritten after the sweep, or its
   address doctored in the log) or torn is passed over, counted, and the run
   still equals the oracle;
-- an append chunk wider than one `stack` program is stacked in blocks.
+- an append chunk wider than one `stack` program is stacked in blocks;
+  one of rows hydrated from records is stacked on the host and put on the
+  device once a leaf, and every suffix row of both passes goes that way.
 
 The cell `recover.wal-snap-1chip` times this path on the chip.
 """
@@ -392,31 +394,98 @@ def test_the_rebuilders_pool_is_gone_before_the_verify(wal, monkeypatch):
     assert alive == [False] and len(pools) == 2
 
 
-@pytest.mark.parametrize("rows, width", [(3, 8), (64, 64), (70, 128),
-                                         (130, 256)])
-def test_a_launch_wider_than_one_stack_program_is_stacked_in_blocks(
-        rows, width, monkeypatch):
+def _rows(k: int, on_device):
+    """k distinct W=1 rows: host arrays, or put on the device where
+    `on_device(j)` says so."""
     import jax
 
     from cadence_tpu.ops.state import init_state
 
+    base = init_state(1, resident.DEFAULT_LAYOUT)
+    rows = [jax.tree_util.tree_map(
+        lambda a, j=j: (np.asarray(a) + j).astype(a.dtype), base)
+        for j in range(k)]
+    return [jax.device_put(r) if on_device(j) else r
+            for j, r in enumerate(rows)], base
+
+
+def _assert_stacked(stacked, rows, filler, width):
+    import jax
+
+    for leaf, *parts in zip(jax.tree_util.tree_leaves(stacked),
+                            *map(jax.tree_util.tree_leaves,
+                                 rows + [filler] * (width - len(rows)))):
+        assert leaf.shape[0] == width
+        want = np.concatenate([np.asarray(p) for p in parts], axis=0)
+        assert np.asarray(leaf).dtype == want.dtype
+        assert (np.asarray(leaf) == want).all()
+
+
+@pytest.mark.parametrize("rows, width, kind", [
+    (3, 8, "device"), (64, 64, "device"), (70, 128, "device"),
+    (130, 256, "device"), (3, 8, "mixed"), (70, 128, "mixed")])
+def test_a_launch_wider_than_one_stack_program_is_stacked_in_blocks(
+        rows, width, kind, monkeypatch):
+    """Device rows (the serving tier's, `_readmit`'s), and a launch that
+    mixes host and device rows, take the jitted stack."""
     operands = []
     stack_states = resident._stack_states
     monkeypatch.setattr(
         resident, "_stack_states",
         lambda states: operands.append(len(states)) or stack_states(states))
-    base = init_state(1, resident.DEFAULT_LAYOUT)
-    states = [jax.tree_util.tree_map(lambda a, k=k: np.asarray(a) + k, base)
-              for k in range(rows)]
-    stacked = resident._stack_padded(states, width)
-    filler = [base] * (width - rows)
-    for leaf, *parts in zip(jax.tree_util.tree_leaves(stacked),
-                            *map(jax.tree_util.tree_leaves,
-                                 states + filler)):
-        assert leaf.shape[0] == width
-        assert (np.asarray(leaf) == np.concatenate(parts, axis=0)).all()
+    states, base = _rows(rows, (lambda j: True) if kind == "device"
+                         else (lambda j: j % 2 == 1))
+    registry = m.MetricsRegistry()
+    stacked = resident._stack_padded(
+        states, width, scope=registry.scope(m.SCOPE_TPU_RESIDENT))
+    _assert_stacked(stacked, states, base, width)
+    assert registry.counter(
+        m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_HOST_STACKED_ROWS) == 0
     # one program up to STACK_BLOCK rows, as a serving flush has it; past
     # that, blocks of STACK_BLOCK and one join of the blocks
     blocks = width // resident.STACK_BLOCK
     assert operands == ([width] if width <= resident.STACK_BLOCK
                         else [resident.STACK_BLOCK] * blocks + [blocks])
+
+
+@pytest.mark.parametrize("rows, width", [(3, 8), (64, 64), (70, 128),
+                                         (130, 256), (1556, 2048)])
+def test_a_launch_of_host_rows_is_stacked_on_the_host(rows, width,
+                                                      monkeypatch):
+    """Rows hydrated from records hold host leaves: the launch state is
+    built on the host, the filler a host initial-state row, and put on the
+    device once a leaf; no stack program runs."""
+    import jax
+
+    monkeypatch.setattr(resident, "_stack_states", lambda states: pytest.fail(
+        "a launch of host rows ran the jitted stack"))
+    states, base = _rows(rows, lambda j: False)
+    filler = jax.device_get(base)
+    for device in (None, jax.devices()[-1]):
+        registry = m.MetricsRegistry()
+        stacked = resident._stack_padded(
+            states, width, device, registry.scope(m.SCOPE_TPU_RESIDENT))
+        _assert_stacked(stacked, states, filler, width)
+        for leaf in jax.tree_util.tree_leaves(stacked):
+            assert isinstance(leaf, jax.Array)
+            if device is not None:
+                assert leaf.devices() == {device}
+        assert registry.counter(
+            m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_HOST_STACKED_ROWS) == rows
+
+
+@pytest.mark.parametrize("snapshots", ["1", "0"])
+def test_the_suffix_rows_of_both_passes_are_stacked_on_the_host(
+        wal, snapshots, monkeypatch):
+    """A warm recovery stacks every suffix row of both passes on the host
+    (the rows hydrated from records); a cold one stacks none."""
+    path, histories, _cuts, _sweep = wal
+    monkeypatch.setenv(snapshot_mod.ENABLE_ENV, snapshots)
+    stores, report = _recover(path)
+    assert report.ok
+    assert _crcs(stores, histories) == _oracle_crcs(histories)
+    stacked = m.DEFAULT_REGISTRY.counter(m.SCOPE_TPU_RESIDENT,
+                                         m.M_RESIDENT_HOST_STACKED_ROWS)
+    suffix = sum(report.suffix_rows.values())
+    assert stacked == suffix
+    assert (suffix > 0) == (snapshots == "1")

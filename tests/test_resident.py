@@ -1061,6 +1061,8 @@ class TestMetricsSurface:
         assert 'cadence_view_rows_total{scope="tpu.resident"} 0' in text
         assert 'cadence_views_materialised_total{scope="tpu.resident"} 0' \
             in text
+        assert 'cadence_host_stacked_rows_total{scope="tpu.resident"} 0' \
+            in text
         assert 'cadence_resident_bytes{scope="tpu.resident"} 0' in text
         assert 'cadence_budget_bytes{scope="tpu.resident"} 0' in text
 
