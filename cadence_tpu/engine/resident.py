@@ -54,9 +54,21 @@ packing overlaps device replay exactly like the cold path's chunks.
 
 Counters land under `tpu.resident/*` (hits, suffix-hits, misses,
 invalidations, evictions, events-appended, widened/renarrowed rows,
-view-rows, views-materialised, host-stacked-rows) and
-the resident-bytes/entries/budget gauges — pre-registered on /metrics
-by ServiceHost so scrapes always expose the names.
+view-rows, views-materialised, host-stacked-rows, row-slices: one a
+W=1 `slice_row` launch the pool makes, in `extract_row` and in a view's
+first read) and the resident-bytes/entries/budget gauges —
+pre-registered on /metrics by ServiceHost so scrapes always expose the
+names.
+
+An append's legs are spans, each ONCE A CHUNK and under a fixed name:
+`resident.launch` (the launch state stacked, the suffix lanes put on the
+device, the from-state scan dispatched), `resident.device-wait` (the
+blocking wait and readback) and `resident.readmit` (the chunk's
+successful rows sliced, narrowed where they may be, re-pinned; its
+error rows invalidated; rows the ladder escalates are not in it). No
+span a row, and no prefix argument: the caller's span (`serving.flush`,
+`rebuild.suffix-replay`, `verify.suffix-replay`) says which path ran
+them.
 """
 from __future__ import annotations
 
@@ -264,7 +276,6 @@ class ResidentStateCache:
             OrderedDict() for _ in range(n)]
         self._slice_bytes: List[int] = [0] * n
         self._row_bytes_cache: Dict[PayloadLayout, int] = {}
-        self.last_append = AppendReport()
         _LIVE.add(self)
         self._gauges()
 
@@ -413,6 +424,8 @@ class ResidentStateCache:
                 m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_VIEWS_MATERIALISED),
             "host_stacked_rows": reg.counter(
                 m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_HOST_STACKED_ROWS),
+            "row_slices": reg.counter(m.SCOPE_TPU_RESIDENT,
+                                      m.M_RESIDENT_ROW_SLICES),
         }
 
     # -- lookup / admit / invalidate ----------------------------------------
@@ -614,7 +627,7 @@ class ResidentStateCache:
             scope.inc(m.M_CACHE_EVICTIONS, evicted)
         return viewed + sum(
             self.admit(key, address,
-                       _slice_row(pin.state, row - pin.shard * per),
+                       self.extract_row(pin.state, row - pin.shard * per),
                        payload, branch)
             for pin, (key, address, row, payload, branch) in alone)
 
@@ -625,7 +638,9 @@ class ResidentStateCache:
         nothing."""
         from ..ops.state import layout_of
 
-        self._scope().inc(m.M_RESIDENT_VIEWS_MATERIALISED)
+        scope = self._scope()
+        scope.inc(m.M_RESIDENT_VIEWS_MATERIALISED)
+        scope.inc(m.M_RESIDENT_ROW_SLICES)
         nbytes = self._row_nbytes(layout_of(entry._state))
         with self._lock:
             pin = entry._charge
@@ -642,10 +657,11 @@ class ResidentStateCache:
 
     # -- device helpers -----------------------------------------------------
 
-    @staticmethod
-    def extract_row(state, index: int):
+    def extract_row(self, state, index: int):
         """W=1 device slice of row `index` from a batched ReplayState
-        (one dynamic-slice launch per leaf; jit-cached per shape)."""
+        (one dynamic-slice launch per leaf; jit-cached per shape),
+        counted under `row-slices`."""
+        self._scope().inc(m.M_RESIDENT_ROW_SLICES)
         return _slice_row(state, index)
 
     # -- the append transaction ---------------------------------------------
@@ -687,15 +703,13 @@ class ResidentStateCache:
                              encode_suffix: Optional[Callable] = None,
                              address_of: Callable = content_address
                              ) -> Tuple[List[AppendResult], AppendReport]:
-        """`replay_append` plus THIS call's AppendReport. The report is a
-        per-call object (also published as `last_append` for the
-        observability probes) so a concurrent append on the shared cache
-        can never swap the numbers out from under the caller."""
+        """`replay_append` plus THIS call's AppendReport: a per-call
+        object, so a concurrent append on the shared cache can never
+        swap the numbers out from under the caller."""
         if encode_suffix is None:
             encode_suffix = _encode_suffix_cold
         results: List[Optional[AppendResult]] = [None] * len(items)
         report = AppendReport(transactions=len(items))
-        self.last_append = report
         # group by (rung, owning shard): states in one launch must share
         # a layout, and under a sharded pool the from-state replay (plus
         # any ladder widen it escalates into) runs on the device that
@@ -749,20 +763,23 @@ class ResidentStateCache:
 
         def launch(ci, corpus):
             lo, hi = spans[ci]
-            s0 = _stack_padded([items[i][1].state for i in idxs[lo:hi]],
-                               corpus.shape[0], device, scope)
-            report.chunk_shapes.append(
-                (corpus.shape[0], corpus.shape[1]))
-            events = int((corpus[:, :, 0] > 0).sum())  # LANE_EVENT_ID
-            report.events_appended += events
-            scope.inc(m.M_RESIDENT_EVENTS_APPENDED, events)
-            # the suffix lanes ship to the OWNING device: the group's
-            # resident states already live there, so the whole
-            # from-state append is device-local
-            corpus_dev = (jax.device_put(corpus, device)
-                          if device is not None
-                          else jax.device_put(jnp.asarray(corpus)))
-            outs = replay_from_state_to_payload(corpus_dev, s0, self.layout)
+            with tracing.span("resident.launch"):
+                s0 = _stack_padded([items[i][1].state
+                                    for i in idxs[lo:hi]],
+                                   corpus.shape[0], device, scope)
+                report.chunk_shapes.append(
+                    (corpus.shape[0], corpus.shape[1]))
+                events = int((corpus[:, :, 0] > 0).sum())  # LANE_EVENT_ID
+                report.events_appended += events
+                scope.inc(m.M_RESIDENT_EVENTS_APPENDED, events)
+                # the suffix lanes ship to the OWNING device: the group's
+                # resident states already live there, so the whole
+                # from-state append is device-local
+                corpus_dev = (jax.device_put(corpus, device)
+                              if device is not None
+                              else jax.device_put(jnp.asarray(corpus)))
+                outs = replay_from_state_to_payload(corpus_dev, s0,
+                                                    self.layout)
             return corpus, outs
 
         def consume(ci, packed):
@@ -784,21 +801,24 @@ class ResidentStateCache:
             flagged = [j for j in range(len(group))
                        if err[j] in CAPACITY_ERRORS
                        or (err[j] == 0 and ovf[j])]
-            narrow_mask = self._narrow_mask(s_fin, rung)
-            for j, i in enumerate(group):
-                if j in flagged:
-                    continue
-                key, entry, batches = items[i]
-                if err[j] != 0:
-                    # genuine history error no capacity fixes: drop the
-                    # entry, let the caller's oracle arbitrate
-                    self.invalidate(key)
-                    results[i] = AppendResult(ok=False, error=int(err[j]))
-                    continue
-                results[i] = self._readmit(
-                    key, address_of(batches), s_fin, j, rows[j],
-                    int(branch[j]), rung,
-                    bool(narrow_mask[j]) if narrow_mask is not None else False)
+            with tracing.span("resident.readmit"):
+                narrow_mask = self._narrow_mask(s_fin, rung)
+                for j, i in enumerate(group):
+                    if j in flagged:
+                        continue
+                    key, entry, batches = items[i]
+                    if err[j] != 0:
+                        # genuine history error no capacity fixes: drop
+                        # the entry, let the caller's oracle arbitrate
+                        self.invalidate(key)
+                        results[i] = AppendResult(ok=False,
+                                                  error=int(err[j]))
+                        continue
+                    results[i] = self._readmit(
+                        key, address_of(batches), s_fin, j, rows[j],
+                        int(branch[j]), rung,
+                        bool(narrow_mask[j]) if narrow_mask is not None
+                        else False)
             if flagged:
                 self._escalate(items, [group[j] for j in flagged],
                                corpus[[j for j in flagged]], rung, results,

@@ -1003,4 +1003,8 @@ class ServingScheduler:
             # flush began, and the seconds spent inside flushes
             "queue_wait_s_total": wait.total,
             "flush_s_total": flush.total,
+            # the pool's W=1 `slice_row` launches (re-admits, cold admits,
+            # views read): a count that only grows
+            "row_slices": self.resident.metrics.counter(
+                m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_ROW_SLICES),
         }

@@ -229,7 +229,8 @@ class ServiceHost(socketserver.ThreadingTCPServer):
                        cm.M_RESIDENT_WIDENED, cm.M_RESIDENT_NARROWED,
                        cm.M_RESIDENT_VIEW_ROWS,
                        cm.M_RESIDENT_VIEWS_MATERIALISED,
-                       cm.M_RESIDENT_HOST_STACKED_ROWS):
+                       cm.M_RESIDENT_HOST_STACKED_ROWS,
+                       cm.M_RESIDENT_ROW_SLICES):
             self.metrics.inc(cm.SCOPE_TPU_RESIDENT, metric, 0)
         for gauge in (cm.M_RESIDENT_BYTES, cm.M_RESIDENT_ENTRIES,
                       cm.M_RESIDENT_BUDGET_BYTES):

@@ -225,7 +225,11 @@ M_CACHE_SUFFIX_PACKS = "suffix-packs"
 #: no buffer), views-materialised the views whose W=1 row was then read;
 #: host-stacked-rows the real rows of each append launch state built on
 #: the host (rows hydrated from snapshot records) and put on the device
-#: once a leaf
+#: once a leaf; row-slices one a W=1 `slice_row` launch the pool makes
+#: (`extract_row`: an append's re-admit, a cold admit; a view's first
+#: read). Beside them an append's spans, once a chunk under fixed names
+#: (the caller's span says which path): resident.launch,
+#: resident.device-wait, resident.readmit
 M_CACHE_INVALIDATIONS = "invalidations"
 M_RESIDENT_SUFFIX_HITS = "suffix-hits"
 M_RESIDENT_BYTES = "resident-bytes"
@@ -237,6 +241,7 @@ M_RESIDENT_NARROWED = "renarrowed-rows"
 M_RESIDENT_VIEW_ROWS = "view-rows"
 M_RESIDENT_HOST_STACKED_ROWS = "host-stacked-rows"
 M_RESIDENT_VIEWS_MATERIALISED = "views-materialised"
+M_RESIDENT_ROW_SLICES = "row-slices"
 #: capacity-escalation ladder counters (engine/ladder.py,
 #: SCOPE_TPU_FALLBACK): rows entering the ladder, rows re-replayed at
 #: each rung (metric name ladder_rung_rows(r)), rows resolved on device,

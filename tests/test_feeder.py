@@ -285,7 +285,7 @@ class TestFeederNativeWirec:
         # O(new events): every launched suffix axis is far below the
         # (bucketed) history axis
         history_e = corpus.shape[1]
-        for _w, e in cache.last_append.chunk_shapes:
+        for _w, e in report.chunk_shapes:
             assert e <= max(16, history_e // 2), (e, history_e)
         # payload parity vs full replay
         full_rows = [pack_cache.encode(k, h) for k, h in zip(keys, hists)]

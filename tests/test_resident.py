@@ -8,9 +8,12 @@ overwrite / reset / NDC branch switch through verify_all; the
 capacity-escalation ladder widening a resident state on an overflowing
 append and re-narrowing it once the load drains; the pipelined executor
 packing only suffix batches at depth >= 2; the rebuilder's resident
-consult; and the tpu.resident/* metrics surface.
+consult; an append's spans (`resident.launch`, `.device-wait`,
+`.readmit`, once a chunk under the caller's span) and the `row-slices`
+count; and the tpu.resident/* metrics surface.
 """
 import random
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -27,12 +30,17 @@ from cadence_tpu.engine.cache import (
     address_relation,
     content_address,
 )
+from cadence_tpu.engine import resident as resident_mod
 from cadence_tpu.engine.ladder import EscalationLadder
 from cadence_tpu.engine.resident import ResidentStateCache
 from cadence_tpu.gen.corpus import generate_corpus
 from cadence_tpu.ops.encode import assemble_corpus, encode_batches_resumable
 from cadence_tpu.oracle.state_builder import StateBuilder
 from cadence_tpu.utils import metrics as m
+from cadence_tpu.utils import tracing
+from tests.test_recover_warm import _kinds, _recover
+from tests.test_recover_warm import wal, written  # noqa: F401 (fixtures)
+from tests.test_serving import _Harness
 
 DOMAIN = "res-domain"
 TL = "res-tl"
@@ -328,14 +336,14 @@ class TestResidentCacheUnit:
         _seed_cache(cache, keys, [h[:-1] for h in hists])
         items = [(k, cache.lookup(k, h)[1], h)
                  for k, h in zip(keys, hists)]
-        results = cache.replay_append(items)
+        results, report = cache.replay_append_report(items)
         for h, res in zip(hists, results):
             assert res.ok and not res.escalated
             assert (res.payload == _oracle_row(h)).all()
         # entries re-addressed at the full history: exact hits now
         for k, h in zip(keys, hists):
             assert cache.lookup(k, h)[0] == "exact"
-        assert cache.last_append.events_appended == sum(
+        assert report.events_appended == sum(
             len(h[-1].events) for h in hists)
 
 
@@ -818,13 +826,13 @@ class TestExecutorIntegration:
         _seed_cache(cache, keys, [h[:-1] for h in hists])
         items = [(k, cache.lookup(k, h)[1], h)
                  for k, h in zip(keys, hists)]
-        results = cache.replay_append(items)
+        results, report = cache.replay_append_report(items)
         for h, res in zip(hists, results):
             assert res.ok
             assert (res.payload == _oracle_row(h)).all()
         # 12 items / chunk 4 = 3 chunks, each packed to the SUFFIX event
         # axis (pow2 floor 16), not the 48-event history
-        shapes = cache.last_append.chunk_shapes
+        shapes = report.chunk_shapes
         assert len(shapes) == 3
         assert all(e <= 16 for _, e in shapes)
 
@@ -842,11 +850,128 @@ class TestExecutorIntegration:
             _seed_cache(cache, keys, [h[:-1] for h in hists])
             items = [(k, cache.lookup(k, h)[1], h)
                      for k, h in zip(keys, hists)]
-            for h, res in zip(hists, cache.replay_append(items)):
+            results, report = cache.replay_append_report(items)
+            for h, res in zip(hists, results):
                 assert res.ok
                 assert (res.payload == _oracle_row(h)).all()
-            shapes[label] = cache.last_append.chunk_shapes
+            shapes[label] = report.chunk_shapes
         assert shapes["short"] == shapes["long"]
+
+
+# ---------------------------------------------------------------------------
+# an append's legs as spans, once a chunk under the caller's span; the
+# pool's W=1 row slices as a count
+# ---------------------------------------------------------------------------
+
+LEGS = ("resident.launch", "resident.device-wait", "resident.readmit")
+
+
+def _flush_of_two_chunks(_request, _monkeypatch):
+    """A serving flush of six suffix appends, three a chunk."""
+    h = _Harness(workflows=6)
+    for k in h.keys:
+        h.counts[k] = len(h.by_key[k]) - 1
+        h.submit(k)
+    h.flush()   # cold admits
+    h.sched.resident.chunk_workflows = 3
+    tickets = []
+    for k in h.keys:
+        h.counts[k] += 1
+        tickets.append(h.submit(k))
+    tracing.DEFAULT_TRACER.reset()
+    with h.sched._prof.leg(m.M_PROFILE_SERVING, span="serving.flush"):
+        h.flush()
+    assert all(t.result(timeout=5).path == "suffix" for t in tickets)
+    return ("serving.flush",)
+
+
+def _recovery_of_two_chunks(request, monkeypatch):
+    """A small warm recovery whose suffix rows make two chunks a pass."""
+    path, histories, cuts, _sweep = request.getfixturevalue("wal")
+    _exact, suffix, _none = _kinds(histories, cuts)
+    assert suffix >= 6
+    monkeypatch.setenv(resident_mod.CHUNK_ENV, str((suffix + 1) // 2))
+    tracing.DEFAULT_TRACER.reset()
+    _stores, report = _recover(path)
+    assert report.ok
+    assert report.suffix_rows == {"rebuild": suffix, "verify": suffix}
+    return ("rebuild.suffix-replay", "verify.suffix-replay")
+
+
+@pytest.mark.parametrize("run", [_flush_of_two_chunks,
+                                 _recovery_of_two_chunks],
+                         ids=["serving.flush", "rebuild.suffix-replay"])
+def test_an_append_lays_its_legs_once_a_chunk_under_the_callers_span(
+        run, request, monkeypatch):
+    callers = run(request, monkeypatch)
+    spans = tracing.DEFAULT_TRACER.finished_spans()
+    ours = [s for s in spans if s.operation.startswith("resident.")]
+    # six a call whatever its rows, all of them directly under the caller
+    assert len(ours) == 6 * len(callers)
+    for name in callers:
+        (outer,) = [s for s in spans if s.operation == name]
+        legs = [s for s in ours if s.parent_id == outer.span_id]
+        assert Counter(s.operation for s in legs) == {leg: 2 for leg in LEGS}
+        by_leg = [sorted((s for s in legs if s.operation == leg),
+                         key=lambda s: s.start_ns) for leg in LEGS]
+        for launch, wait, readmit in zip(*by_leg):
+            assert launch.start_ns + launch.duration_ns <= wait.start_ns
+            assert wait.start_ns + wait.duration_ns <= readmit.start_ns
+
+
+def _row_slices(cache):
+    return cache.stats()["row_slices"]
+
+
+@pytest.mark.parametrize("how", ["views", "rows"])
+def test_row_slices_count_the_pools_slice_row_launches(how, monkeypatch):
+    """`tpu.resident/row-slices` = the `slice_row` launches: one a row
+    sliced and admitted, one a view's FIRST read, one a readmitted row of
+    an append."""
+    launched = []
+    real = resident_mod._slice_row
+    monkeypatch.setattr(resident_mod, "_slice_row",
+                        lambda s, i: launched.append(i) or real(s, i))
+    cache = ResidentStateCache(DEFAULT_LAYOUT,
+                               ladder=EscalationLadder(DEFAULT_LAYOUT),
+                               chunk_workflows=2)
+    n = 5
+    hists = generate_corpus("basic", num_workflows=n, seed=29,
+                            target_events=32)
+    keys = [("d", f"w{i}", "r") for i in range(n)]
+    _seed_from_chunk(cache, keys, [h[:-1] for h in hists], how)
+    assert _row_slices(cache) == len(launched) == (0 if how == "views"
+                                                   else n)
+    seeded = len(launched)
+    if how == "views":
+        # a view's first read slices its row; a second read does not
+        entry = cache.entry_for(keys[0])
+        assert entry.state is entry.state
+        assert _row_slices(cache) == len(launched) == 1
+    before = len(launched)
+    items = [(k, cache.lookup(k, h)[1], h) for k, h in zip(keys, hists)]
+    results, _report = cache.replay_append_report(items)
+    assert all(r.ok for r in results)
+    # each view still unread is sliced once for the launch state, and
+    # every appended row once to be re-pinned
+    unread = n - 1 if how == "views" else 0
+    assert len(launched) - before == unread + n
+    assert _row_slices(cache) == len(launched) == seeded + (
+        1 if how == "views" else 0) + unread + n
+
+
+def test_serving_stats_carry_the_pools_row_slices():
+    h = _Harness(workflows=3)
+    seen = [h.sched.stats()["row_slices"]]
+    assert seen == [0]
+    for counts in (-1, 0):   # the cold admits, then a suffix append each
+        for k in h.keys:
+            h.counts[k] = len(h.by_key[k]) + counts
+            h.submit(k)
+        h.flush()
+        seen.append(h.sched.stats()["row_slices"])
+    assert seen == [0, 3, 6]
+    assert h.sched.resident.stats()["row_slices"] == 6
 
 
 # ---------------------------------------------------------------------------
@@ -1063,6 +1188,7 @@ class TestMetricsSurface:
             in text
         assert 'cadence_host_stacked_rows_total{scope="tpu.resident"} 0' \
             in text
+        assert 'cadence_row_slices_total{scope="tpu.resident"} 0' in text
         assert 'cadence_resident_bytes{scope="tpu.resident"} 0' in text
         assert 'cadence_budget_bytes{scope="tpu.resident"} 0' in text
 
