@@ -464,41 +464,21 @@ class DeviceRebuilder:
 
     def _hydrate_resolved(self, resolved) -> Dict[int, MutableState]:
         """Hydrate MutableStates from resident-served rows, verified
-        against each entry's canonical payload. Base-rung rows hydrate
-        in BATCHES: chunks stack into one pytree and pay ONE device_get
-        — a restart hydrating thousands of rows must not pay a per-key
-        device round-trip per workflow. Ladder-widened rows (different
-        leaf shapes) read back individually — the rare case."""
-        import jax
-
-        from .resident import _bucket, _stack_padded
-
+        against each entry's canonical payload. The rows come to the host
+        in BULK (`ResidentStateCache.host_rows`): an appended chunk's
+        views in one `device_get` of the chunk, rows hydrated from
+        snapshot records where they already are, other device rows a
+        stack at a time — a restart hydrating thousands of rows must not
+        pay a device round-trip, or a `slice_row` launch, a workflow."""
         pre: Dict[int, MutableState] = {}
-
-        def hydrate_one(arrs, row, pos, key, batches, entry, rentry):
+        host = self.resident.host_rows([r[4] for r in resolved])
+        for (pos, key, batches, entry, rentry), (arrs, row) in zip(
+                resolved, host):
             ms = self._hydrate(arrs, row, batches, entry,
                                known_size=self._known_size(key, batches))
             if ms is not None and (payload_row(ms, self.layout)
                                    == rentry.payload).all():
                 pre[pos] = ms
-
-        base = [r for r in resolved if r[4].rung == 0]
-        for lo in range(0, len(base), 64):
-            group = base[lo:lo + 64]
-            states = [g[4].state for g in group]
-            if len(states) == 1:
-                arrs = jax.device_get(states[0])
-            else:
-                arrs = jax.device_get(
-                    _stack_padded(states, _bucket(len(states), 8)))
-            for j, (pos, key, batches, entry, rentry) in enumerate(group):
-                hydrate_one(arrs, j if len(group) > 1 else 0,
-                            pos, key, batches, entry, rentry)
-        for pos, key, batches, entry, rentry in resolved:
-            if rentry.rung == 0:
-                continue
-            arrs = jax.device_get(rentry.state)
-            hydrate_one(arrs, 0, pos, key, batches, entry, rentry)
         return pre
 
     def _known_size(self, key, batches) -> Optional[int]:

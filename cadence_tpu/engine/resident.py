@@ -13,13 +13,15 @@ ResidentStateCache is the device twin of that execution cache:
 
 - per-workflow final `ReplayState` rows stay RESIDENT in HBM between
   calls, LRU-bounded by a configurable HBM byte budget. A row that
-  arrives alone (a serving flush, an append's re-admit, a hydrated
-  snapshot) is pinned as a W=1 slice of the batched scan state, one
-  pytree of device arrays per workflow (`admit`). The verified rows of
-  a bulk chunk arrive together (`admit_chunk`) and are pinned as VIEWS
-  of the chunk's own state: (chunk state, row index), no program
-  launched and no device buffer made until somebody reads the row's
-  `state`; an exact hit never does;
+  arrives alone (a serving flush's cold admit, a hydrated snapshot, an
+  append's row at a widened rung) is pinned as a W=1 slice of the
+  batched scan state, one pytree of device arrays per workflow
+  (`admit`). Rows that arrive together, the verified rows of a bulk
+  chunk (`admit_chunk`) and the base-rung rows of an append chunk, are
+  pinned as VIEWS of the chunk's own state: (chunk state, row index),
+  no program launched and no device buffer made until somebody reads
+  the row's `state`; an exact hit never does, and a bulk reader reads
+  the chunk whole (`host_rows`);
 - entries are content-addressed by the same (workflow key, batch count,
   last-batch CRC32) scheme the pack cache uses — the shared helper in
   engine/cache.py, so the two caches can never drift on invalidation
@@ -64,8 +66,9 @@ An append's legs are spans, each ONCE A CHUNK and under a fixed name:
 `resident.launch` (the launch state stacked, the suffix lanes put on the
 device, the from-state scan dispatched), `resident.device-wait` (the
 blocking wait and readback) and `resident.readmit` (the chunk's
-successful rows sliced, narrowed where they may be, re-pinned; its
-error rows invalidated; rows the ladder escalates are not in it). No
+successful rows re-pinned: as views of its final state at the base
+rung, sliced and narrowed where they may be at a widened one; its error
+rows invalidated; rows the ladder escalates are not in it). No
 span a row, and no prefix argument: the caller's span (`serving.flush`,
 `rebuild.suffix-replay`, `verify.suffix-replay`) says which path ran
 them.
@@ -126,10 +129,11 @@ def _tree_nbytes(tree) -> int:
 
 
 class _ChunkPin:
-    """What the views of one bulk-verified chunk share on ONE device: the
-    chunk's [W, ...] state there (all of it under an unsharded pool, the
-    device's own rows under a sharded one), held whole, padding rows and
-    rows no longer viewed included, until its last view lets go."""
+    """What the views of one chunk (bulk-verified, or appended to) share
+    on ONE device: the chunk's [W, ...] state there (all of it under an
+    unsharded pool, the device's own rows under a sharded one), held
+    whole, padding rows and rows no longer viewed included, until its
+    last view lets go."""
 
     __slots__ = ("state", "nbytes", "shard", "live", "lock", "pool",
                  "__weakref__")
@@ -231,8 +235,9 @@ class ResidentStateCache:
 
     - a W=1 row (`admit`, or a view once its `state` was read) counts
       one row: its device leaves plus the host payload row;
-    - a view (`admit_chunk`) pins the WHOLE of its chunk's state on its
-      device — padding rows, rows that failed the verify, rows whose
+    - a view (`admit_chunk`, an append's base-rung rows) pins the WHOLE
+      of its chunk's state on its device — padding rows, rows that
+      failed the verify or the append, rows whose
       views were evicted, invalidated or materialised since — so the
       chunk counts whole, once, in that device's slice for as long as
       one view of it is live there, and each view adds only its host
@@ -591,34 +596,44 @@ class ResidentStateCache:
         n = len(self._slices)
         parts = _device_parts(state, self._mesh) if n > 1 else [state]
         per = jax.tree_util.tree_leaves(state)[0].shape[0] // n
-        payload_nbytes = self.layout.width * 8
         by_shard: Dict[int, list] = {}
-        for item in rows:
-            shard = self.shard_of(item[0])
-            if not shard * per <= item[2] < (shard + 1) * per:
+        for key, address, row, payload, branch in rows:
+            shard = self.shard_of(key)
+            if not shard * per <= row < (shard + 1) * per:
                 raise ValueError(
-                    f"row {item[2]} of a chunk of {n} x {per} rows does "
-                    f"not lie on shard {shard}, which owns {item[0]}")
-            by_shard.setdefault(shard, []).append(item)
-        pins = {shard: _ChunkPin(parts[shard], shard, self)
-                for shard in by_shard}
+                    f"row {row} of a chunk of {n} x {per} rows does "
+                    f"not lie on shard {shard}, which owns {key}")
+            by_shard.setdefault(shard, []).append(
+                (key, address, row - shard * per, payload, branch))
+        return self._pin_views([
+            (_ChunkPin(parts[shard], shard, self), group)
+            for shard, group in sorted(by_shard.items())])
+
+    def _pin_views(self, groups: Sequence[Tuple[_ChunkPin, list]]) -> int:
+        """Pin each group's rows as views of its pin at the base rung:
+        (key, address, row index in `pin.state`, canonical payload row,
+        branch) each, counted by the class's budget rule under one lock
+        and one gauge update for all of them. A pin that cannot fit its
+        slice beside its rows' payload rows has its rows sliced and
+        admitted one by one instead. Returns the number of rows now
+        resident."""
+        payload_nbytes = self.layout.width * 8
         viewed = evicted = 0
         alone = []
         with self._lock:
-            for shard, group in sorted(by_shard.items()):
-                pin = pins[shard]
+            for pin, group in groups:
                 if (pin.nbytes + len(group) * payload_nbytes
                         > min(self.slice_budget, self.budget_bytes)):
                     alone += [(pin, item) for item in group]
                     continue
                 for key, address, row, payload, branch in group:
-                    self._count_locked(shard, key, ResidentEntry(
+                    self._count_locked(pin.shard, key, ResidentEntry(
                         payload=np.asarray(payload, dtype=np.int64),
                         branch=int(branch), address=address, rung=0,
-                        nbytes=payload_nbytes, _chunk=pin,
-                        _row=int(row) - shard * per, _charge=pin))
+                        nbytes=payload_nbytes, _chunk=pin, _row=int(row),
+                        _charge=pin))
                 viewed += len(group)
-                evicted += self._evict_locked(shard)
+                evicted += self._evict_locked(pin.shard)
             self._gauges_locked()
         scope = self._scope()
         if viewed:
@@ -626,8 +641,7 @@ class ResidentStateCache:
         if evicted:
             scope.inc(m.M_CACHE_EVICTIONS, evicted)
         return viewed + sum(
-            self.admit(key, address,
-                       self.extract_row(pin.state, row - pin.shard * per),
+            self.admit(key, address, self.extract_row(pin.state, row),
                        payload, branch)
             for pin, (key, address, row, payload, branch) in alone)
 
@@ -663,6 +677,48 @@ class ResidentStateCache:
         counted under `row-slices`."""
         self._scope().inc(m.M_RESIDENT_ROW_SLICES)
         return _slice_row(state, index)
+
+    def host_rows(self, entries: Sequence[ResidentEntry]
+                  ) -> List[Tuple[object, int]]:
+        """Each entry's state on the host, as (host tree, row index in
+        it), for a reader of many rows at once (a rebuild's hydration).
+        A view is read from its chunk and stays a view: the views of one
+        chunk share ONE `device_get` of the chunk's state. Rows whose
+        leaves are on the host already (hydrated from snapshot records)
+        are read where they are; W=1 device rows at the base rung are
+        stacked STACK_BLOCK at a time and read with one `device_get` a
+        stack, a widened row alone (its leaves have other shapes)."""
+        out: List[Optional[Tuple[object, int]]] = [None] * len(entries)
+        by_pin: Dict[int, Tuple[_ChunkPin, list]] = {}
+        base: List[int] = []
+        for i, entry in enumerate(entries):
+            # a concurrent read may materialise the view meanwhile: the
+            # pin keeps the chunk and `_row` never changes, and a view
+            # lets go of its chunk only once its `_state` is set
+            pin = entry._chunk
+            if pin is not None:
+                by_pin.setdefault(id(pin), (pin, []))[1].append(
+                    (i, entry._row))
+            elif _host_leaves([entry._state]) is not None:
+                out[i] = (entry._state, 0)
+            elif entry.rung == 0:
+                base.append(i)
+            else:
+                out[i] = (jax.device_get(entry._state), 0)
+        for pin, rows in by_pin.values():
+            tree = jax.device_get(pin.state)
+            for i, row in rows:
+                out[i] = (tree, row)
+        for lo in range(0, len(base), STACK_BLOCK):
+            group = base[lo:lo + STACK_BLOCK]
+            if len(group) == 1:
+                out[group[0]] = (jax.device_get(entries[group[0]]._state), 0)
+                continue
+            tree = jax.device_get(_stack_padded(
+                [entries[i]._state for i in group], _bucket(len(group), 8)))
+            for j, i in enumerate(group):
+                out[i] = (tree, j)
+        return out
 
     # -- the append transaction ---------------------------------------------
 
@@ -803,6 +859,8 @@ class ResidentStateCache:
                        or (err[j] == 0 and ovf[j])]
             with tracing.span("resident.readmit"):
                 narrow_mask = self._narrow_mask(s_fin, rung)
+                #: base-rung rows, re-pinned together as views of s_fin
+                viewed = []
                 for j, i in enumerate(group):
                     if j in flagged:
                         continue
@@ -814,11 +872,23 @@ class ResidentStateCache:
                         results[i] = AppendResult(ok=False,
                                                   error=int(err[j]))
                         continue
+                    if rung == 0:
+                        viewed.append((key, address_of(batches), j,
+                                       rows[j], int(branch[j])))
+                        results[i] = AppendResult(
+                            ok=True, payload=np.asarray(rows[j]),
+                            branch=int(branch[j]), rung=0)
+                        continue
                     results[i] = self._readmit(
                         key, address_of(batches), s_fin, j, rows[j],
                         int(branch[j]), rung,
                         bool(narrow_mask[j]) if narrow_mask is not None
                         else False)
+                if viewed:
+                    # the group lives on one device (its shard's): one
+                    # pin over the chunk's final state, no launch a row
+                    self._pin_views([(_ChunkPin(s_fin, shard, self),
+                                      viewed)])
             if flagged:
                 self._escalate(items, [group[j] for j in flagged],
                                corpus[[j for j in flagged]], rung, results,
@@ -834,8 +904,9 @@ class ResidentStateCache:
     def _readmit(self, key, address: ContentAddress, s_fin, row: int,
                  payload, branch: int, rung: int,
                  narrowable: bool) -> AppendResult:
-        """Re-pin one successfully appended row (re-narrowed when its
-        load drained back under base capacities)."""
+        """Re-pin one successfully appended row of a widened rung as a
+        W=1 row (re-narrowed when its load drained back under base
+        capacities); base-rung rows are re-pinned as views instead."""
         state_row = self.extract_row(s_fin, row)
         if rung > 0 and narrowable:
             from ..ops.state import narrow_state

@@ -941,7 +941,9 @@ class ServingScheduler:
                 warmed += 1
         # the per-flush host plumbing jits too: stacking the W=1
         # resident rows into the launch state and slicing a row back out
-        # each trace once per flush width — both must happen HERE, not
+        # (an appended row is re-pinned as a view of the flush's final
+        # state and sliced by the next flush that reads it) each trace
+        # once per flush width — both must happen HERE, not
         # inside the first drain windows (each mid-window trace stalls
         # the drain long enough for folds to outgrow the warmed event
         # buckets)
@@ -1003,8 +1005,8 @@ class ServingScheduler:
             # flush began, and the seconds spent inside flushes
             "queue_wait_s_total": wait.total,
             "flush_s_total": flush.total,
-            # the pool's W=1 `slice_row` launches (re-admits, cold admits,
-            # views read): a count that only grows
+            # the pool's W=1 `slice_row` launches (cold admits, views
+            # read, widened re-admits): a count that only grows
             "row_slices": self.resident.metrics.counter(
                 m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_ROW_SLICES),
         }

@@ -221,13 +221,14 @@ M_CACHE_SUFFIX_PACKS = "suffix-packs"
 #: HBM-resident state, invalidations count stale entries dropped on tail
 #: overwrite / reset / NDC branch switch; the resident-bytes gauge is
 #: the cache's HBM footprint against its configured budget; view-rows
-#: counts rows a bulk chunk seeded as views of its own state (no launch,
-#: no buffer), views-materialised the views whose W=1 row was then read;
-#: host-stacked-rows the real rows of each append launch state built on
-#: the host (rows hydrated from snapshot records) and put on the device
-#: once a leaf; row-slices one a W=1 `slice_row` launch the pool makes
-#: (`extract_row`: an append's re-admit, a cold admit; a view's first
-#: read). Beside them an append's spans, once a chunk under fixed names
+#: counts rows a bulk chunk seeded, or an append chunk re-pinned, as
+#: views of its own state (no launch, no buffer), views-materialised the
+#: views whose W=1 row was then read; host-stacked-rows the real rows of
+#: each append launch state built on the host (rows hydrated from
+#: snapshot records) and put on the device once a leaf; row-slices one a
+#: W=1 `slice_row` launch the pool makes (`extract_row`: a cold admit,
+#: an append's re-admit at a widened rung; a view's first read). Beside
+#: them an append's spans, once a chunk under fixed names
 #: (the caller's span says which path): resident.launch,
 #: resident.device-wait, resident.readmit
 M_CACHE_INVALIDATIONS = "invalidations"

@@ -397,7 +397,8 @@ def test_sharded_pool_seeded_by_a_chunk_of_views(how):
                            m.M_RESIDENT_VIEWS_MATERIALISED) == count[0]
 
     # a suffix append from the views left gives the row's append, on
-    # the owning device
+    # the owning device, re-pinned as a view of the append's final state
+    # there; the row it materialises lies there too
     on1 = [i for i, k in enumerate(keys) if owner[k] == 1]
     results = cache.replay_append(
         [(keys[i], cache.lookup(keys[i], hists[i])[1], hists[i])
@@ -408,7 +409,9 @@ def test_sharded_pool_seeded_by_a_chunk_of_views(how):
         oracle[STICKY_ROW_INDEX] = 0
         assert (res.payload == oracle).all()
         kind, entry = cache.lookup(keys[i], hists[i])
-        assert kind == "exact" and not entry.is_view
+        assert kind == "exact" and entry.is_view
+        for leaf in jax.tree_util.tree_leaves(entry._chunk.state):
+            assert leaf.devices() == {mesh.devices.flat[1]}
         leaf = jax.tree_util.tree_leaves(entry.state)[0]
         assert leaf.devices() == {mesh.devices.flat[1]}
     assert per_device() == [c * row_nbytes for c in count]

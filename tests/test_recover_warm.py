@@ -22,7 +22,9 @@ in 0..31, seeded); one run in six starts after the sweep and has no record.
   still equals the oracle;
 - an append chunk wider than one `stack` program is stacked in blocks;
   one of rows hydrated from records is stacked on the host and put on the
-  device once a leaf, and every suffix row of both passes goes that way.
+  device once a leaf, and every suffix row of both passes goes that way;
+- both passes re-pin their suffix rows as views of the append's final
+  state and the hydration reads them from it: no `slice_row` launch.
 
 The cell `recover.wal-snap-1chip` times this path on the chip.
 """
@@ -472,6 +474,99 @@ def test_a_launch_of_host_rows_is_stacked_on_the_host(rows, width,
                 assert leaf.devices() == {device}
         assert registry.counter(
             m.SCOPE_TPU_RESIDENT, m.M_RESIDENT_HOST_STACKED_ROWS) == rows
+
+
+def _resident_counter(name):
+    return m.DEFAULT_REGISTRY.counter(m.SCOPE_TPU_RESIDENT, name)
+
+
+def _record_slices(monkeypatch):
+    """The `slice_row` launches the pool makes from here on."""
+    sliced = []
+    slice_row = resident._slice_row
+    monkeypatch.setattr(
+        resident, "_slice_row",
+        lambda state, index: sliced.append(index) or slice_row(state, index))
+    return sliced
+
+
+def test_a_warm_recovery_slices_no_row(wal, monkeypatch):
+    """Both passes re-pin their suffix rows as views of the append's final
+    state, and the rebuild's hydration reads them from it: no `slice_row`
+    launch in the whole recovery, and no view materialised."""
+    path, histories, cuts, _sweep = wal
+    _exact, _suffix, none = _kinds(histories, cuts)
+    sliced = _record_slices(monkeypatch)
+    stores, report = _recover(path)
+    assert report.ok
+    assert _crcs(stores, histories) == _oracle_crcs(histories)
+    assert sliced == []
+    assert _resident_counter(m.M_RESIDENT_ROW_SLICES) == 0
+    assert _resident_counter(m.M_RESIDENT_VIEWS_MATERIALISED) == 0
+    # every suffix row of both passes is a view; beside them the verify's
+    # cold chunk seeds the runs with no record as views of its own state
+    # (`verify.seed-resident`, which does not fire where every run has a
+    # record)
+    suffix = m.DEFAULT_REGISTRY.counter(m.SCOPE_TPU_RECOVER,
+                                        m.M_RECOVER_SUFFIX_ROWS)
+    assert suffix == sum(report.suffix_rows.values()) > 0
+    assert _resident_counter(m.M_RESIDENT_VIEW_ROWS) == suffix + none
+
+
+def test_the_hydration_reads_views_from_their_chunk_and_leaves_them_views(
+        monkeypatch):
+    """A rebuild's prepass over rows of each kind the pool holds: appended
+    rows (re-pinned as views), W=1 device rows and rows on the host, all
+    hydrated to the oracle's state with no `slice_row` launch; the views
+    stay views."""
+    import jax
+    import jax.numpy as jnp
+
+    from cadence_tpu.engine.cache import content_address
+    from cadence_tpu.engine.rebuild import DeviceRebuilder
+    from cadence_tpu.ops.encode import (
+        assemble_corpus,
+        encode_batches_resumable,
+    )
+    from cadence_tpu.ops.payload import payload_rows
+    from cadence_tpu.ops.replay import replay_events
+
+    def _run(history):
+        return (history[0].domain_id, history[0].workflow_id,
+                history[0].run_id)
+
+    histories = [generate_history(suite, SEED, 0, TARGET_EVENTS)
+                 for suite in SUITES] + [
+        generate_history("timer_retry", SEED, 1, TARGET_EVENTS)]
+    appended, on_host = range(3), (5,)
+    # what the pool holds: a prefix of the first three, the others whole
+    pinned = [h[:-1] if j in appended else h
+              for j, h in enumerate(histories)]
+    rebuilder = DeviceRebuilder()
+    pool = rebuilder.resident = resident.ResidentStateCache(
+        ladder=rebuilder.ladder)
+    lanes = [encode_batches_resumable(h)[0] for h in pinned]
+    state = replay_events(jnp.asarray(
+        assemble_corpus(lanes, max(r.shape[0] for r in lanes))))
+    rows = np.asarray(payload_rows(state))
+    branch = np.asarray(state.current_branch)
+    for j, h in enumerate(pinned):
+        row = pool.extract_row(state, j)
+        assert pool.admit(_run(h), content_address(h),
+                          jax.device_get(row) if j in on_host else row,
+                          rows[j], int(branch[j]))
+    sliced = _record_slices(monkeypatch)
+    got = rebuilder.rebuild([(h, None) for h in histories])
+    assert sliced == []
+    assert rebuilder.stats.resident == len(histories)
+    assert rebuilder.stats.suffix_rows == len(appended)
+    for ms, h in zip(got, histories):
+        assert crc32_of_row(payload_row(ms)) == \
+            crc32_of_row(payload_row(StateBuilder().replay_history(h)))
+    entries = [pool.entry_for(_run(h)) for h in histories]
+    assert [e.is_view for e in entries] == [
+        j in appended for j in range(len(histories))]
+    assert _resident_counter(m.M_RESIDENT_VIEWS_MATERIALISED) == 0
 
 
 @pytest.mark.parametrize("snapshots", ["1", "0"])

@@ -8,9 +8,11 @@ overwrite / reset / NDC branch switch through verify_all; the
 capacity-escalation ladder widening a resident state on an overflowing
 append and re-narrowing it once the load drains; the pipelined executor
 packing only suffix batches at depth >= 2; the rebuilder's resident
-consult; an append's spans (`resident.launch`, `.device-wait`,
-`.readmit`, once a chunk under the caller's span) and the `row-slices`
-count; and the tpu.resident/* metrics surface.
+consult; an append's base-rung rows re-pinned as views of its final
+state (a widened rung's row by row); an append's spans
+(`resident.launch`, `.device-wait`, `.readmit`, once a chunk under the
+caller's span) and the `row-slices` count; and the tpu.resident/*
+metrics surface.
 """
 import random
 from collections import Counter
@@ -446,7 +448,7 @@ class TestChunkViews:
         items = [(keys[i], cache.lookup(keys[i], hists[i])[1], hists[i])
                  for i in appended]
         results = cache.replay_append(items)
-        row_nbytes = cache._row_nbytes(DEFAULT_LAYOUT)
+        payload_nbytes = DEFAULT_LAYOUT.width * 8
         for i, res in zip(appended, results):
             assert res.ok and not res.escalated and res.rung == 0
             assert (res.payload == _oracle_row(hists[i])).all()
@@ -454,13 +456,16 @@ class TestChunkViews:
             assert res.branch == oracle.version_histories.current_index
             kind, entry = cache.lookup(keys[i], hists[i])
             assert kind == "exact"          # re-admitted at the new address
-            assert not entry.is_view and entry.nbytes == row_nbytes
+            # as a view of the append's own final state
+            assert entry.is_view and entry.nbytes == payload_nbytes
             assert (entry.payload == res.payload).all()
-        # only the rows appended to were materialised; the rest still view
+        # only the rows appended to were materialised (for the launch),
+        # then re-pinned as views; the rest still view the seeding chunk
+        seeded = self.N if how == "views" else 0
         assert _view_counters(cache) == (
-            (self.N, len(appended)) if how == "views" else (0, 0))
+            seeded + len(appended), len(appended) if seeded else 0)
         assert cache.stats()["view_entries"] == (
-            self.N - len(appended) if how == "views" else 0)
+            self.N if how == "views" else len(appended))
         assert cache.resident_bytes == _counted(cache)
 
     def test_bytes_follow_the_stated_rule(self, how):
@@ -642,10 +647,26 @@ def test_an_evicted_view_still_held_by_a_caller_reads_its_state():
     assert _view_counters(cache) == (2, 1)
 
 
-def test_views_under_readers_and_invalidations_at_once():
-    """Eight threads read every view's state while one invalidates and
-    re-admits: each view is sliced once, every reader sees the row the
-    eager slice gives, and the count is the rule's at the end."""
+def _views_made_by(made_by, cache, keys, hists):
+    """Pin every key's whole-history row as a view: of the chunk that
+    replayed the histories (`admit_chunk`), or of the final state of an
+    append of each history's last batch to its prefix row. Returns a
+    state whose row i is key i's."""
+    if made_by == "admit_chunk":
+        return _seed_from_chunk(cache, keys, hists, "views")
+    _seed_cache(cache, keys, [h[:-1] for h in hists])
+    results = cache.replay_append(
+        [(k, cache.lookup(k, h)[1], h) for k, h in zip(keys, hists)])
+    assert all(r.ok for r in results)
+    return _replay_full(hists)[0]
+
+
+@pytest.mark.parametrize("made_by", ["admit_chunk", "append"])
+def test_views_under_readers_and_invalidations_at_once(made_by):
+    """Eight threads read every view's state (a verified chunk's, or an
+    append's re-pinned rows) while one invalidates and re-admits: each
+    view is sliced once, every reader sees the row the eager slice gives,
+    and the count is the rule's at the end."""
     import sys
     import threading
 
@@ -654,7 +675,8 @@ def test_views_under_readers_and_invalidations_at_once():
     hists = generate_corpus("basic", num_workflows=n, seed=43,
                             target_events=20)
     keys = [("d", f"w{i}", "r") for i in range(n)]
-    state = _seed_from_chunk(cache, keys, hists, "views")
+    state = _views_made_by(made_by, cache, keys, hists)
+    assert cache.stats()["view_entries"] == n
     expected = [np.asarray(cache.extract_row(state, i).next_event_id)
                 for i in range(n)]
     entries = [cache.entry_for(k) for k in keys]
@@ -698,6 +720,116 @@ def test_views_under_readers_and_invalidations_at_once():
     assert len(cache) == n and cache.stats()["view_entries"] == 0
     assert cache.resident_bytes == _counted(cache) \
         == n * cache._row_nbytes(DEFAULT_LAYOUT)
+
+
+# ---------------------------------------------------------------------------
+# an append's base-rung rows re-pinned as views of the append's final state
+# ---------------------------------------------------------------------------
+
+
+def _append_last_batches(cache, n=5, suite="basic", seed=59):
+    """Seed n W=1 prefix rows, then append each history's last batch:
+    (keys, hists, the append's results)."""
+    hists = generate_corpus(suite, num_workflows=n, seed=seed,
+                            target_events=32)
+    keys = [("d", f"w{i}", "r") for i in range(n)]
+    _seed_cache(cache, keys, [h[:-1] for h in hists])
+    results = cache.replay_append(
+        [(k, cache.lookup(k, h)[1], h) for k, h in zip(keys, hists)])
+    return keys, hists, results
+
+
+def _fields(results):
+    return [(r.ok, r.payload.tobytes(), r.branch, r.error, r.rung,
+             r.escalated) for r in results]
+
+
+def test_an_appends_rows_come_back_as_views_of_one_pin(monkeypatch):
+    launched = []
+    real = resident_mod._slice_row
+    monkeypatch.setattr(resident_mod, "_slice_row",
+                        lambda s, i: launched.append(i) or real(s, i))
+    cache = ResidentStateCache(DEFAULT_LAYOUT,
+                               ladder=EscalationLadder(DEFAULT_LAYOUT))
+    n = 5
+    keys, hists, results = _append_last_batches(cache, n)
+    # the seeding sliced its n rows; the append slices none
+    assert _row_slices(cache) == len(launched) == n
+    assert _view_counters(cache) == (n, 0)
+    for h, res in zip(hists, results):
+        assert res.ok and not res.escalated and res.rung == 0
+        assert (res.payload == _oracle_row(h)).all()
+    entries = [cache.lookup(k, h)[1] for k, h in zip(keys, hists)]
+    assert all(e.is_view and e.rung == 0 for e in entries)
+    (pin,) = {id(e._chunk): e._chunk for e in entries}.values()
+    assert [e._row for e in entries] == list(range(n))
+    assert cache.resident_bytes == _counted(cache) \
+        == pin.nbytes + n * DEFAULT_LAYOUT.width * 8
+    assert cache.stats()["view_entries"] == n
+    # the pin is the append's final state: row i is history i's whole row
+    import jax
+
+    full, _rows = _replay_full(hists)
+    for leaf, want in zip(jax.tree_util.tree_leaves(pin.state),
+                          jax.tree_util.tree_leaves(full)):
+        assert (np.asarray(leaf)[:n] == np.asarray(want)).all()
+
+
+@pytest.mark.parametrize("suite", ["basic", "echo_signal", "timer_retry",
+                                   "concurrent_child", "ndc"])
+def test_an_append_from_re_pinned_views_is_byte_identical(suite):
+    """Two appends in a row: the second replays from the views the first
+    re-pinned, and lands on a full replay's payloads, CRCs and branches."""
+    cache = ResidentStateCache(DEFAULT_LAYOUT,
+                               ladder=EscalationLadder(DEFAULT_LAYOUT))
+    n = 4
+    hists = generate_corpus(suite, num_workflows=n, seed=61,
+                            target_events=32)
+    keys = [("d", f"w{i}", "r") for i in range(n)]
+    _seed_cache(cache, keys, [h[:-2] for h in hists])
+    for cut in (-1, None):
+        stage = [h[:cut] for h in hists]
+        results = cache.replay_append(
+            [(k, cache.lookup(k, h)[1], h) for k, h in zip(keys, stage)])
+        assert all(r.ok and r.rung == 0 for r in results)
+        assert all(cache.lookup(k, h)[1].is_view
+                   for k, h in zip(keys, stage))
+    full, rows = _replay_full(hists)
+    got = np.stack([r.payload for r in results])
+    assert got.tobytes() == rows.tobytes()
+    assert (crc32_of_rows(got) == crc32_of_rows(rows)).all()
+    assert [r.branch for r in results] == \
+        np.asarray(full.current_branch).tolist()
+    # the seeding's n slices, and the second launch's read of the views
+    # the first re-pinned
+    assert _row_slices(cache) == 2 * n
+    assert _view_counters(cache) == (2 * n, n)
+
+
+def test_a_re_pinned_chunk_too_large_for_its_slice_is_admitted_row_by_row():
+    """Room for five rows and not for the append's eight-row final state
+    beside their payloads: the rows are sliced and admitted one by one,
+    and the append's results read as they do when the views fit."""
+    probe = ResidentStateCache(DEFAULT_LAYOUT)
+    row_nbytes = probe._row_nbytes(DEFAULT_LAYOUT)
+    n = 5
+    _keys, _hists, viewed = _append_last_batches(
+        ResidentStateCache(DEFAULT_LAYOUT,
+                           ladder=EscalationLadder(DEFAULT_LAYOUT),
+                           registry=m.MetricsRegistry()), n)
+    cache = ResidentStateCache(DEFAULT_LAYOUT,
+                               ladder=EscalationLadder(DEFAULT_LAYOUT),
+                               budget_bytes=n * row_nbytes + 1)
+    keys, hists, results = _append_last_batches(cache, n)
+    assert _fields(results) == _fields(viewed)
+    assert _view_counters(cache) == (0, 0)
+    assert _row_slices(cache) == 2 * n
+    assert len(cache) == n and cache.stats()["view_entries"] == 0
+    assert cache.resident_bytes == _counted(cache) == n * row_nbytes
+    for k, h in zip(keys, hists):
+        kind, entry = cache.lookup(k, h)
+        assert kind == "exact" and not entry.is_view
+        assert entry.nbytes == row_nbytes
 
 
 # ---------------------------------------------------------------------------
@@ -798,6 +930,31 @@ class TestResidentLadder:
         assert reg.counter(m.SCOPE_TPU_RESIDENT,
                            m.M_RESIDENT_NARROWED) == 1
         assert cache.stats()["widened_entries"] == 0
+
+    def test_a_widened_row_renarrows_through_the_row_path(self):
+        """Rows of a widened rung are re-pinned one by one, the only path
+        that narrows: no view is made of a widened state."""
+        cache = ResidentStateCache(DEFAULT_LAYOUT,
+                                   ladder=EscalationLadder(DEFAULT_LAYOUT))
+        prefix, append1, append2 = _overflow_chain()
+        key = ("d", "ovf", "r")
+        _seed_cache(cache, [key], [prefix])
+        res = cache.replay_append(
+            [(key, cache.lookup(key, append1)[1], append1)])[0]
+        assert res.ok and res.escalated and res.rung == 1
+        entry = cache.lookup(key, append1)[1]
+        assert not entry.is_view and entry.rung == 1
+        res = cache.replay_append([(key, entry, append2)])[0]
+        assert res.ok and res.rung == 0 and not res.escalated
+        assert (res.payload == _oracle_row(append2)).all()
+        kind, entry = cache.lookup(key, append2)
+        assert kind == "exact" and entry.rung == 0 and not entry.is_view
+        assert entry.nbytes == cache._row_nbytes(DEFAULT_LAYOUT)
+        # the seeding's slice, the escalated row's, the narrowed row's
+        assert _row_slices(cache) == 3
+        assert _view_counters(cache) == (0, 0)
+        assert cache.metrics.counter(m.SCOPE_TPU_RESIDENT,
+                                     m.M_RESIDENT_NARROWED) == 1
 
     def test_no_ladder_falls_back_cleanly(self):
         cache = ResidentStateCache(DEFAULT_LAYOUT, ladder=None)
@@ -926,8 +1083,8 @@ def _row_slices(cache):
 @pytest.mark.parametrize("how", ["views", "rows"])
 def test_row_slices_count_the_pools_slice_row_launches(how, monkeypatch):
     """`tpu.resident/row-slices` = the `slice_row` launches: one a row
-    sliced and admitted, one a view's FIRST read, one a readmitted row of
-    an append."""
+    sliced and admitted, one a view's FIRST read; an append's rows are
+    re-pinned as views of its final state, and slice nothing."""
     launched = []
     real = resident_mod._slice_row
     monkeypatch.setattr(resident_mod, "_slice_row",
@@ -952,25 +1109,28 @@ def test_row_slices_count_the_pools_slice_row_launches(how, monkeypatch):
     items = [(k, cache.lookup(k, h)[1], h) for k, h in zip(keys, hists)]
     results, _report = cache.replay_append_report(items)
     assert all(r.ok for r in results)
-    # each view still unread is sliced once for the launch state, and
-    # every appended row once to be re-pinned
+    # each view still unread is sliced once for the launch state; the
+    # appended rows are sliced by nobody
     unread = n - 1 if how == "views" else 0
-    assert len(launched) - before == unread + n
+    assert len(launched) - before == unread
     assert _row_slices(cache) == len(launched) == seeded + (
-        1 if how == "views" else 0) + unread + n
+        1 if how == "views" else 0) + unread
 
 
 def test_serving_stats_carry_the_pools_row_slices():
     h = _Harness(workflows=3)
     seen = [h.sched.stats()["row_slices"]]
     assert seen == [0]
-    for counts in (-1, 0):   # the cold admits, then a suffix append each
+    # the cold admits, then two suffix appends each: the first re-pins
+    # its rows as views and slices nothing, the second's launch reads
+    # them and slices each once
+    for counts in (-2, -1, 0):
         for k in h.keys:
             h.counts[k] = len(h.by_key[k]) + counts
             h.submit(k)
         h.flush()
         seen.append(h.sched.stats()["row_slices"])
-    assert seen == [0, 3, 6]
+    assert seen == [0, 3, 3, 6]
     assert h.sched.resident.stats()["row_slices"] == 6
 
 
