@@ -58,6 +58,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+import traceback
 import weakref
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -734,16 +735,30 @@ class ServingScheduler:
         hydrates instead of replaying. Runs AFTER every ticket in the
         flush group resolved: a due key's write (device readback + WAL
         append) must never sit between co-batched callers and their
-        results."""
+        results. Every key is noted before any is written, and each
+        key's write stands alone: one that raises is counted under
+        `write-errors` and the loop goes on, so a failed write costs a
+        record and never a ticket, a later key's count or the flush's
+        cold items."""
         from . import snapshot as snapshot_mod
 
         if not keys_events or self._injected_reads \
                 or not snapshot_mod.enabled():
             return
         snapper = self.tpu.snapshotter()
-        for key, appended_events in keys_events:
-            snapper.note_append(key, appended_events)
-            snapper.maybe_snapshot(key)
+        with tracing.span("serving.snapshot"):
+            for key, appended_events in keys_events:
+                snapper.note_append(key, appended_events)
+            for key, _events in keys_events:
+                try:
+                    snapper.maybe_snapshot(key)
+                except Exception as exc:
+                    self.metrics.inc(m.SCOPE_TPU_SNAPSHOT,
+                                     m.M_SNAP_WRITE_ERRORS)
+                    flightrecorder.emit(
+                        "snapshot-write-error", key=list(key),
+                        error=f"{type(exc).__name__}: {exc}",
+                        traceback=traceback.format_exc())
 
     def _parity(self, item: _Pending, payload: np.ndarray,
                 branch: int) -> Tuple[bool, int]:

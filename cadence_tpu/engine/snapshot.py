@@ -41,8 +41,11 @@ the durable twin of the resident cache that closes that last residue:
   any other type.
 
 Counters land under `tpu.snapshot/*` (writes, checksum-skips, hydrates,
-ignored-stale, ignored-torn) plus the entry/byte gauges the `admin
-snapshot` CLI verb rolls up.
+ignored-stale, ignored-torn, gate-chains, write-errors) plus the
+entry/byte gauges the `admin snapshot` CLI verb rolls up, which the
+writer's registry collector reads from the store before every render.
+Each gate chain the serving policy runs is the span
+`serving.snapshot-gate-chain`, inside the flush's `serving.snapshot`.
 """
 from __future__ import annotations
 
@@ -56,7 +59,9 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..core.checksum import DEFAULT_LAYOUT, PayloadLayout
+from ..utils import flightrecorder
 from ..utils import metrics as m
+from ..utils import tracing
 from .cache import ContentAddress
 
 #: snapshot record format version (inside the WAL's schema version: the
@@ -275,11 +280,6 @@ class SnapshotStore:
         with self._lock:
             return len(self._snaps)
 
-    @property
-    def total_bytes(self) -> int:
-        with self._lock:
-            return sum(r.nbytes for r in self._snaps.values())
-
     def stats(self) -> Dict[str, object]:
         with self._lock:
             recs = list(self._snaps.values())
@@ -416,6 +416,7 @@ class Snapshotter:
         #: which may be a remote proxy on a ServiceHost — and keeps the
         #: full gate chain from re-running per committed transaction.
         self._known: set = set()
+        self.metrics.add_collector(self._collect_gauges)
 
     def _scope(self):
         return self.metrics.scope(m.SCOPE_TPU_SNAPSHOT)
@@ -466,7 +467,10 @@ class Snapshotter:
         commit."""
         if not self.due(key):
             return False
-        if self.snapshot_key(key):
+        self._scope().inc(m.M_SNAP_GATE_CHAINS)
+        with tracing.span("serving.snapshot-gate-chain"):
+            written = self.snapshot_key(key)
+        if written:
             return True
         self._defer(key, reset_counter=True)
         return False
@@ -565,17 +569,31 @@ class Snapshotter:
                 # start; a publish failure must never fail the local write
                 pass
         self._defer(key, reset_counter=True)
-        scope = self._scope()
-        scope.inc(m.M_SNAP_WRITES)
-        self._gauges()
+        self._scope().inc(m.M_SNAP_WRITES)
         return True
 
-    def _gauges(self) -> None:
-        store = self.stores.snapshot
+    def refresh_gauges(self) -> None:
+        """Set the entry and byte gauges from the store's `stats()`, the
+        one call a local `SnapshotStore` and a service host's remote
+        proxy both answer. A sweep calls it, and the registry before
+        each render (`_collect_gauges`); the policy's write path does
+        not, so a serving flush pays no round trip for it."""
+        stats = self.stores.snapshot.stats()
         self.metrics.gauge(m.SCOPE_TPU_SNAPSHOT, m.M_SNAP_ENTRIES,
-                           float(len(store)))
+                           float(stats["entries"]))
         self.metrics.gauge(m.SCOPE_TPU_SNAPSHOT, m.M_SNAP_BYTES,
-                           float(store.total_bytes))
+                           float(stats["bytes"]))
+
+    def _collect_gauges(self) -> None:
+        """The registry's collector: the gauges read fresh before every
+        render, whichever path scrapes. A store that does not answer
+        leaves them as they were and says so on the flight recorder; a
+        scrape never fails for it."""
+        try:
+            self.refresh_gauges()
+        except Exception as exc:
+            flightrecorder.emit("snapshot-gauges-unread",
+                                error=f"{type(exc).__name__}: {exc}")
 
     def sweep(self, keys=None, force: bool = False) -> SweepReport:
         """Snapshot every resident key (or `keys`); the admin verb and
@@ -596,5 +614,5 @@ class Snapshotter:
                 report.skipped_policy += 1
             else:
                 report.skipped_not_at_tip += 1
-        self._gauges()
+        self.refresh_gauges()
         return report

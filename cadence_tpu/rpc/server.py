@@ -322,7 +322,8 @@ class ServiceHost(socketserver.ThreadingTCPServer):
         # same contract as the serving divergence counter
         for metric in (cm.M_SNAP_WRITES, cm.M_SNAP_CHECKSUM_SKIPS,
                        cm.M_SNAP_HYDRATES, cm.M_SNAP_IGNORED_STALE,
-                       cm.M_SNAP_IGNORED_TORN):
+                       cm.M_SNAP_IGNORED_TORN, cm.M_SNAP_GATE_CHAINS,
+                       cm.M_SNAP_WRITE_ERRORS):
             self.metrics.inc(cm.SCOPE_TPU_SNAPSHOT, metric, 0)
         for gauge in (cm.M_SNAP_ENTRIES, cm.M_SNAP_BYTES):
             self.metrics.gauge(cm.SCOPE_TPU_SNAPSHOT, gauge, 0.0)
@@ -354,6 +355,10 @@ class ServiceHost(socketserver.ThreadingTCPServer):
             tpu.metrics = self.metrics
             self.tpu = tpu
             self.serving = tpu.serving_scheduler()
+            # the snapshot writer made at boot: its collector reads the
+            # store's occupancy into the gauges on every scrape from the
+            # first, not only once a flush has made it
+            tpu.snapshotter()
             # live HBM state migration (engine/migration.py): shard
             # movement snapshots this host's resident rows out and
             # hydrates acquired shards from the SHARED snapshot store

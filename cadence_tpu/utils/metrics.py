@@ -6,7 +6,10 @@ emits through tally to m3/statsd/prometheus; here the registry keeps the
 aggregates in-process and exposes two emitter seams: snapshot() (the
 structured dump tests and the bench assert on, now with percentiles) and
 to_prometheus() (text exposition format 0.0.4, served by the /metrics
-scrape surface in utils/scrape.py and rpc/server.py).
+scrape surface in utils/scrape.py and rpc/server.py). A series read on
+demand from elsewhere (a store in another process) is kept by a
+collector, which both seams run before they read, so every scrape path
+sees it fresh.
 
 Timers feed fixed-bucket histograms on every record(), so each latency
 metric carries a full distribution (bucket counts + interpolated
@@ -20,6 +23,7 @@ import bisect
 import re
 import threading
 import time
+import weakref
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -316,7 +320,15 @@ M_SERVING_HANDOFF_FAILED = "handoff-failures"
 #: persisted), `hydrates` counts snapshot→resident seeds on a cold path
 #: (restart, chain break, cold admit), `ignored-stale`/`ignored-torn`
 #: count snapshots detected invalid and skipped — fallen back to full
-#: replay, never served; the gauges mirror the store's occupancy
+#: replay, never served; the gauges mirror the store's occupancy, read
+#: through the store's `stats()` on a sweep and, as the writer's
+#: registry collector, on every render of the registry (snapshot(),
+#: to_prometheus(): the `admin_metrics` op and GET /metrics alike).
+#: `gate-chains` counts each gate chain the serving policy enters (a
+#: due key's `snapshot_key`, written or not, each inside the span
+#: `serving.snapshot-gate-chain`); `write-errors` counts the
+#: policy's writes that raised on a serving flush (the flush's tickets
+#: are resolved already: the failure costs a record, never a ticket)
 M_SNAP_WRITES = "writes"
 M_SNAP_CHECKSUM_SKIPS = "checksum-skips"
 M_SNAP_HYDRATES = "hydrates"
@@ -324,6 +336,8 @@ M_SNAP_IGNORED_STALE = "ignored-stale"
 M_SNAP_IGNORED_TORN = "ignored-torn"
 M_SNAP_BYTES = "snapshot-bytes"
 M_SNAP_ENTRIES = "snapshot-entries"
+M_SNAP_GATE_CHAINS = "gate-chains"
+M_SNAP_WRITE_ERRORS = "write-errors"
 
 #: live HBM state migration (engine/migration.py, SCOPE_TPU_MIGRATION):
 #: on shard RELEASE the losing host writes checksum-gated snapshot
@@ -517,6 +531,7 @@ class MetricsRegistry:
         self._timers: Dict[Tuple[str, str], _TimerStat] = {}
         self._gauges: Dict[Tuple[str, str], float] = {}
         self._histograms: Dict[Tuple[str, str], HistogramStat] = {}
+        self._collectors: List[weakref.WeakMethod] = []
 
     def scope(self, name: str) -> "Scope":
         return Scope(self, name)
@@ -552,6 +567,24 @@ class MetricsRegistry:
     def gauge(self, scope: str, name: str, value: float) -> None:
         with self._lock:
             self._gauges[(scope, name)] = value
+
+    def add_collector(self, method) -> None:
+        """Have `method`, a bound method, set its series before every
+        render (`snapshot()`, `to_prometheus()`): the seam for a series
+        read on demand from elsewhere, such as the occupancy of a store
+        in another process, so that each scrape path reads it fresh and
+        none has to ask. Held weakly: a collector goes with its object."""
+        with self._lock:
+            self._collectors.append(weakref.WeakMethod(method))
+
+    def _collect(self) -> None:
+        with self._lock:
+            live = [c() for c in self._collectors]
+            self._collectors = [c for c, method in zip(self._collectors, live)
+                                if method is not None]
+        for method in live:
+            if method is not None:
+                method()
 
     # reads
 
@@ -605,6 +638,7 @@ class MetricsRegistry:
 
     def snapshot(self) -> Dict[str, Dict[str, object]]:
         """Full dump, grouped by scope — the structured emitter seam."""
+        self._collect()
         out: Dict[str, Dict[str, object]] = {}
         with self._lock:
             for (scope, name), v in self._counters.items():
@@ -637,6 +671,7 @@ class MetricsRegistry:
         live HistogramStat references, so a concurrent observe() could
         land between the `_bucket` walk and the `_count` line and the
         exposition's +Inf bucket would disagree with its own count."""
+        self._collect()
         counters, gauges, histograms = self.raw_series()
 
         def metric_name(name: str) -> str:

@@ -88,6 +88,34 @@ class TestMetrics:
         snap = box.metrics.snapshot()
         assert m.SCOPE_REBUILD in snap and m.SCOPE_TPU_REPLAY not in ("",)
 
+    @pytest.mark.parametrize("render", ["snapshot", "to_prometheus"])
+    def test_collectors_run_before_every_render_and_go_with_their_object(
+            self, render):
+        """A collector sets its series before each render, whichever
+        renders; the registry holds it weakly, so it goes with its
+        object."""
+        import gc
+
+        registry = m.MetricsRegistry()
+
+        class Store:
+            entries = 0
+
+            def collect(self):
+                self.entries += 1
+                registry.gauge("store", "entries", float(self.entries))
+
+        store = Store()
+        registry.add_collector(store.collect)
+        getattr(registry, render)()
+        getattr(registry, render)()
+        assert store.entries == 2
+        assert registry.gauge_value("store", "entries") == 2.0
+        del store
+        gc.collect()
+        getattr(registry, render)()
+        assert registry.gauge_value("store", "entries") == 2.0
+
 
 class TestDynamicConfig:
     def test_payload_layout_tunable_without_code_edits(self):
