@@ -24,7 +24,9 @@ payload blobs; state_builder.go:132-646).
 from __future__ import annotations
 
 import struct
-from typing import List, Sequence
+import threading
+from collections.abc import Sequence as _SequenceABC
+from typing import List, Optional, Sequence
 
 from .enums import EventType
 from .events import HistoryBatch, HistoryEvent, RetryPolicy
@@ -223,9 +225,14 @@ def _event_wire_attrs(ev: HistoryEvent) -> List[tuple]:
 
 
 def serialize_history(batches: Sequence[HistoryBatch]) -> bytes:
-    """One workflow's batched history → wire bytes."""
+    """One workflow's batched history → wire bytes. A batch whose events
+    are held as bytes (`HeldEvents`) is written as those bytes, undecoded:
+    the codec is canonical, so they are what its events serialize to."""
     parts: List[bytes] = [_U32.pack(len(batches))]
     for batch in batches:
+        if type(batch.events) is HeldEvents:
+            parts.append(batch.events.blob[_U32.size:])
+            continue
         parts.append(_U16.pack(len(batch.events)))
         for ev in batch.events:
             attrs = _event_wire_attrs(ev)
@@ -240,6 +247,14 @@ def serialize_history(batches: Sequence[HistoryBatch]) -> bytes:
                 else:
                     parts.append(_I64.pack(value))
     return b"".join(parts)
+
+
+def batch_bytes(batch: HistoryBatch) -> bytes:
+    """One batch as a one-batch history's bytes: the held bytes as they
+    are where the batch has them, else its events serialized."""
+    if type(batch.events) is HeldEvents:
+        return batch.events.blob
+    return serialize_history([batch])
 
 
 def serialize_corpus(histories: Sequence[Sequence[HistoryBatch]]) -> List[bytes]:
@@ -291,6 +306,142 @@ def deserialize_history(data: bytes, domain_id: str = "d", workflow_id: str = "w
         batches.append(HistoryBatch(domain_id=domain_id, workflow_id=workflow_id,
                                     run_id=run_id, events=events))
     return batches
+
+
+class CorruptLogError(Exception):
+    """A write-ahead log that cannot be recovered as written: a record
+    mid-file that does not parse, or a history batch held from one whose
+    bytes do not decode to the consecutive ids their head gives."""
+
+
+class DecodeTally:
+    """Held batches decoded, each counted once (a history store keeps one
+    for the batches it holds)."""
+
+    __slots__ = ("decoded",)
+
+    def __init__(self) -> None:
+        self.decoded = 0
+
+
+#: one decode at a time, so that a held batch is decoded once however many
+#: threads read it
+_DECODE_LOCK = threading.Lock()
+
+
+class HeldEvents(_SequenceABC):
+    """One batch's events held as the codec's bytes: a one-batch history,
+    as a write-ahead log's `h` record carries it. The length and the id
+    range come from the head; the events are decoded the first time
+    something reads them, once, and kept. The engine assigns ids one by
+    one, so the last id is the first plus the length less one; a blob whose
+    ids are not consecutive is refused when it is decoded. Immutable: every
+    reader shares one."""
+
+    __slots__ = ("blob", "first_id", "_n", "_events", "_tally")
+
+    def __init__(self, blob: bytes, n: int, first_id: int,
+                 tally: Optional[DecodeTally] = None) -> None:
+        self.blob = blob
+        self._n = n
+        self.first_id = first_id
+        self._events: Optional[List[HistoryEvent]] = None
+        self._tally = tally
+
+    @property
+    def last_id(self) -> int:
+        return self.first_id + self._n - 1
+
+    @property
+    def is_decoded(self) -> bool:
+        return self._events is not None
+
+    def decoded(self) -> List[HistoryEvent]:
+        events = self._events
+        if events is None:
+            with _DECODE_LOCK:
+                events = self._events
+                if events is None:
+                    events = self._decode()
+                    self._events = events
+                    if self._tally is not None:
+                        self._tally.decoded += 1
+        return events
+
+    def _decode(self) -> List[HistoryEvent]:
+        try:
+            (batch,) = deserialize_history(self.blob)
+        except (struct.error, ValueError, KeyError,
+                UnicodeDecodeError) as exc:
+            raise CorruptLogError(
+                f"held history batch at id {self.first_id} does not "
+                f"decode: {exc!r}") from exc
+        ids = [e.id for e in batch.events]
+        if ids != list(range(self.first_id, self.first_id + self._n)):
+            raise CorruptLogError(
+                f"held history batch at id {self.first_id}: ids {ids} are "
+                f"not the {self._n} consecutive ids its head gives")
+        return batch.events
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __getitem__(self, index):
+        return self.decoded()[index]
+
+    def __iter__(self):
+        return iter(self.decoded())
+
+    def __reversed__(self):
+        return reversed(self.decoded())
+
+    def __eq__(self, other) -> bool:
+        if type(other) is HeldEvents:
+            return self.blob == other.blob
+        if isinstance(other, _SequenceABC) and not isinstance(
+                other, (str, bytes)):
+            return self.decoded() == list(other)
+        return NotImplemented
+
+    __hash__ = None
+
+    def __reduce__(self):
+        return HeldEvents, (self.blob, self._n, self.first_id)
+
+    def __repr__(self) -> str:
+        return (f"HeldEvents(ids {self.first_id}..{self.last_id}, "
+                f"{len(self.blob)} bytes, "
+                f"{'decoded' if self.is_decoded else 'held'})")
+
+
+def batch_first_id(events) -> int:
+    """The first id of a batch's events, read from the head where they are
+    held."""
+    return events.first_id if type(events) is HeldEvents else events[0].id
+
+
+def batch_last_id(events) -> int:
+    """The last id of a batch's events, read from the head where they are
+    held."""
+    return events.last_id if type(events) is HeldEvents else events[-1].id
+
+
+def hold(blob: bytes,
+         tally: Optional[DecodeTally] = None) -> Optional[HeldEvents]:
+    """`blob` held undecoded where it is one batch of one event or more
+    (its event count and first id read from the head); None for any other
+    blob."""
+    if len(blob) < _HELD_HEAD.size:
+        return None
+    n_batches, n, first_id = _HELD_HEAD.unpack_from(blob, 0)
+    if n_batches != 1 or n == 0:
+        return None
+    return HeldEvents(blob, n, first_id, tally)
+
+
+#: a one-batch history's head: u32 n_batches, u16 n_events, the first
+#: event's i64 id
+_HELD_HEAD = struct.Struct("<IHq")
 
 
 _CODE_TO_KEY = {

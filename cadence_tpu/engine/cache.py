@@ -50,9 +50,10 @@ def batch_crc(batch) -> int:
     """CRC32 of one serialized batch — the tail fingerprint of the
     content address (a torn/overwritten tail changes the last batch's
     bytes, so the checksum catches every mutation the engine can
-    produce; new_run_events ride the serialized form too)."""
-    from ..core.codec import serialize_history
-    return zlib.crc32(serialize_history([batch]))
+    produce; new_run_events ride the serialized form too). A batch held
+    as its bytes is read from them, undecoded."""
+    from ..core.codec import batch_bytes
+    return zlib.crc32(batch_bytes(batch))
 
 
 class ContentAddress(NamedTuple):

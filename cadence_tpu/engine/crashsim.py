@@ -123,11 +123,8 @@ class CrashSim:
             key: RunKey = (rec["d"], rec["w"], rec["r"])
             if t == "h":
                 import base64
-                from ..core.codec import deserialize_history
-                for batch in deserialize_history(
-                        base64.b64decode(rec["blob"]), *key):
-                    scratch.append_batch(*key, events=batch.events,
-                                         branch=rec["b"])
+                scratch.append_serialized(
+                    *key, base64.b64decode(rec["blob"]), branch=rec["b"])
             elif t == "f":
                 scratch.fork_branch(*key, source_branch=rec["src"],
                                     fork_event_id=rec["at"])

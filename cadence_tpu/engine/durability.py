@@ -54,7 +54,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-from ..core.codec import deserialize_history, serialize_history
+from ..core.codec import CorruptLogError, HeldEvents, serialize_history
 from ..core.events import HistoryBatch
 from ..oracle.mutable_state import (
     MutableState,
@@ -158,10 +158,6 @@ class DurableLog:
                     f"{path}: corrupt record at line {i + 1} "
                     f"(not the final line — refusing to recover past it)")
         return records
-
-
-class CorruptLogError(Exception):
-    """Mid-file WAL corruption (not a torn tail)."""
 
 
 class SqliteLog:
@@ -677,6 +673,10 @@ def recover_stores(path: str, verify_on_device: bool = True,
                 wal.append(version_record())
             stores.attach_wal(wal)
         report.seconds["reconcile"] = leg.duration_s
+        # the log replay held every one-batch `h` record as its bytes;
+        # what the call read of them was decoded once a batch
+        m.DEFAULT_REGISTRY.scope(m.SCOPE_TPU_RECOVER).inc(
+            m.M_RECOVER_BATCHES_DECODED, stores.history.held_tally.decoded)
     report.seconds["call"] = call.duration_s
     return stores, report
 
@@ -695,7 +695,7 @@ def _replay_log(path: str, stores: Stores) -> Tuple[set, int, int, int]:
     # schema gate + in-memory migration (the setup/update-schema contract):
     # older logs lift transparently; NEWER logs refuse
     records, original = migrate_records(read_log(path))
-    n_batches = n_events = n_bytes = n_snaps = n_snap_bytes = 0
+    n_batches = n_events = n_bytes = n_snaps = n_snap_bytes = n_held = 0
     for rec in records:
         t = rec["t"]
         if t == "d":
@@ -719,13 +719,13 @@ def _replay_log(path: str, stores: Stores) -> Tuple[set, int, int, int]:
                 transfer_queue_states=[list(q)
                                        for q in rec.get("qs", [])]))
         elif t == "h":
+            # the batch is held as its bytes: nothing here decodes it
             blob = base64.b64decode(rec["blob"])
-            batches = deserialize_history(blob, rec["d"], rec["w"], rec["r"])
-            for batch in batches:
-                stores.history.append_batch(rec["d"], rec["w"], rec["r"],
-                                            batch.events, branch=rec["b"])
-                n_events += len(batch.events)
-            n_batches += len(batches)
+            for events in stores.history.append_serialized(
+                    rec["d"], rec["w"], rec["r"], blob, branch=rec["b"]):
+                n_batches += 1
+                n_events += len(events)
+                n_held += type(events) is HeldEvents
             n_bytes += len(blob)
         elif t == "f":
             stores.history.fork_branch(rec["d"], rec["w"], rec["r"],
@@ -801,6 +801,7 @@ def _replay_log(path: str, stores: Stores) -> Tuple[set, int, int, int]:
     scope.inc(m.M_RECOVER_HISTORY_BATCHES, n_batches)
     scope.inc(m.M_RECOVER_HISTORY_EVENTS, n_events)
     scope.inc(m.M_RECOVER_HISTORY_BYTES, n_bytes)
+    scope.inc(m.M_RECOVER_BATCHES_HELD, n_held)
     scope.inc(m.M_RECOVER_SNAPSHOT_RECORDS, n_snaps)
     scope.inc(m.M_RECOVER_SNAPSHOT_BYTES, n_snap_bytes)
     return referenced_runs, original, n_events, n_snaps
@@ -934,10 +935,13 @@ def _rebuild_executions(stores: Stores, verify_on_device: bool,
                         workflow_type=info.workflow_type_name,
                         start_time=info.start_timestamp))
                 if closed:
-                    events = stores.history.read_events(*key)
+                    # the close event ends the last batch: only that one
+                    # is read
+                    last = stores.history.read_batches_range(
+                        *key, stores.history.batch_count(*key) - 1)
                     stores.visibility.record_closed(
                         *key,
-                        close_time=events[-1].timestamp if events else 0,
+                        close_time=last[-1][-1].timestamp if last else 0,
                         close_status=info.close_status)
         scope.inc(m.M_RECOVER_EXECUTIONS, report.executions_rebuilt)
     report.seconds["rebuild"] = leg.duration_s

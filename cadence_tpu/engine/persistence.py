@@ -31,6 +31,15 @@ import threading
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Tuple
 
+from ..core.codec import (
+    DecodeTally,
+    HeldEvents,
+    batch_bytes,
+    batch_first_id,
+    batch_last_id,
+    deserialize_history,
+    hold,
+)
 from ..core.events import HistoryBatch, HistoryEvent
 from ..oracle.mutable_state import MutableState
 from . import crashpoints
@@ -122,15 +131,23 @@ class HistoryStore:
     branch copies the source up to the fork event (splitting a batch when
     the fork lands mid-batch). The per-run current-branch pointer tracks
     NDC conflict resolution (which branch the mutable state follows);
-    callers that pass branch=None read/append the current branch."""
+    callers that pass branch=None read/append the current branch.
+
+    A batch is held as what arrived: a list of events from a transaction
+    (`append_batch`), or, from a write-ahead log's `h` record, the codec's
+    bytes (`append_serialized`, a `HeldEvents`), decoded the first time
+    something reads its events. Readers hand a held batch on as it is, so
+    a recovery decodes only the batches it reads."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         #: (domain_id, workflow_id, run_id) -> list of branches, each a
-        #: list of event batches
-        self._branches: Dict[Tuple[str, str, str], List[List[List[HistoryEvent]]]] = {}
+        #: list of event batches (a list of events, or HeldEvents)
+        self._branches: Dict[Tuple[str, str, str], List[list]] = {}
         self._current: Dict[Tuple[str, str, str], int] = {}
         self._wal = None
+        #: held batches of this store decoded so far, once each
+        self.held_tally = DecodeTally()
         #: SnapshotStore back-reference (Stores wires it): history
         #: mutations that rewrite bytes under a snapshot's content
         #: address — tail overwrite at/before the snapshot point, NDC
@@ -166,16 +183,41 @@ class HistoryStore:
         if not events:
             raise ValueError("empty history batch")
         crashpoints.fire("store.history.append_batch")
+        self._append((domain_id, workflow_id, run_id), list(events), branch,
+                     blob)
+
+    def append_serialized(self, domain_id: str, workflow_id: str,
+                          run_id: str, blob: bytes,
+                          branch: Optional[int] = None) -> list:
+        """Append a write-ahead log `h` record's batch as its bytes (the
+        blob after base64), held undecoded: its id range comes from the
+        head, and the same contiguity, overwrite and snapshot rules as
+        `append_batch` apply. A blob of more than one batch is decoded at
+        once and appended a batch at a time. Returns the batches appended,
+        each a sequence of events."""
         key = (domain_id, workflow_id, run_id)
+        held = hold(blob, self.held_tally)
+        if held is None:
+            appended = []
+            for batch in deserialize_history(blob, *key):
+                self.append_batch(*key, batch.events, branch=branch)
+                appended.append(batch.events)
+            return appended
+        crashpoints.fire("store.history.append_batch")
+        self._append(key, held, branch, blob)
+        return [held]
+
+    def _append(self, key: Tuple[str, str, str], batch,
+                branch: Optional[int], blob: Optional[bytes]) -> None:
         with self._lock:
             branches = self._branches.setdefault(key, [[]])
             index = self._current.get(key, 0) if branch is None else branch
             if index >= len(branches):
                 raise EntityNotExistsError(f"no branch {index} for {key}")
             target = branches[index]
-            first = events[0].id
+            first = batch_first_id(batch)
             if target:
-                expected = target[-1][-1].id + 1
+                expected = batch_last_id(target[-1]) + 1
                 if first > expected:
                     raise ConditionFailedError(
                         f"history append out of order: got first id "
@@ -184,16 +226,16 @@ class HistoryStore:
                 if first < expected:
                     # overwrite: drop the tail from `first` on
                     truncated_last = False
-                    while target and target[-1][0].id >= first:
+                    while target and batch_first_id(target[-1]) >= first:
                         target.pop()
-                    if target and target[-1][-1].id >= first:
+                    if target and batch_last_id(target[-1]) >= first:
                         kept = [e for e in target[-1] if e.id < first]
                         if kept:
                             target[-1] = kept
                             truncated_last = True
                         else:
                             target.pop()
-                    if target and target[-1][-1].id + 1 != first:
+                    if target and batch_last_id(target[-1]) + 1 != first:
                         raise ConditionFailedError(
                             f"history overwrite leaves a gap before {first}")
                     self._size_cache.pop((key, index), None)
@@ -209,15 +251,13 @@ class HistoryStore:
                         self._snapshots.invalidate_overwrite(
                             key, len(target) - (1 if truncated_last
                                                 else 0))
-            target.append(list(events))
+            target.append(batch)
             if self._wal is not None:
                 from .durability import history_record, history_record_from_blob
                 self._wal.append(
-                    history_record_from_blob(domain_id, workflow_id, run_id,
-                                             index, blob)
+                    history_record_from_blob(*key, index, blob)
                     if blob is not None else
-                    history_record(domain_id, workflow_id, run_id, index,
-                                   events))
+                    history_record(*key, index, batch))
 
     def fork_branch(self, domain_id: str, workflow_id: str, run_id: str,
                     source_branch: int, fork_event_id: int) -> int:
@@ -228,10 +268,10 @@ class HistoryStore:
             branches = self._branches.get(key)
             if branches is None or source_branch >= len(branches):
                 raise EntityNotExistsError(f"no branch {source_branch} for {key}")
-            forked: List[List[HistoryEvent]] = []
+            forked: list = []
             for batch in branches[source_branch]:
-                if batch[-1].id <= fork_event_id:
-                    forked.append(list(batch))
+                if batch_last_id(batch) <= fork_event_id:
+                    forked.append(_copy(batch))
                 else:
                     partial = [e for e in batch if e.id <= fork_event_id]
                     if partial:
@@ -301,7 +341,7 @@ class HistoryStore:
             index = self._current.get(key, 0) if branch is None else branch
             if index >= len(branches):
                 raise EntityNotExistsError(f"no branch {index} for {key}")
-            return [list(b) for b in branches[index]]
+            return [_copy(b) for b in branches[index]]
 
     def read_events(self, domain_id: str, workflow_id: str, run_id: str,
                     branch: Optional[int] = None) -> List[HistoryEvent]:
@@ -318,7 +358,6 @@ class HistoryStore:
         drop the cache. The snapshot writer persists this next to the
         device state so a warm restart recovers history-size accounting
         in O(suffix) instead of re-serializing the prefix."""
-        from ..core.codec import serialize_history
         key = (domain_id, workflow_id, run_id)
         with self._lock:
             branches = self._branches.get(key)
@@ -333,9 +372,9 @@ class HistoryStore:
             if len(sizes) > len(target):
                 del sizes[:]  # stale cache (belt and braces)
             for b in target[len(sizes):]:
-                sizes.append(len(serialize_history([HistoryBatch(
+                sizes.append(len(batch_bytes(HistoryBatch(
                     domain_id=domain_id, workflow_id=workflow_id,
-                    run_id=run_id, events=list(b))])))
+                    run_id=run_id, events=b))))
             return sum(sizes)
 
     def batch_count(self, domain_id: str, workflow_id: str, run_id: str,
@@ -372,7 +411,7 @@ class HistoryStore:
             index = self._current.get(key, 0) if branch is None else branch
             if index >= len(branches):
                 raise EntityNotExistsError(f"no branch {index} for {key}")
-            return [list(b) for b in branches[index][max(0, from_batch):]]
+            return [_copy(b) for b in branches[index][max(0, from_batch):]]
 
     def as_history_batches_range(self, domain_id: str, workflow_id: str,
                                  run_id: str, from_batch: int,
@@ -415,7 +454,7 @@ class HistoryStore:
                 raise EntityNotExistsError(f"no branch {index} for {key}")
             out: List[HistoryEvent] = []
             for b in branches[index]:
-                if b and b[-1].id < first_event_id:
+                if b and batch_last_id(b) < first_event_id:
                     continue
                 for e in b:
                     if e.id >= first_event_id:
@@ -423,6 +462,12 @@ class HistoryStore:
                         if len(out) >= page_size:
                             return out
             return out
+
+
+def _copy(batch):
+    """What a reader is handed: a copy of a list of events, a held batch
+    as it is (it is immutable, and copying it would decode it)."""
+    return batch if type(batch) is HeldEvents else list(batch)
 
 
 # ---------------------------------------------------------------------------

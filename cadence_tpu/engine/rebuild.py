@@ -24,6 +24,7 @@ reported, never silent (SURVEY.md §7). Consumers:
 """
 from __future__ import annotations
 
+import bisect
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -101,10 +102,53 @@ def _rebuilt_history_size(batches: Sequence[HistoryBatch],
     size accounting silently reset to zero — the history-size limits
     would stop protecting exactly the workflows that just failed over.
     For a continue-as-new chain only the final run's batches count (the
-    new run starts its own accounting)."""
-    from ..core.codec import serialize_history
-    return sum(len(serialize_history([b])) for b in batches
-               if b.run_id == run_id)
+    new run starts its own accounting). A batch held as its bytes is
+    measured by them, undecoded."""
+    from ..core.codec import batch_bytes
+    return sum(len(batch_bytes(b)) for b in batches if b.run_id == run_id)
+
+
+class _EventsById:
+    """The events of one run's batches by id, for `_hydrate`'s handful of
+    lookups. Over an id-contiguous lineage (what a history store holds)
+    the batch that holds an id is found by a bisect on the first ids and
+    only it is read, so a batch held as its bytes is decoded only when an
+    id in it is asked for (a held batch's ids are checked consecutive when
+    it is decoded). Batches that are not contiguous are read whole into a
+    dict, the last event of an id winning."""
+
+    def __init__(self, batches: Sequence[HistoryBatch]) -> None:
+        from ..core.codec import batch_first_id, batch_last_id
+        self._batches = batches
+        self._firsts: List[int] = []
+        self._dict: Optional[Dict[int, HistoryEvent]] = None
+        after = None
+        for b in batches:
+            first = batch_first_id(b.events)
+            after_last = after is None or first == after
+            if not after_last or \
+                    batch_last_id(b.events) != first + len(b.events) - 1:
+                self._read_whole()
+                return
+            self._firsts.append(first)
+            after = first + len(b.events)
+
+    def _read_whole(self) -> None:
+        self._dict = {e.id: e for b in self._batches for e in b.events}
+
+    def get(self, event_id: int) -> Optional[HistoryEvent]:
+        if self._dict is None:
+            k = bisect.bisect_right(self._firsts, event_id) - 1
+            if k < 0:
+                return None
+            events = self._batches[k].events
+            j = event_id - self._firsts[k]
+            if j >= len(events):
+                return None
+            if events[j].id == event_id:
+                return events[j]
+            self._read_whole()
+        return self._dict.get(event_id)
 
 
 class DeviceRebuilder:
@@ -493,9 +537,8 @@ class DeviceRebuilder:
         n, size = info
         if n > len(batches) or any(b.new_run_events for b in batches):
             return None
-        from ..core.codec import serialize_history
-        return size + sum(len(serialize_history([b]))
-                          for b in batches[n:])
+        from ..core.codec import batch_bytes
+        return size + sum(len(batch_bytes(b)) for b in batches[n:])
 
     @staticmethod
     def _oracle_rebuild(batches, entry) -> MutableState:
@@ -528,8 +571,7 @@ class DeviceRebuilder:
                     run_id=b.events[-1].get("new_execution_run_id", b.run_id),
                     events=b.new_run_events)])
         last_run = runs[-1]
-        by_id: Dict[int, HistoryEvent] = {
-            e.id: e for b in last_run for e in b.events}
+        by_id = _EventsById(last_run)
 
         # static/start fields via the oracle on the START BATCH ONLY — the
         # one place all string attributes live; O(1) in history length
@@ -566,8 +608,10 @@ class DeviceRebuilder:
         info.decision_original_scheduled_timestamp = int(
             arrs.decision_original_scheduled_ts[i])
         if info.cancel_requested:
+            # the latest request, read from the tail: a batch held as its
+            # bytes before the request is never decoded for it
             cancel_ev = next(
-                (e for b in last_run for e in reversed(b.events)
+                (e for b in reversed(last_run) for e in reversed(b.events)
                  if e.event_type == EventType.WorkflowExecutionCancelRequested),
                 None)
             if cancel_ev is not None:

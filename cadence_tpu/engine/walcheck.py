@@ -169,7 +169,7 @@ def is_probable_record(line: str) -> bool:
 
 def audit_stores(stores: Stores) -> List[Finding]:
     """Cross-invariants of the rebuilt stores."""
-    from ..core.codec import serialize_history
+    from ..core.codec import batch_bytes
     findings: List[Finding] = []
 
     # orphaned acks: a resume cursor pointing past the queue's contents.
@@ -195,7 +195,7 @@ def audit_stores(stores: Stores) -> List[Finding]:
         except Exception:
             continue  # quarantined-but-deleted or tombstoned
         branch = stores.history.get_current_branch(*key)
-        expected = sum(len(serialize_history([b]))
+        expected = sum(len(batch_bytes(b))
                        for b in stores.history.as_history_batches(
                            *key, branch=branch))
         if ms.history_size != expected:

@@ -414,7 +414,10 @@ M_VIS_ATTR_REPLACEMENTS = "attr-column-replacements"
 #: records the log replay installed and their decoded bytes (state blob +
 #: payload row); then, summed over both device passes, the runs hydrated
 #: from a record, the rows served as exact and as suffix hits of a
-#: resident pool, and the events those suffixes replayed
+#: resident pool, and the events those suffixes replayed. The batches the
+#: log replay held as their bytes (engine/persistence.HistoryStore.
+#: append_serialized), and those of them the call decoded, once each:
+#: their quotient is how much of the history the call read
 M_RECOVER_LOG_RECORDS = "log-records"
 M_RECOVER_LOG_BYTES = "log-bytes"
 M_RECOVER_HISTORY_BATCHES = "history-batches"
@@ -432,6 +435,8 @@ M_RECOVER_RUNS_HYDRATED = "runs-hydrated"
 M_RECOVER_EXACT_ROWS = "exact-rows"
 M_RECOVER_SUFFIX_ROWS = "suffix-rows"
 M_RECOVER_SUFFIX_EVENTS = "suffix-events"
+M_RECOVER_BATCHES_HELD = "batches-held-serialized"
+M_RECOVER_BATCHES_DECODED = "batches-decoded"
 
 
 def recover_records(record_type: str) -> str:
